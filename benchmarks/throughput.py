@@ -79,6 +79,7 @@ import jax
 
 from repro.configs import get_config
 from repro.core.format import CassandraConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving.blockpool import blocks_needed
 from repro.serving.engine import EngineConfig
@@ -1108,4 +1109,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -158,3 +158,125 @@ def unpack_bits(words: jax.Array, n: int) -> jax.Array:
     bits = (bytes_[..., None] >> shifts) & jnp.uint8(1)
     out = bits.reshape(*words.shape[:-1], words.shape[-1] * 32)
     return out[..., :n].astype(jnp.bool_)
+
+
+# ---------------------------------------------------------------------------
+# Gather/scatter-free positional primitives. On a TPU, XLA runs gathers,
+# scatters and sorts with per-element indices far slower than elementwise
+# work; the format's encode and decode use these instead.
+# ---------------------------------------------------------------------------
+
+def _select_bit(word: jax.Array, m: jax.Array) -> jax.Array:
+    """Bit index of the m-th (0-indexed) set bit of each uint32 ``word``:
+    a 5-step binary search on popcounts of the low half-window."""
+    pos = jnp.zeros(m.shape, jnp.int32)
+    for half in (16, 8, 4, 2, 1):
+        low = word & jnp.uint32((1 << half) - 1)
+        cnt = jax.lax.population_count(low).astype(jnp.int32)
+        high = m >= cnt
+        m = jnp.where(high, m - cnt, m)
+        word = jnp.where(high, word >> half, low)
+        pos = pos + jnp.where(high, half, 0)
+    return pos
+
+
+def one_positions(words: jax.Array, k: int) -> jax.Array:
+    """Bit positions (..., k) int32 of the first ``k`` set bits of packed
+    (..., W) uint32 words (little-endian). Entries past the last set bit
+    are unspecified."""
+    n_words = words.shape[-1]
+    ends = jnp.cumsum(jax.lax.population_count(words).astype(jnp.int32),
+                      axis=-1)                          # set bits through word w
+    j = jnp.arange(k, dtype=jnp.int32)
+    word_idx = jnp.zeros((*words.shape[:-1], k), jnp.int32)
+    for w in range(n_words - 1):
+        word_idx = word_idx + (ends[..., w:w + 1] <= j)
+    before = jnp.zeros_like(word_idx)
+    word = jnp.zeros(word_idx.shape, jnp.uint32)
+    for w in range(n_words):
+        here = word_idx == w
+        if w:
+            before = jnp.where(here, ends[..., w - 1:w], before)
+        word = jnp.where(here, words[..., w:w + 1], word)
+    return 32 * word_idx + _select_bit(word, j - before)
+
+
+def _shift_last(x: jax.Array, s: int) -> jax.Array:
+    """Shift along the last axis by ``s`` (>0: toward higher indices),
+    filling with zeros."""
+    pad = jnp.zeros((*x.shape[:-1], abs(s)), x.dtype)
+    if s > 0:
+        return jnp.concatenate([pad, x[..., :-s]], axis=-1)
+    return jnp.concatenate([x[..., -s:], pad], axis=-1)
+
+
+def _route(x: jax.Array, dist: jax.Array, live: jax.Array, sign: int,
+           steps) -> jax.Array:
+    """Move each live element of (..., n) ``x`` by ``sign * dist`` lanes
+    with a log-step shift network, one shift per bit of ``dist``; lanes
+    left empty read 0. Collision-free when the moving elements keep their
+    order and their distances are nondecreasing along them — compaction
+    (LSB step first) and its inverse, expansion (MSB step first)."""
+    for b in steps:
+        mv = live & (((dist >> b) & 1) == 1)
+        arrive = _shift_last(mv, sign << b)
+        x = jnp.where(arrive, _shift_last(x, sign << b), x)
+        dist = jnp.where(arrive, _shift_last(dist, sign << b), dist)
+        live = arrive | (live & ~mv)
+    return jnp.where(live, x, jnp.zeros((), x.dtype))
+
+
+def compact(x: jax.Array, mask: jax.Array, k: int) -> jax.Array:
+    """The first ``k`` elements of (..., n) ``x`` where ``mask``, in order —
+    ``take_along_axis(x, argsort(~mask, stable=True))[..., :k]`` for masks
+    with at least k set entries."""
+    n = x.shape[-1]
+    zeros_before = jnp.cumsum(~mask, axis=-1, dtype=jnp.int32)
+    out = _route(jnp.where(mask, x, jnp.zeros((), x.dtype)),
+                 jnp.where(mask, zeros_before, 0), mask, -1,
+                 range(max(n - 1, 1).bit_length()))
+    return out[..., :k]
+
+
+def expand(vals: jax.Array, pos: jax.Array, n: int) -> jax.Array:
+    """Scatter (..., k) ``vals`` to strictly increasing positions (..., k)
+    ``pos`` of a zero (..., n) array — the inverse of :func:`compact`."""
+    k = vals.shape[-1]
+    if k == n:
+        return vals
+    lead = vals.shape[:-1]
+    x = jnp.concatenate([vals, jnp.zeros((*lead, n - k), vals.dtype)], -1)
+    dist = jnp.concatenate(
+        [pos - jnp.arange(k, dtype=jnp.int32),
+         jnp.zeros((*lead, n - k), jnp.int32)], -1)
+    live = jnp.broadcast_to(jnp.arange(n) < k, x.shape)
+    return _route(x, dist, live, 1, range((n - k).bit_length())[::-1])
+
+
+def lookup256(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``table[idx]`` for a table of at most 256 entries in [0, 256) and
+    int indices in [0, 256), as two one-hot matmuls (exact: one nonzero
+    bf16 integer term per output). Returns int32."""
+    t = jnp.zeros(256, jnp.float32).at[:table.shape[0]].set(
+        table.astype(jnp.float32)).reshape(16, 16).astype(jnp.bfloat16)
+    i = idx.astype(jnp.int32)
+    hi = jax.nn.one_hot(i >> 4, 16, dtype=jnp.bfloat16)
+    lo = jax.nn.one_hot(i & 15, 16, dtype=jnp.bfloat16)
+    rows = jnp.dot(hi, t, preferred_element_type=jnp.float32)
+    return jnp.sum(rows * lo, axis=-1).astype(jnp.int32)
+
+
+def histogram256(x: jax.Array) -> jax.Array:
+    """Counts (256,) int32 of the uint8 values in ``x`` — ``bincount`` as
+    one-hot matmuls over (high, low) nibble pairs, in chunks small enough
+    that each f32 partial count is exact."""
+    i = x.reshape(-1).astype(jnp.int32)
+    chunk = min(1 << 22, max(i.shape[0], 1))
+    i = jnp.pad(i, (0, -i.shape[0] % chunk), constant_values=-1)
+    i = i.reshape(-1, chunk)                # padding -1 one-hots to zeros
+    hi = jax.nn.one_hot(i >> 4, 16, dtype=jnp.bfloat16)
+    lo = jax.nn.one_hot(jnp.where(i < 0, -1, i & 15), 16,
+                        dtype=jnp.bfloat16)
+    counts = jnp.einsum("cna,cnb->cab", hi, lo,
+                        preferred_element_type=jnp.float32)
+    return jnp.sum(counts.astype(jnp.int32), axis=0).reshape(256)

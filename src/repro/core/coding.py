@@ -23,8 +23,8 @@ region:
   max — far beyond anything observed in real tensors.
 
 Decoding mode 0 is the vectorised form of the paper's parallel zero counter:
-the positions of the ``1`` bits are recovered with a single prefix-sum over
-the bit lanes, and ``rank_j = pos_j - pos_{j-1} - 1``.
+the positions of the ``1`` bits are recovered from per-word popcounts, and
+``rank_j = pos_j - pos_{j-1} - 1``.
 
 All functions operate on blocked tensors ``(..., NB, K)`` (NB superblocks of
 K kept exponents each) and are jit-safe.
@@ -58,7 +58,7 @@ def build_codebook(exps: jax.Array) -> tuple[jax.Array, jax.Array]:
     frequent exponent. Ranks beyond the observed alphabet map past MAX_RANK
     so the encoder falls back to delta mode for blocks containing them.
     """
-    counts = jnp.bincount(exps.reshape(-1).astype(jnp.int32), length=256)
+    counts = bitops.histogram256(exps)
     order = jnp.argsort(-counts, stable=True)  # descending frequency
     exp_of_rank = order.astype(jnp.uint8)
     rank_of_exp = jnp.zeros(256, dtype=jnp.int32).at[order].set(jnp.arange(256))
@@ -83,27 +83,32 @@ def unary_encode_block(ranks: jax.Array,
     ends = jnp.cumsum(lens, axis=-1) - 1
     total = ends[..., -1] + 1
     ok = (total <= n_bits) & jnp.all(ranks < MAX_RANK, axis=-1)
-    # scatter 1s at `ends` (clipped; invalid blocks are discarded by `ok`)
+    # 1s at `ends` (clipped; invalid blocks are discarded by `ok`)
     pos = jnp.clip(ends, 0, n_bits - 1)
-    bits = jnp.zeros((*ranks.shape[:-1], n_bits), dtype=jnp.bool_)
-    bits = jnp.put_along_axis(bits, pos, True, axis=-1, inplace=False)
+    bits = bitops.expand(jnp.ones(ranks.shape, jnp.bool_), pos, n_bits)
     return bits, ok
 
 
-def unary_decode_block(bits: jax.Array, k: int) -> jax.Array:
-    """Decode a unary bitstream (..., n_bits) into ranks (..., K).
+def unary_decode_words(words: jax.Array, k: int) -> jax.Array:
+    """Decode a packed unary region (..., W) uint32 into ranks (..., K).
 
-    Vectorised parallel-zero-counter (paper Alg. 1): a stable argsort moves
-    the positions of the ``1`` bits to the front in order (equivalently, a
-    prefix-sum over the bit lanes), and ``rank_j = pos_j - pos_{j-1} - 1``.
+    Vectorised parallel-zero-counter (paper Alg. 1): ``pos_j``, the bit
+    position of the (j+1)-th ``1``, comes from word popcounts
+    (``bitops.one_positions``), and ``rank_j = pos_j - pos_{j-1} - 1``.
+    Exact for every stream the encoder marks ``ok``; other streams
+    (delta-mode blocks) decode to unused values.
     """
-    # stable argsort of ~bits: positions of ones, in order, come first
-    positions = jnp.argsort(~bits, axis=-1, stable=True)[..., :k].astype(jnp.int32)
+    pos = bitops.one_positions(words, k)
     prev = jnp.concatenate(
-        [jnp.full((*positions.shape[:-1], 1), -1, positions.dtype),
-         positions[..., :-1]], axis=-1)
-    ranks = positions - prev - 1
-    return jnp.clip(ranks, 0, MAX_RANK - 1).astype(jnp.uint8)
+        [jnp.full((*pos.shape[:-1], 1), -1, pos.dtype), pos[..., :-1]],
+        axis=-1)
+    return jnp.clip(pos - prev - 1, 0, MAX_RANK - 1).astype(jnp.uint8)
+
+
+def unary_decode_block(bits: jax.Array, k: int) -> jax.Array:
+    """Decode a unary bitstream (..., n_bits) of bools into ranks (..., K)
+    (``n_bits`` a multiple of 32); see :func:`unary_decode_words`."""
+    return unary_decode_words(bitops.pack_bits(bits), k)
 
 
 # ---------------------------------------------------------------------------
@@ -163,12 +168,6 @@ def _pack_fixed(codes: jax.Array, exp_bits: int, n_bits: int) -> jax.Array:
     return flat
 
 
-def _unpack_fixed(bits: jax.Array, exp_bits: int, k: int) -> jax.Array:
-    sel = bits[..., : k * exp_bits].reshape(*bits.shape[:-1], k, exp_bits)
-    shifts = jnp.arange(exp_bits, dtype=jnp.uint32)
-    return jnp.sum(sel.astype(jnp.uint32) << shifts, axis=-1).astype(jnp.uint8)
-
-
 def trim_codebook(exp_of_rank: jax.Array) -> jax.Array:
     """Keep only the MAX_RANK entries the unary decoder can address."""
     return exp_of_rank[:MAX_RANK]
@@ -188,7 +187,7 @@ def encode_exponents(exps: jax.Array, rank_of_exp: jax.Array, exp_bits: int = 3,
     """
     k = exps.shape[-1]
     n_bits = region_words(k, exp_bits) * 32
-    ranks = rank_of_exp[exps.astype(jnp.int32)]
+    ranks = bitops.lookup256(rank_of_exp, exps)
     ubits, ok = unary_encode_block(ranks, n_bits)
     emax = jnp.max(exps, axis=-1)
     dcodes, dcorr = delta_encode_block(exps, emax, exp_bits, corr_bits)
@@ -213,11 +212,9 @@ def decode_exponents(region: dict[str, jax.Array], exp_of_rank: jax.Array,
     ``exact=False`` is the draft view (speculation data only); ``exact=True``
     additionally applies the verification corrections.
     """
-    n_bits = region_words(k, exp_bits) * 32
-    bits = bitops.unpack_bits(region["words"], n_bits)
-    uranks = unary_decode_block(bits, k)
-    uexps = exp_of_rank[uranks.astype(jnp.int32)]
-    dcodes = _unpack_fixed(bits, exp_bits, k)
+    uranks = unary_decode_words(region["words"], k)
+    uexps = bitops.lookup256(exp_of_rank, uranks)
+    dcodes = bitops.unpack_codes(region["words"], exp_bits, k)
     corr = None
     if exact and region.get("corr") is not None:
         # corr may have been trimmed away when every block is mode-0 (unary

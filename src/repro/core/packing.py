@@ -5,7 +5,7 @@ weight with its packed ``{"spec", "verif"}`` partition. Small / accuracy-
 critical leaves stay full precision: embeddings (row lookups — no bandwidth
 win), MoE routers (paper keeps them exact), norms, biases, convs, SSM
 A_log/D/dt. Stacked (scan) weights of shape (R, in, out) are packed per
-layer via vmap.
+layer (``lax.map`` over the stack).
 
 Wanda calibration: ``Calibrator`` records per-input-channel activation L2
 norms during an (unjitted) calibration forward; ``format_params`` consumes
@@ -15,6 +15,7 @@ breaks (measured in benchmarks/acceptance.py).
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any
 
 import jax
@@ -70,25 +71,37 @@ def _should_pack(parent_key: str, w: jax.Array) -> bool:
     return n_in % 32 == 0
 
 
-def _pack_weight(w: jax.Array, act_norm, cass: CassandraConfig, trim: bool):
-    def one(wl, an):
-        wt = wl.T
-        if an is None:
-            scores = jnp.abs(wt.astype(jnp.float32))
-        else:
-            from repro.core import pruning
-            scores = pruning.wanda_scores(wl, an).T
-        block = cass.weight_block(wl.shape[0])
-        keep = cass.weight_keep(block)
-        return fmt.format_tensor(wt, scores, cass, block, keep,
-                                 cass.mx_group, cass.weight_trunc)
-
-    if w.ndim == 2:
-        spec, verif = one(w, act_norm)
-    elif act_norm is None:
-        spec, verif = jax.vmap(lambda wl: one(wl, None))(w)
+def _format_layer(w: jax.Array, act_norm, cass: CassandraConfig):
+    """Format one (in, out) weight (blocked along ``in``)."""
+    wt = w.T
+    if act_norm is None:
+        scores = jnp.abs(wt.astype(jnp.float32))
     else:
-        spec, verif = jax.vmap(one)(w, act_norm)
+        from repro.core import pruning
+        scores = pruning.wanda_scores(w, act_norm).T
+    block = cass.weight_block(w.shape[0])
+    keep = cass.weight_keep(block)
+    return fmt.format_tensor(wt, scores, cass, block, keep,
+                             cass.mx_group, cass.weight_trunc)
+
+
+@partial(jax.jit, static_argnames=("cass",))
+def _format_stack(w: jax.Array, act_norm, cass: CassandraConfig):
+    """Format a stacked (R, in, out) weight one layer at a time.
+
+    ``lax.map`` keeps one layer's formatting temporaries live; a ``vmap``
+    over the stack holds all R of them at once, which for a 28-36 layer
+    FFN stack exceeds one accelerator's memory."""
+    if act_norm is None:
+        return jax.lax.map(lambda wl: _format_layer(wl, None, cass), w)
+    return jax.lax.map(lambda xs: _format_layer(*xs, cass), (w, act_norm))
+
+
+def _pack_weight(w: jax.Array, act_norm, cass: CassandraConfig, trim: bool):
+    if w.ndim == 2:
+        spec, verif = _format_layer(w, act_norm, cass)
+    else:
+        spec, verif = _format_stack(w, act_norm, cass)
     if trim:  # host sync — concrete values only (offline formatting)
         spec, verif = fmt._trim_lossless(spec, verif, cass.variant)
     return {"spec": spec, "verif": verif}
@@ -123,6 +136,45 @@ def format_params(params: Any, cass: CassandraConfig,
         return node
 
     return walk(params, "", "")
+
+
+@partial(jax.jit, static_argnames=("cass", "shape"))
+def _decode_views(spec: dict, verif: dict, cass: CassandraConfig,
+                  shape: tuple[int, int]) -> dict:
+    def one(sv):
+        return {"draft": fmt.draft_weight(sv[0], cass, shape),
+                "target": fmt.target_weight(sv[0], sv[1], cass, shape)}
+
+    if spec["bitmap"].ndim == 3:                  # one (in, out) weight
+        return one((spec, verif))
+    return jax.lax.map(one, (spec, verif))        # (R, ...) stack
+
+
+def resolve_views(params: Any, cass: CassandraConfig) -> Any:
+    """Decode every packed weight once into its dense draft and target views.
+
+    Weights do not change while serving, but ``layers.dense`` would
+    otherwise rebuild the whole model's dense weights from the packed
+    streams on each of the γ draft passes and on the verify pass — a
+    decode far dearer than the matmuls it feeds on a TPU. The price is two
+    dense bf16 copies of the packed weights. Each ``{"spec", "verif"}``
+    leaf becomes ``{"draft": w, "target": w}`` with ``w`` of the weight's
+    (..., in, out) shape; ``layers.resolve_weight`` picks the view.
+    """
+    def walk(node):
+        if isinstance(node, dict):
+            if "spec" in node and "verif" in node:
+                bitmap = node["spec"]["bitmap"]   # (..., out, NB, block//32)
+                shape = (bitmap.shape[-2] * bitmap.shape[-1] * 32,
+                         bitmap.shape[-3])
+                return _decode_views(node["spec"], node["verif"], cass,
+                                     shape)
+            return {k: walk(v) for k, v in node.items()}
+        if isinstance(node, list):
+            return [walk(v) for v in node]
+        return node
+
+    return walk(params)
 
 
 def params_nbytes(params: Any) -> dict[str, int]:

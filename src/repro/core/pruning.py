@@ -60,8 +60,7 @@ def select_topk_blocked(values: jax.Array, scores: jax.Array, keep: int,
     nb = n // block
     v = values.reshape(*values.shape[:-1], nb, block)
     s = scores.reshape(*scores.shape[:-1], nb, block).astype(jnp.float32)
-    # threshold = keep-th largest score per block
-    kth = -jnp.sort(-s, axis=-1)[..., keep - 1: keep]       # (..., NB, 1)
+    kth = _kth_largest(s, keep)                              # (..., NB, 1)
     # break ties by position: among score==kth keep the earliest so the
     # total kept count is exactly `keep`
     ge = s > kth
@@ -72,10 +71,24 @@ def select_topk_blocked(values: jax.Array, scores: jax.Array, keep: int,
     mask = ge | take_eq                                      # exactly keep ones
     bitmap = bitops.pack_bits(mask).astype(jnp.uint32)
     # stable compaction: kept values in position order
-    order = jnp.argsort(~mask, axis=-1, stable=True)
-    gathered = jnp.take_along_axis(v, order, axis=-1)
-    return {"bitmap": bitmap, "kept": gathered[..., :keep],
-            "pruned": gathered[..., keep:]}
+    return {"bitmap": bitmap, "kept": bitops.compact(v, mask, keep),
+            "pruned": bitops.compact(v, ~mask, block - keep)}
+
+
+def _kth_largest(s: jax.Array, k: int) -> jax.Array:
+    """(..., n) non-negative f32 -> (..., 1) k-th largest value.
+
+    Non-negative floats order like their bit patterns, so a 31-step
+    binary search on the pattern (counting entries at or above each
+    candidate) finds it with elementwise work — no sort, which is slow on
+    a TPU."""
+    bits = jax.lax.bitcast_convert_type(s, jnp.int32)
+    t = jnp.zeros((*s.shape[:-1], 1), jnp.int32)
+    for b in range(30, -1, -1):
+        cand = t | (1 << b)
+        enough = jnp.sum(bits >= cand, axis=-1, keepdims=True) >= k
+        t = jnp.where(enough, cand, t)
+    return jax.lax.bitcast_convert_type(t, jnp.float32)
 
 
 @partial(jax.jit, static_argnames=("block",))
@@ -90,15 +103,12 @@ def desparsify(bitmap: jax.Array, kept: jax.Array, block: int,
     if pruned is not None and pruned.shape[-1] == 0:
         pruned = None                       # keep == block: nothing pruned
     mask = bitops.unpack_bits(bitmap, block)                  # (..., NB, block)
-    rank = jnp.cumsum(mask, axis=-1) - 1                      # kept index
-    keep = kept.shape[-1]
-    kidx = jnp.clip(rank, 0, keep - 1)
-    dense = jnp.take_along_axis(kept, kidx, axis=-1)
+    dense = bitops.expand(kept, bitops.one_positions(bitmap, kept.shape[-1]),
+                          block)
     if pruned is None:
         dense = jnp.where(mask, dense, jnp.zeros_like(dense))
     else:
-        prank = jnp.cumsum(~mask, axis=-1) - 1
-        pidx = jnp.clip(prank, 0, pruned.shape[-1] - 1)
-        pdense = jnp.take_along_axis(pruned, pidx, axis=-1)
+        pdense = bitops.expand(
+            pruned, bitops.one_positions(~bitmap, pruned.shape[-1]), block)
         dense = jnp.where(mask, dense, pdense)
     return dense.reshape(*dense.shape[:-2], -1)

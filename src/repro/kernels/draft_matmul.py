@@ -1,5 +1,5 @@
 """Fused Cassandra-decode + matmul Pallas kernel — the paper's decoder on
-the TPU memory path (DESIGN.md §2).
+the TPU memory path.
 
 ``y = x @ draft_weight(spec)`` where the weight never exists densely in
 HBM: each grid step streams one packed superblock tile (bitmap + 4-bit
@@ -17,9 +17,9 @@ is identical to the unary region (the static-superblock budget is
 a bit-serial scan. The paper-faithful unary decoder (parallel zero counter,
 Alg. 1) lives in ``unary_decode.py`` and is used on the KV path.
 
-All bit unpacking is static reshape+shift (no dynamic gather); the only
-dynamic lane gather is the bitmap de-sparsification ``take_along_axis``,
-the vector form of the paper's decoder step 5.
+Bit unpacking is per-lane word selection plus shifts, the bitmap
+de-sparsification (the paper's decoder step 5) a prefix-count matmul and a
+128-lane-windowed gather — see ``kernels.unpack``.
 """
 from __future__ import annotations
 
@@ -30,66 +30,48 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax ≥0.5 renamed TPUCompilerParams → CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels import unpack as U
 
 MANT_BITS = 7
 
 
-def _unpack_bits32(words: jax.Array, n: int) -> jax.Array:
-    """(R, W) u32 -> (R, n) int32 bits, little-endian within each word."""
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    bits = (words[..., None] >> shifts) & jnp.uint32(1)
-    return bits.reshape(*words.shape[:-1], words.shape[-1] * 32
-                        )[..., :n].astype(jnp.int32)
-
-
-def _unpack_codes32(words: jax.Array, width: int, k: int) -> jax.Array:
-    """(R, W) u32 -> (R, k) int32 codes of ``width`` bits (static layout)."""
-    bits = _unpack_bits32(words, words.shape[-1] * 32)
-    sel = bits[..., : k * width].reshape(*bits.shape[:-1], k, width)
-    return jnp.sum(sel << jnp.arange(width, dtype=jnp.int32), axis=-1)
-
-
 def _decode_tile(bitmap, signmant, exp3, emax, book, *, block, keep, trunc,
                  exp_bits):
-    """Reconstruct a (TN, block) bf16 draft-weight tile from packed refs."""
+    """Reconstruct a (TN, block) bf16 draft-weight tile from packed rows.
+
+    ``emax`` is a (TN, 1) int32 column; ``book`` the 8-entry rank
+    codebook (array or SMEM ref)."""
     t_keep = MANT_BITS - trunc
     esc = (1 << exp_bits) - 1
     # sign|mant codes, (TN, keep)
-    code = _unpack_codes32(signmant, 1 + t_keep, keep)
+    code = U.unpack_fixed(signmant, 1 + t_keep, keep)
     sign = (code >> t_keep) & 1
     mant = (code & ((1 << t_keep) - 1)) << trunc
     # 3-bit exponent rank codes -> exponents via 8-entry codebook selects
-    r3 = _unpack_codes32(exp3, exp_bits, keep)            # (TN, keep)
-    exp = jnp.where(r3 == esc, emax.astype(jnp.int32)[:, None], 0)
+    r3 = U.unpack_fixed(exp3, exp_bits, keep)
+    exp = jnp.where(r3 == esc, emax, 0)
     for r in range(esc):
-        exp = exp + jnp.where(r3 == r, book[r].astype(jnp.int32), 0)
+        exp = exp + jnp.where(r3 == r, book[r], 0)
     kept16 = (sign << 15) | (exp << 7) | mant             # (TN, keep) i32
-    # bitmap de-sparsification (decoder step 5): prefix-sum + lane gather
-    bits = _unpack_bits32(bitmap, block)                  # (TN, block)
-    rank = jnp.cumsum(bits, axis=-1) - 1
-    dense16 = jnp.take_along_axis(kept16, jnp.clip(rank, 0, keep - 1),
-                                  axis=-1)
-    dense16 = jnp.where(bits == 1, dense16, 0).astype(jnp.uint16)
-    return jax.lax.bitcast_convert_type(dense16, jnp.bfloat16)
+    dense16 = U.desparsify(kept16, U.unpack_fixed(bitmap, 1, block))
+    return jax.lax.bitcast_convert_type(dense16.astype(jnp.uint16),
+                                        jnp.bfloat16)
 
 
-def _kernel(x_ref, bitmap_ref, sm_ref, exp3_ref, emax_ref, book_ref, o_ref,
+def _kernel(book_ref, x_ref, bitmap_ref, sm_ref, exp3_ref, emax_ref, o_ref,
             *, block, keep, trunc, exp_bits):
     k_idx = pl.program_id(2)
-    w_tile = _decode_tile(bitmap_ref[:, 0], sm_ref[:, 0], exp3_ref[:, 0],
-                          emax_ref[:, 0], book_ref[...], block=block,
-                          keep=keep, trunc=trunc, exp_bits=exp_bits)
+    w_tile = _decode_tile(bitmap_ref[0], sm_ref[0], exp3_ref[0],
+                          emax_ref[0], book_ref, block=block, keep=keep,
+                          trunc=trunc, exp_bits=exp_bits)
 
     @pl.when(k_idx == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32),
-                          w_tile.T.astype(jnp.float32),
-                          preferred_element_type=jnp.float32)
+    o_ref[...] += jax.lax.dot_general(
+        x_ref[...].astype(jnp.float32), w_tile.astype(jnp.float32),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 @partial(jax.jit, static_argnames=("block", "keep", "trunc", "exp_bits",
@@ -101,32 +83,36 @@ def draft_matmul(x: jax.Array, bitmap: jax.Array, signmant: jax.Array,
                  interpret: bool = False) -> jax.Array:
     """x (M, K) @ packed-draft-weight (K, N) -> (M, N) fp32.
 
-    Operand layout (N-major, from ``ops.prepare_draft_operands``):
-      bitmap (N, NB, block//32) u32 · signmant (N, NB, Wsm) u32 ·
-      exp3 (N, NB, We) u32 · emax (N, NB) i32 · book (8,) i32
+    Operand layout (superblock-major, from ``ops.prepare_draft_operands``):
+      bitmap (NB, N, block//32) u32 · signmant (NB, N, Wsm) u32 ·
+      exp3 (NB, N, We) u32 · emax (NB, N, 1) i32 · book (8,) i32
+    so every block's last two dims are (tn, full) — Mosaic's tiling rule.
     """
     m, k_in = x.shape
-    n, nb = bitmap.shape[0], bitmap.shape[1]
+    nb, n = bitmap.shape[0], bitmap.shape[1]
     assert nb * block == k_in, (nb, block, k_in)
     tm, tn = min(tm, m), min(tn, n)
-    grid = (m // tm, n // tn, nb)
 
+    def packed_spec(a):
+        return pl.BlockSpec((1, tn, a.shape[-1]),
+                            lambda i, j, k, *_: (k, j, 0))
+
+    if interpret:
+        mode = {"interpret": True}
+    else:
+        mode = {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))}
     return pl.pallas_call(
         partial(_kernel, block=block, keep=keep, trunc=trunc,
                 exp_bits=exp_bits),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tm, block), lambda i, j, k: (i, k)),
-            pl.BlockSpec((tn, 1, block // 32), lambda i, j, k: (j, k, 0)),
-            pl.BlockSpec((tn, 1, signmant.shape[-1]),
-                         lambda i, j, k: (j, k, 0)),
-            pl.BlockSpec((tn, 1, exp3.shape[-1]), lambda i, j, k: (j, k, 0)),
-            pl.BlockSpec((tn, 1), lambda i, j, k: (j, k)),
-            pl.BlockSpec((8,), lambda i, j, k: (0,)),
-        ],
-        out_specs=pl.BlockSpec((tm, tn), lambda i, j, k: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(m // tm, n // tn, nb),
+            in_specs=[pl.BlockSpec((tm, block), lambda i, j, k, *_: (i, k)),
+                      packed_spec(bitmap), packed_spec(signmant),
+                      packed_spec(exp3), packed_spec(emax)],
+            out_specs=pl.BlockSpec((tm, tn), lambda i, j, k, *_: (i, j)),
+        ),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(x, bitmap, signmant, exp3, emax, book)
+        **mode,
+    )(book, x, bitmap, signmant, exp3, emax)

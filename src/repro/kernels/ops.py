@@ -46,12 +46,16 @@ def prepare_draft_operands(spec: dict, cass: CassandraConfig,
     code3 = jnp.full(exps.shape, ESC, jnp.uint32)
     for r in range(ESC):
         code3 = jnp.where(exps == book32[r], jnp.uint32(r), code3)
-    # escape decodes to emax — keep exact when the value IS emax
+    # escape decodes to emax — keep exact when the value IS emax.
+    # Superblock-major (NB, N, ...) so a kernel block is (1, tn, full).
+    def nb_major(a):
+        return jnp.swapaxes(a, 0, 1)
+
     return {
-        "bitmap": spec["bitmap"],
-        "signmant": spec["signmant"],
-        "exp3": bitops.pack_codes(code3, cass.exp_bits),
-        "emax": spec["exp_emax"].astype(jnp.int32),
+        "bitmap": nb_major(spec["bitmap"]),
+        "signmant": nb_major(spec["signmant"]),
+        "exp3": nb_major(bitops.pack_codes(code3, cass.exp_bits)),
+        "emax": nb_major(spec["exp_emax"].astype(jnp.int32))[..., None],
         "book": jnp.pad(book32[:ESC].astype(jnp.int32), (0, 8 - ESC)),
     }
 
@@ -61,8 +65,9 @@ def draft_matmul(x: jax.Array, spec: dict, cass: CassandraConfig,
                  ) -> jax.Array:
     """x (..., K) @ draft weight — fused decode+matmul kernel (C-1 only)."""
     if cass.variant != 1:
-        from repro.kernels import ref
-        return ref.draft_matmul_ref(x, spec, cass, shape).astype(x.dtype)
+        raise NotImplementedError(
+            f"the fused draft matmul decodes Cassandra-1 only, not "
+            f"variant {cass.variant}; use the jnp path (kernels='jnp')")
     n_in, n_out = shape
     block = cass.weight_block(n_in)
     keep = cass.weight_keep(block)
@@ -94,8 +99,9 @@ def draft_matmul_rank3_oracle(x: jax.Array, spec: dict,
     block = cass.weight_block(n_in)
     keep = cass.weight_keep(block)
     ops_ = prepare_draft_operands(spec, cass, shape)
-    code3 = bitops.unpack_codes(ops_["exp3"], cass.exp_bits, keep)
-    exps = jnp.where(code3 == ESC, ops_["emax"][..., None],
+    code3 = jnp.swapaxes(
+        bitops.unpack_codes(ops_["exp3"], cass.exp_bits, keep), 0, 1)
+    exps = jnp.where(code3 == ESC, jnp.swapaxes(ops_["emax"], 0, 1),
                      jnp.take(ops_["book"], jnp.minimum(code3, ESC - 1)
                               ).astype(jnp.int32))
     t_keep = 7 - cass.weight_trunc

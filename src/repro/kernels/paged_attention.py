@@ -20,11 +20,11 @@ Two variants behind one family of entry points:
 * **packed** — the pool blocks are the Cassandra C-1 spec leaves
   (bitmap / signmant / exp words / mode / emax); the rank-codebook
   reconstruction (``unary_decode``-style compare-sum ranks + 3-bit delta
-  exponents, ``draft_matmul._decode_tile``-style unpacking) runs inside
-  the kernel between the VMEM load and the QK dot. Draft-pass KV never
-  exists densely in HBM. ``paged_gqa_packed``. (MLA caches cannot be
-  packed repo-wide — ``qk_rope_dim=16`` fails the 32-lane pack — so the
-  packed variant is GQA-only.)
+  exponents, unpacked with ``kernels.unpack``) runs inside the kernel
+  between the VMEM load and the QK dot. Draft-pass KV never exists
+  densely in HBM. ``paged_gqa_packed``. (MLA caches cannot be packed
+  repo-wide — ``qk_rope_dim=16`` fails the 32-lane pack — so the packed
+  variant is GQA-only.)
 
 Each entry point takes ``impl`` ∈ {"jnp", "interpret", "pallas"}:
 ``jnp`` is the gather-then-scan reference built from the *same* per-block
@@ -41,21 +41,19 @@ pool) with one more flash step — see ``merge_gqa_suffix`` /
 from __future__ import annotations
 
 import functools
-from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import unpack as U
+
 NEG_INF = -1e30
 # Unused table slots point at block 0 by convention (the trash block,
 # same contract as serving.kvcache.TRASH_BLOCK / append_paged_batched).
 # Kept as a local constant so kernels/ does not import serving/.
 TRASH_BLOCK = 0
-
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None))
 
 
 def sanitize_table(table: jax.Array, num_blocks: int) -> jax.Array:
@@ -73,67 +71,51 @@ def sanitize_table(table: jax.Array, num_blocks: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Cassandra C-1 spec decode (bit-exact replica of the kvcache.read_store
 # draft view: coding.decode_exponents + format._join_kept_draft +
-# pruning.desparsify), written in the 2-D unrolled style Pallas lowers.
+# pruning.desparsify), written in the 2-D style Mosaic lowers.
 # ---------------------------------------------------------------------------
 
 
-def _unpack_bits32(words: jax.Array, n: int) -> jax.Array:
-    """(R, W) uint32 words -> (R, n) int32 bits, little-endian."""
-    r, w = words.shape
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    bits = (words[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1)
-    return bits.reshape(r, w * 32)[:, :n].astype(jnp.int32)
-
-
-def _unpack_codes32(words: jax.Array, width: int, k: int) -> jax.Array:
-    """(R, W) uint32 words -> (R, k) int32 codes of ``width`` bits."""
-    bits = _unpack_bits32(words, k * width).reshape(words.shape[0], k, width)
-    shifts = jnp.arange(width, dtype=jnp.int32)
-    return jnp.sum(bits << shifts[None, None, :], axis=-1)
-
-
-def _unary_ranks(bits: jax.Array, keep: int, pchunk: int = 128) -> jax.Array:
+def _unary_ranks(bits: jax.Array, keep: int) -> jax.Array:
     """Compare-sum unary rank decode (kernels/unary_decode.py Alg. 1).
 
     ``bits`` is the (R, n) 0/1 stream; returns (R, keep) int32 ranks in
-    [0, 31]. VMEM-bounded: the position search runs in ``pchunk``-wide
-    column chunks instead of one (R, keep, n) broadcast.
+    [0, 31]. Rank j is the gap between the j-th and (j+1)-th set bits:
+    ``pos[j] = #{p : idx[p] < j+1}`` (strict compare — ``<=`` lands on
+    the next bit) is the 0-indexed position of the (j+1)-th set bit,
+    with ``idx`` the inclusive prefix count. One lane reduction per rank.
     """
-    r, n = bits.shape
-    idx = jnp.cumsum(bits, axis=-1)           # ones seen through col p
-    # NB: arange(0, n) + 1, not arange(1, n+1) — the latter materialises
-    # eagerly and Pallas rejects kernels that close over array constants.
-    ks = jnp.arange(keep, dtype=jnp.int32) + 1
-    pos = jnp.zeros((r, keep), dtype=jnp.int32)
-    for p0 in range(0, n, pchunk):
-        chunk = idx[:, p0:p0 + pchunk]
-        # pos[j] = #{p : idx[p] < j+1} = 0-indexed position of the
-        # (j+1)-th set bit (strict compare — <= lands on the next bit)
-        pos = pos + jnp.sum(
-            (chunk[:, None, :] < ks[None, :, None]).astype(jnp.int32),
-            axis=-1)
-    prev = jnp.concatenate(
-        [jnp.full((r, 1), -1, dtype=jnp.int32), pos[:, :-1]], axis=-1)
+    r = bits.shape[0]
+    idx = U.prefix_count(bits)
+    lane = U.iota((r, keep), 1)
+
+    def body(j, carry):
+        pos, prev = carry
+        cnt = jnp.sum((idx < j + 1).astype(jnp.int32), axis=1, keepdims=True)
+        return (jnp.where(lane == j, cnt, pos),
+                jnp.where(lane == j + 1, cnt, prev))
+
+    pos, prev = jax.lax.fori_loop(
+        0, keep, body, (jnp.zeros((r, keep), jnp.int32),
+                        jnp.full((r, keep), -1, jnp.int32)))
     return jnp.clip(pos - prev - 1, 0, 31)
 
 
 def _decode_kv_rows(bitmap: jax.Array, signmant: jax.Array,
                     exp_words: jax.Array, mode: jax.Array, emax: jax.Array,
-                    book32: jax.Array, *, d: int, keep: int, trunc: int,
+                    book32, *, d: int, keep: int, trunc: int,
                     exp_bits: int) -> jax.Array:
     """Decode (R,) Cassandra C-1 spec rows -> (R, d) bf16.
 
     Bit-exact vs the host draft view (``read_store`` with
     ``view="draft"``): unary/delta exponent reconstruction without the
     verif correction, truncated mantissas, desparsified against the
-    bitmap. ``book32`` is ``exp_of_rank[:32]`` as int32.
+    bitmap. ``mode``/``emax`` are (R, 1) int32 columns; ``book32`` is
+    ``exp_of_rank[:32]`` as int32 — an array, or the kernel's SMEM ref.
     """
-    r = bitmap.shape[0]
     t_keep = 7 - trunc
-    width = 1 + t_keep
     esc = (1 << exp_bits) - 1
 
-    code = _unpack_codes32(signmant, width, keep)       # (R, keep)
+    code = U.unpack_fixed(signmant, 1 + t_keep, keep)    # (R, keep)
     sign = (code >> t_keep) & 1
     mant = (code & ((1 << t_keep) - 1)) << trunc
 
@@ -141,30 +123,33 @@ def _decode_kv_rows(bitmap: jax.Array, signmant: jax.Array,
     # unary stream may run into the region's word-padding past
     # keep*exp_bits bits (encode_exponents sizes the region in whole
     # uint32 words), so rank-decode over the FULL region width.
-    ebits = _unpack_bits32(exp_words, exp_words.shape[1] * 32)
-    uranks = _unary_ranks(ebits, keep)                   # (R, keep)
-    uexp = jnp.zeros((r, keep), dtype=jnp.int32)
+    ebits = U.unpack_fixed(exp_words, 1, exp_words.shape[1] * 32)
+    uranks = _unary_ranks(ebits, keep)
+    uexp = jnp.zeros_like(uranks)
     for rk in range(32):
         uexp = uexp + jnp.where(uranks == rk, book32[rk], 0)
 
-    dcodes = jnp.sum(
-        ebits[:, :keep * exp_bits].reshape(r, keep, exp_bits)
-        << jnp.arange(exp_bits, dtype=jnp.int32)[None, None, :],
-        axis=-1)
-    dexp = jnp.clip(emax[:, None] - dcodes, 0, 255)
+    dcodes = U.unpack_fixed(exp_words, exp_bits, keep)
+    dexp = jnp.clip(emax - dcodes, 0, 255)
     dexp = jnp.where(dcodes == esc, 0, dexp)
 
-    exp = jnp.where((mode == 0)[:, None], uexp, dexp)
+    exp = jnp.where(mode == 0, uexp, dexp)
+    kept16 = (sign << 15) | (exp << 7) | mant
+    dense16 = U.desparsify(kept16, U.unpack_fixed(bitmap, 1, d))
+    return jax.lax.bitcast_convert_type(dense16.astype(jnp.uint16),
+                                        jnp.bfloat16)
 
-    kept16 = ((sign << 15) | (exp << 7) | mant).astype(jnp.int32)
 
-    # desparsify against the bitmap
-    bbits = _unpack_bits32(bitmap, d)                    # (R, d)
-    rank = jnp.cumsum(bbits, axis=-1) - 1
-    gidx = jnp.clip(rank, 0, keep - 1)
-    dense16 = jnp.take_along_axis(kept16, gidx, axis=-1)
-    dense16 = jnp.where(bbits == 1, dense16, 0).astype(jnp.uint16)
-    return jax.lax.bitcast_convert_type(dense16, jnp.bfloat16)
+def _spec_rows(spec: dict, lead: tuple[int, ...], rows: int) -> tuple:
+    """Spec leaves (..., 1, W) / (..., 1) -> (*lead, rows, W) word planes
+    and (*lead, rows, 1) int32 mode/emax columns."""
+    return (
+        spec["bitmap"].reshape(*lead, rows, -1),
+        spec["signmant"].reshape(*lead, rows, -1),
+        spec["exp_words"].reshape(*lead, rows, -1),
+        spec["exp_mode"].reshape(*lead, rows, 1).astype(jnp.int32),
+        spec["exp_emax"].reshape(*lead, rows, 1).astype(jnp.int32),
+    )
 
 
 @functools.partial(jax.jit,
@@ -181,15 +166,9 @@ def decode_spec_pool(spec: dict, book: jax.Array, *, d: int, keep: int,
     flash state, whose float association order is compile-dependent.
     """
     nb, bs, hkv = spec["bitmap"].shape[:3]
-    rows = nb * bs * hkv
-    out = _decode_kv_rows(
-        spec["bitmap"].reshape(rows, -1),
-        spec["signmant"].reshape(rows, -1),
-        spec["exp_words"].reshape(rows, -1),
-        spec["exp_mode"].reshape(rows).astype(jnp.int32),
-        spec["exp_emax"].reshape(rows).astype(jnp.int32),
-        book[:32].astype(jnp.int32),
-        d=d, keep=keep, trunc=trunc, exp_bits=exp_bits)
+    out = _decode_kv_rows(*_spec_rows(spec, (), nb * bs * hkv),
+                          book[:32].astype(jnp.int32), d=d, keep=keep,
+                          trunc=trunc, exp_bits=exp_bits)
     return out.reshape(nb, bs, hkv, d)
 
 
@@ -197,55 +176,77 @@ def decode_spec_pool(spec: dict, book: jax.Array, *, d: int, keep: int,
 # Shared per-block flash step helpers. The Pallas kernel bodies and the
 # jnp gather reference call the *same* functions on identically-shaped
 # operands, which is what makes the parity contract bitwise.
+#
+# Everything is 2-D. A GQA block holds all Hkv heads of BS tokens as
+# (BS*Hkv, D) rows ordered (token, head) — the pool's own memory order —
+# and the queries of a row are (Hkv*G*T, D) rows ordered (head, group,
+# token). One matmul scores every query row against every key row; the
+# pairs whose heads differ are masked out, which costs Hkv× the QK/PV
+# MACs of a per-head loop but needs no in-kernel transpose.
 # ---------------------------------------------------------------------------
 
 
-def _gqa_block(q: jax.Array, kb: jax.Array, vb: jax.Array,
-               valid: jax.Array, m: jax.Array, l: jax.Array,
-               acc: jax.Array, *, scale: float):
-    """One flash step over a (S, Hkv, D) KV block.
+def _dot_nt(a: jax.Array, b: jax.Array) -> jax.Array:
+    """(M, K) x (N, K) -> (M, N) f32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
-    q: (T, Hkv, G, D) f32 · kb/vb: (S, Hkv, Dk)/(S, Hkv, Dv) ·
-    valid: (S,) bool · m/l: (Hkv, G, T) f32 · acc: (Hkv, G, T, Dv) f32.
+
+def _flash_update(s: jax.Array, valid: jax.Array, v: jax.Array,
+                  m: jax.Array, l: jax.Array, acc: jax.Array):
+    """Fold (M, N) scores against (N, Dv) f32 values into (m, l, acc)."""
+    s = jnp.where(valid, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m - m_new)
+    l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_new = acc * corr + jnp.dot(p, v, preferred_element_type=jnp.float32)
+    return m_new, l_new, acc_new
+
+
+def _gqa_block(q: jax.Array, kb: jax.Array, vb: jax.Array, start, length,
+               m: jax.Array, l: jax.Array, acc: jax.Array, *, scale: float,
+               hkv: int, rows_per_head: int):
+    """One flash step over a KV block of BS tokens x Hkv heads.
+
+    q: (Hkv*G*T, D) f32 · kb/vb: (BS*Hkv, Dk)/(BS*Hkv, Dv) · start: the
+    block's first token position · length: the row's prefix length ·
+    m/l: (Hkv*G*T, 1) f32 · acc: (Hkv*G*T, Dv) f32.
     Invalid rows are zeroed on the *value* operand too: a masked packed
     lane can decode to NaN and 0·NaN would poison the accumulator.
     """
-    vb = jnp.where(valid[:, None, None], vb, 0).astype(vb.dtype)
-    s = jnp.einsum("thgd,shd->hgts", q, kb.astype(jnp.float32)) * scale
-    s = jnp.where(valid[None, None, None, :], s, NEG_INF)
-    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-    p = jnp.where(valid[None, None, None, :],
-                  jnp.exp(s - m_new[..., None]), 0.0)
-    corr = jnp.exp(m - m_new)
-    l_new = l * corr + jnp.sum(p, axis=-1)
-    acc_new = acc * corr[..., None] + jnp.einsum(
-        "hgts,shd->hgtd", p, vb.astype(jnp.float32))
-    return m_new, l_new, acc_new
+    vb = jnp.where(start + U.iota(vb.shape, 0) // hkv < length, vb, 0)
+    s = _dot_nt(q, kb.astype(jnp.float32)) * scale
+    col = U.iota(s.shape, 1)
+    valid = ((U.iota(s.shape, 0) // rows_per_head == col % hkv)
+             & (start + col // hkv < length))
+    return _flash_update(s, valid, vb.astype(jnp.float32), m, l, acc)
 
 
 def _mla_block(q_eff: jax.Array, q_rope: jax.Array, cb: jax.Array,
-               krb: jax.Array, valid: jax.Array, m: jax.Array,
-               l: jax.Array, acc: jax.Array, *, scale: float):
+               krb: jax.Array, start, length, m: jax.Array, l: jax.Array,
+               acc: jax.Array, *, scale: float):
     """One flash step in latent space over a (S, L)+(S, R) block.
 
-    q_eff: (T, H, L) f32 (q_nope absorbed through w_uk) · q_rope:
-    (T, H, R) f32 · cb: (S, L) · krb: (S, R) · m/l: (H, T) f32 ·
-    acc: (H, T, L) f32. The latent block ``cb`` is both the score and
+    q_eff: (H*T, L) f32 (q_nope absorbed through w_uk) · q_rope:
+    (H*T, R) f32 · cb: (S, L) · krb: (S, R) · m/l: (H*T, 1) f32 ·
+    acc: (H*T, L) f32. The latent block ``cb`` is both the score and
     the value operand (absorbed MLA math), so one zeroed copy serves
     both and keeps masked-lane NaNs out of the accumulator.
     """
-    cz = jnp.where(valid[:, None], cb, 0).astype(jnp.float32)
-    krz = jnp.where(valid[:, None], krb, 0).astype(jnp.float32)
-    s = (jnp.einsum("thl,sl->hts", q_eff, cz)
-         + jnp.einsum("thr,sr->hts", q_rope, krz)) * scale
-    s = jnp.where(valid[None, None, :], s, NEG_INF)
-    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-    p = jnp.where(valid[None, None, :],
-                  jnp.exp(s - m_new[..., None]), 0.0)
-    corr = jnp.exp(m - m_new)
-    l_new = l * corr + jnp.sum(p, axis=-1)
-    acc_new = acc * corr[..., None] + jnp.einsum("hts,sl->htl", p, cz)
-    return m_new, l_new, acc_new
+    cz = jnp.where(start + U.iota(cb.shape, 0) < length, cb,
+                   0).astype(jnp.float32)
+    krz = jnp.where(start + U.iota(krb.shape, 0) < length, krb,
+                    0).astype(jnp.float32)
+    s = (_dot_nt(q_eff, cz) + _dot_nt(q_rope, krz)) * scale
+    valid = start + U.iota(s.shape, 1) < length
+    return _flash_update(s, valid, cz, m, l, acc)
+
+
+def _init_state(rows: int, dv: int):
+    return (jnp.full((rows, 1), NEG_INF, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32),
+            jnp.zeros((rows, dv), jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -256,74 +257,104 @@ def _mla_block(q_eff: jax.Array, q_rope: jax.Array, cb: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _gqa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
-                acc_ref, m_ref, l_ref, *, scale: float, block_size: int):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
+def _flash_step(j, acc_ref, m_ref, l_ref, step):
     @pl.when(j == 0)
     def _init():
-        m_ref[0] = jnp.full(m_ref.shape[1:], NEG_INF, dtype=jnp.float32)
-        l_ref[0] = jnp.zeros(l_ref.shape[1:], dtype=jnp.float32)
-        acc_ref[0] = jnp.zeros(acc_ref.shape[1:], dtype=jnp.float32)
+        m0, l0, a0 = _init_state(*acc_ref.shape[1:])
+        m_ref[0], l_ref[0], acc_ref[0] = m0, l0, a0
 
-    valid = j * block_size + jnp.arange(block_size) < len_ref[b]
-    m, l, acc = _gqa_block(
-        q_ref[0].astype(jnp.float32), k_ref[0], v_ref[0], valid,
-        m_ref[0], l_ref[0], acc_ref[0], scale=scale)
-    m_ref[0], l_ref[0], acc_ref[0] = m, l, acc
+    m_ref[0], l_ref[0], acc_ref[0] = step(m_ref[0], l_ref[0], acc_ref[0])
 
 
-def _gqa_packed_kernel(tbl_ref, len_ref, q_ref,
+def _gqa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
+                acc_ref, m_ref, l_ref, *, scale: float, block_size: int,
+                hkv: int, rows_per_head: int):
+    b, j = pl.program_id(0), pl.program_id(1)
+    _flash_step(j, acc_ref, m_ref, l_ref, functools.partial(
+        _gqa_block, q_ref[0], k_ref[0], v_ref[0], j * block_size,
+        len_ref[b], scale=scale, hkv=hkv, rows_per_head=rows_per_head))
+
+
+def _gqa_packed_kernel(tbl_ref, len_ref, book_ref, q_ref,
                        kbm_ref, ksm_ref, kew_ref, kmo_ref, kem_ref,
                        vbm_ref, vsm_ref, vew_ref, vmo_ref, vem_ref,
-                       book_ref,
                        acc_ref, m_ref, l_ref, *, scale: float,
-                       block_size: int, hkv: int, d: int, keep: int,
-                       trunc: int, exp_bits: int):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[0] = jnp.full(m_ref.shape[1:], NEG_INF, dtype=jnp.float32)
-        l_ref[0] = jnp.zeros(l_ref.shape[1:], dtype=jnp.float32)
-        acc_ref[0] = jnp.zeros(acc_ref.shape[1:], dtype=jnp.float32)
-
-    book32 = book_ref[...].astype(jnp.int32)
-    kb = _decode_kv_rows(
-        kbm_ref[0], ksm_ref[0], kew_ref[0], kmo_ref[0], kem_ref[0],
-        book32, d=d, keep=keep, trunc=trunc, exp_bits=exp_bits)
-    vb = _decode_kv_rows(
-        vbm_ref[0], vsm_ref[0], vew_ref[0], vmo_ref[0], vem_ref[0],
-        book32, d=d, keep=keep, trunc=trunc, exp_bits=exp_bits)
-    kb = kb.reshape(block_size, hkv, d)
-    vb = vb.reshape(block_size, hkv, d)
-
-    valid = j * block_size + jnp.arange(block_size) < len_ref[b]
-    m, l, acc = _gqa_block(
-        q_ref[0].astype(jnp.float32), kb, vb, valid,
-        m_ref[0], l_ref[0], acc_ref[0], scale=scale)
-    m_ref[0], l_ref[0], acc_ref[0] = m, l, acc
+                       block_size: int, hkv: int, rows_per_head: int,
+                       d: int, keep: int, trunc: int, exp_bits: int):
+    b, j = pl.program_id(0), pl.program_id(1)
+    dec = functools.partial(_decode_kv_rows, d=d, keep=keep, trunc=trunc,
+                            exp_bits=exp_bits)
+    kb = dec(kbm_ref[0], ksm_ref[0], kew_ref[0], kmo_ref[0], kem_ref[0],
+             book_ref)
+    vb = dec(vbm_ref[0], vsm_ref[0], vew_ref[0], vmo_ref[0], vem_ref[0],
+             book_ref)
+    _flash_step(j, acc_ref, m_ref, l_ref, functools.partial(
+        _gqa_block, q_ref[0], kb, vb, j * block_size, len_ref[b],
+        scale=scale, hkv=hkv, rows_per_head=rows_per_head))
 
 
 def _mla_kernel(tbl_ref, len_ref, qe_ref, qr_ref, c_ref, kr_ref,
                 acc_ref, m_ref, l_ref, *, scale: float, block_size: int):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+    b, j = pl.program_id(0), pl.program_id(1)
+    _flash_step(j, acc_ref, m_ref, l_ref, functools.partial(
+        _mla_block, qe_ref[0], qr_ref[0], c_ref[0], kr_ref[0],
+        j * block_size, len_ref[b], scale=scale))
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[0] = jnp.full(m_ref.shape[1:], NEG_INF, dtype=jnp.float32)
-        l_ref[0] = jnp.zeros(l_ref.shape[1:], dtype=jnp.float32)
-        acc_ref[0] = jnp.zeros(acc_ref.shape[1:], dtype=jnp.float32)
 
-    valid = j * block_size + jnp.arange(block_size) < len_ref[b]
-    m, l, acc = _mla_block(
-        qe_ref[0].astype(jnp.float32), qr_ref[0].astype(jnp.float32),
-        c_ref[0], kr_ref[0], valid,
-        m_ref[0], l_ref[0], acc_ref[0], scale=scale)
-    m_ref[0], l_ref[0], acc_ref[0] = m, l, acc
+def _scan_rows(step, rows: int, dv: int, mb: int, *per_row):
+    """The jnp reference walk: per batch row, scan ``step(j, m, l, acc,
+    *row_operands)`` over the MB table columns. Returns (acc, m, l)."""
+    def row(*ops):
+        def body(carry, j):
+            return step(j, *carry, *ops), None
+
+        (m, l, acc), _ = jax.lax.scan(body, _init_state(rows, dv),
+                                      jnp.arange(mb, dtype=jnp.int32))
+        return acc, m, l
+
+    return jax.vmap(row)(*per_row)
+
+
+def _pool_call(kernel, *, impl: str, b: int, mb: int, rows: int, dv: int,
+               prefetch: list, row_operands: list, pool_operands: list):
+    """pallas_call over grid (B, MB): ``row_operands`` are (B, ...) and
+    blocked per batch row; ``pool_operands`` are (NB, ...) and blocked at
+    pool block ``table[b, j]`` (``prefetch[0]``). Returns flash state
+    acc (B, rows, dv), m/l (B, rows, 1)."""
+    def row_spec(x):
+        zeros = (0,) * (len(x.shape) - 1)
+        return pl.BlockSpec((1,) + x.shape[1:],
+                            lambda bi, j, *_: (bi,) + zeros)
+
+    def pool_spec(x):
+        zeros = (0,) * (len(x.shape) - 1)
+        return pl.BlockSpec((1,) + x.shape[1:],
+                            lambda bi, j, tbl, *_: (tbl[bi, j],) + zeros)
+
+    out_shape = [jax.ShapeDtypeStruct((b, rows, dv), jnp.float32),
+                 jax.ShapeDtypeStruct((b, rows, 1), jnp.float32),
+                 jax.ShapeDtypeStruct((b, rows, 1), jnp.float32)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(b, mb),
+        in_specs=([row_spec(x) for x in row_operands]
+                  + [pool_spec(x) for x in pool_operands]),
+        out_specs=[row_spec(o) for o in out_shape],
+    )
+    if impl == "interpret":
+        mode = {"interpret": True}
+    else:
+        mode = {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"))}
+    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
+                          **mode)(*prefetch, *row_operands, *pool_operands)
+
+
+def _gqa_rows(q: jax.Array) -> jax.Array:
+    """(B, T, Hkv, G, D) -> (B, Hkv*G*T, D) f32, rows ordered (h, g, t)."""
+    b, t, hkv, g, d = q.shape
+    return jnp.transpose(q.astype(jnp.float32),
+                         (0, 2, 3, 1, 4)).reshape(b, hkv * g * t, d)
 
 
 # ---------------------------------------------------------------------------
@@ -344,71 +375,32 @@ def paged_gqa(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     Returns unnormalised flash state (acc (B, Hkv, G, T, Dv) f32,
     m (B, Hkv, G, T) f32, l (B, Hkv, G, T) f32).
     """
-    b, t, hkv, g, dq = q.shape
-    nb, bs, _, dk = k_pool.shape
+    b, t, hkv, g, _ = q.shape
+    nb, bs = k_pool.shape[:2]
     dv = v_pool.shape[-1]
     mb = table.shape[1]
+    rows = hkv * g * t
     table = sanitize_table(table, nb)
     length = length.astype(jnp.int32)
-    qf = q.astype(jnp.float32)
+    qr = _gqa_rows(q)
+    k2 = k_pool.reshape(nb, bs * hkv, -1)
+    v2 = v_pool.reshape(nb, bs * hkv, dv)
+    kw = dict(scale=scale, hkv=hkv, rows_per_head=g * t)
 
     if impl == "jnp":
-        def row(qr, tbl_row, ln):
-            def body(carry, j):
-                m, l, acc = carry
-                kb = k_pool[tbl_row[j]]
-                vb = v_pool[tbl_row[j]]
-                valid = j * bs + jnp.arange(bs) < ln
-                m, l, acc = _gqa_block(qr, kb, vb, valid, m, l, acc,
-                                       scale=scale)
-                return (m, l, acc), None
+        def step(j, m, l, acc, qrow, tbl_row, ln):
+            return _gqa_block(qrow, k2[tbl_row[j]], v2[tbl_row[j]], j * bs,
+                              ln, m, l, acc, **kw)
 
-            init = (jnp.full((hkv, g, t), NEG_INF, jnp.float32),
-                    jnp.zeros((hkv, g, t), jnp.float32),
-                    jnp.zeros((hkv, g, t, dv), jnp.float32))
-            (m, l, acc), _ = jax.lax.scan(
-                body, init, jnp.arange(mb, dtype=jnp.int32))
-            return acc, m, l
-
-        return jax.vmap(row)(qf, table, length)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, mb),
-        in_specs=[
-            pl.BlockSpec((1, t, hkv, g, dq),
-                         lambda bi, j, tbl, ln: (bi, 0, 0, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, dk),
-                         lambda bi, j, tbl, ln: (tbl[bi, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, dv),
-                         lambda bi, j, tbl, ln: (tbl[bi, j], 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, hkv, g, t, dv),
-                         lambda bi, j, tbl, ln: (bi, 0, 0, 0, 0)),
-            pl.BlockSpec((1, hkv, g, t),
-                         lambda bi, j, tbl, ln: (bi, 0, 0, 0)),
-            pl.BlockSpec((1, hkv, g, t),
-                         lambda bi, j, tbl, ln: (bi, 0, 0, 0)),
-        ],
-    )
-    kwargs: dict[str, Any] = {}
-    if impl == "interpret":
-        kwargs["interpret"] = True
-    elif _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    acc, m, l = pl.pallas_call(
-        functools.partial(_gqa_kernel, scale=scale, block_size=bs),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, g, t, dv), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g, t), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g, t), jnp.float32),
-        ],
-        **kwargs,
-    )(table, length, qf, k_pool, v_pool)
-    return acc, m, l
+        acc, m, l = _scan_rows(step, rows, dv, mb, qr, table, length)
+    else:
+        acc, m, l = _pool_call(
+            functools.partial(_gqa_kernel, block_size=bs, **kw),
+            impl=impl, b=b, mb=mb, rows=rows, dv=dv,
+            prefetch=[table, length], row_operands=[qr],
+            pool_operands=[k2, v2])
+    return (acc.reshape(b, hkv, g, t, dv), m.reshape(b, hkv, g, t),
+            l.reshape(b, hkv, g, t))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -426,104 +418,37 @@ def paged_gqa_packed(q: jax.Array, k_spec: dict, v_spec: dict,
     ``book`` is the layer's exp_of_rank codebook (>=32 entries).
     Returns unnormalised flash state like ``paged_gqa``.
     """
-    b, t, hkv, g, dq = q.shape
+    b, t, hkv, g, _ = q.shape
     nb, bs = k_spec["bitmap"].shape[:2]
     mb = table.shape[1]
-    rows = bs * hkv
+    rows = hkv * g * t
     table = sanitize_table(table, nb)
     length = length.astype(jnp.int32)
-    qf = q.astype(jnp.float32)
+    qr = _gqa_rows(q)
     book32 = book[:32].astype(jnp.int32)
-
-    def flat(spec):
-        # (NB, BS, Hkv, 1, W) word planes -> (NB, R, W); mode/emax -> (NB, R)
-        return (
-            spec["bitmap"].reshape(nb, rows, -1),
-            spec["signmant"].reshape(nb, rows, -1),
-            spec["exp_words"].reshape(nb, rows, -1),
-            spec["exp_mode"].reshape(nb, rows).astype(jnp.int32),
-            spec["exp_emax"].reshape(nb, rows).astype(jnp.int32),
-        )
-
-    kf, vf = flat(k_spec), flat(v_spec)
-
-    def decode_block(leaves, idx):
-        bm, sm, ew, mo, em = (leaf[idx] for leaf in leaves)
-        out = _decode_kv_rows(bm, sm, ew, mo, em, book32, d=d, keep=keep,
-                              trunc=trunc, exp_bits=exp_bits)
-        return out.reshape(bs, hkv, d)
+    kf = _spec_rows(k_spec, (nb,), bs * hkv)
+    vf = _spec_rows(v_spec, (nb,), bs * hkv)
+    kw = dict(scale=scale, hkv=hkv, rows_per_head=g * t)
+    dec = functools.partial(_decode_kv_rows, d=d, keep=keep, trunc=trunc,
+                            exp_bits=exp_bits)
 
     if impl == "jnp":
-        def row(qr, tbl_row, ln):
-            def body(carry, j):
-                m, l, acc = carry
-                kb = decode_block(kf, tbl_row[j])
-                vb = decode_block(vf, tbl_row[j])
-                valid = j * bs + jnp.arange(bs) < ln
-                m, l, acc = _gqa_block(qr, kb, vb, valid, m, l, acc,
-                                       scale=scale)
-                return (m, l, acc), None
+        def step(j, m, l, acc, qrow, tbl_row, ln):
+            kb = dec(*(leaf[tbl_row[j]] for leaf in kf), book32)
+            vb = dec(*(leaf[tbl_row[j]] for leaf in vf), book32)
+            return _gqa_block(qrow, kb, vb, j * bs, ln, m, l, acc, **kw)
 
-            init = (jnp.full((hkv, g, t), NEG_INF, jnp.float32),
-                    jnp.zeros((hkv, g, t), jnp.float32),
-                    jnp.zeros((hkv, g, t, d), jnp.float32))
-            (m, l, acc), _ = jax.lax.scan(
-                body, init, jnp.arange(mb, dtype=jnp.int32))
-            return acc, m, l
-
-        return jax.vmap(row)(qf, table, length)
-
-    def pool_spec(w):
-        return pl.BlockSpec((1, rows, w),
-                            lambda bi, j, tbl, ln: (tbl[bi, j], 0, 0))
-
-    def scalar_spec():
-        return pl.BlockSpec((1, rows),
-                            lambda bi, j, tbl, ln: (tbl[bi, j], 0))
-
-    in_specs = [pl.BlockSpec((1, t, hkv, g, dq),
-                             lambda bi, j, tbl, ln: (bi, 0, 0, 0, 0))]
-    operands = [qf]
-    for leaves in (kf, vf):
-        bm, sm, ew, mo, em = leaves
-        in_specs += [pool_spec(bm.shape[-1]), pool_spec(sm.shape[-1]),
-                     pool_spec(ew.shape[-1]), scalar_spec(), scalar_spec()]
-        operands += [bm, sm, ew, mo, em]
-    in_specs.append(pl.BlockSpec((32,), lambda bi, j, tbl, ln: (0,)))
-    operands.append(book32)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, mb),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, hkv, g, t, d),
-                         lambda bi, j, tbl, ln: (bi, 0, 0, 0, 0)),
-            pl.BlockSpec((1, hkv, g, t),
-                         lambda bi, j, tbl, ln: (bi, 0, 0, 0)),
-            pl.BlockSpec((1, hkv, g, t),
-                         lambda bi, j, tbl, ln: (bi, 0, 0, 0)),
-        ],
-    )
-    kwargs: dict[str, Any] = {}
-    if impl == "interpret":
-        kwargs["interpret"] = True
-    elif _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    acc, m, l = pl.pallas_call(
-        functools.partial(
-            _gqa_packed_kernel, scale=scale, block_size=bs, hkv=hkv,
-            d=d, keep=keep, trunc=trunc, exp_bits=exp_bits),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, g, t, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g, t), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g, t), jnp.float32),
-        ],
-        **kwargs,
-    )(table, length, *operands)
-    return acc, m, l
+        acc, m, l = _scan_rows(step, rows, d, mb, qr, table, length)
+    else:
+        acc, m, l = _pool_call(
+            functools.partial(_gqa_packed_kernel, block_size=bs, d=d,
+                              keep=keep, trunc=trunc, exp_bits=exp_bits,
+                              **kw),
+            impl=impl, b=b, mb=mb, rows=rows, dv=d,
+            prefetch=[table, length, book32], row_operands=[qr],
+            pool_operands=[*kf, *vf])
+    return (acc.reshape(b, hkv, g, t, d), m.reshape(b, hkv, g, t),
+            l.reshape(b, hkv, g, t))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "impl"))
@@ -539,71 +464,34 @@ def paged_mla(q_eff: jax.Array, q_rope: jax.Array, c_pool: jax.Array,
     This is also the latent-space flash kernel for long MLA prefill.
     """
     b, t, h, latent = q_eff.shape
-    r_dim = q_rope.shape[-1]
     nb, bs, _ = c_pool.shape
     mb = table.shape[1]
+    rows = h * t
     table = sanitize_table(table, nb)
     length = length.astype(jnp.int32)
-    qe = q_eff.astype(jnp.float32)
-    qr = q_rope.astype(jnp.float32)
+
+    def head_rows(x):                      # (B, T, H, X) -> (B, H*T, X)
+        return jnp.transpose(x.astype(jnp.float32),
+                             (0, 2, 1, 3)).reshape(b, rows, -1)
+
+    qe, qr = head_rows(q_eff), head_rows(q_rope)
 
     if impl == "jnp":
-        def row(qer, qrr, tbl_row, ln):
-            def body(carry, j):
-                m, l, acc = carry
-                cb = c_pool[tbl_row[j]]
-                krb = kr_pool[tbl_row[j]]
-                valid = j * bs + jnp.arange(bs) < ln
-                m, l, acc = _mla_block(qer, qrr, cb, krb, valid, m, l,
-                                       acc, scale=scale)
-                return (m, l, acc), None
+        def step(j, m, l, acc, qer, qrr, tbl_row, ln):
+            return _mla_block(qer, qrr, c_pool[tbl_row[j]],
+                              kr_pool[tbl_row[j]], j * bs, ln, m, l, acc,
+                              scale=scale)
 
-            init = (jnp.full((h, t), NEG_INF, jnp.float32),
-                    jnp.zeros((h, t), jnp.float32),
-                    jnp.zeros((h, t, latent), jnp.float32))
-            (m, l, acc), _ = jax.lax.scan(
-                body, init, jnp.arange(mb, dtype=jnp.int32))
-            return acc, m, l
-
-        return jax.vmap(row)(qe, qr, table, length)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, mb),
-        in_specs=[
-            pl.BlockSpec((1, t, h, latent),
-                         lambda bi, j, tbl, ln: (bi, 0, 0, 0)),
-            pl.BlockSpec((1, t, h, r_dim),
-                         lambda bi, j, tbl, ln: (bi, 0, 0, 0)),
-            pl.BlockSpec((1, bs, latent),
-                         lambda bi, j, tbl, ln: (tbl[bi, j], 0, 0)),
-            pl.BlockSpec((1, bs, r_dim),
-                         lambda bi, j, tbl, ln: (tbl[bi, j], 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, h, t, latent),
-                         lambda bi, j, tbl, ln: (bi, 0, 0, 0)),
-            pl.BlockSpec((1, h, t), lambda bi, j, tbl, ln: (bi, 0, 0)),
-            pl.BlockSpec((1, h, t), lambda bi, j, tbl, ln: (bi, 0, 0)),
-        ],
-    )
-    kwargs: dict[str, Any] = {}
-    if impl == "interpret":
-        kwargs["interpret"] = True
-    elif _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    acc, m, l = pl.pallas_call(
-        functools.partial(_mla_kernel, scale=scale, block_size=bs),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, t, latent), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, t), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, t), jnp.float32),
-        ],
-        **kwargs,
-    )(table, length, qe, qr, c_pool, kr_pool)
-    return acc, m, l
+        acc, m, l = _scan_rows(step, rows, latent, mb, qe, qr, table,
+                               length)
+    else:
+        acc, m, l = _pool_call(
+            functools.partial(_mla_kernel, scale=scale, block_size=bs),
+            impl=impl, b=b, mb=mb, rows=rows, dv=latent,
+            prefetch=[table, length], row_operands=[qe, qr],
+            pool_operands=[c_pool, kr_pool])
+    return (acc.reshape(b, h, t, latent), m.reshape(b, h, t),
+            l.reshape(b, h, t))
 
 
 # ---------------------------------------------------------------------------

@@ -87,6 +87,7 @@ from repro.configs import get_config
 from repro.core.format import CassandraConfig
 from repro.core.packing import Calibrator, format_params, params_nbytes
 from repro.core.speculative import speedup_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, forward_train
 from repro.models.layers import Runtime
 from repro.serving.engine import Engine, EngineConfig, validate_request_slos
@@ -96,6 +97,13 @@ from repro.serving.telemetry import (Telemetry, format_stats_lines,
 
 
 def run(argv=None):
+    """Parse ``argv`` (default ``sys.argv``), serve, print the report.
+
+    Returns ``(finished_requests, summary)`` in scheduler mode — the
+    ``Request`` objects in completion order and ``Scheduler.summary()``
+    plus the serving wall time under ``wall_s`` — and
+    ``(tokens, stats)`` from the fixed-batch engine.
+    """
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -309,6 +317,7 @@ def run(argv=None):
         done = sched.run()
         dt = time.perf_counter() - t0
         s = sched.summary()
+        s["wall_s"] = dt
         mode = "fused" if sched.fused else "alternating"
         # the ONE stats formatter: every section keys off the summary's
         # subsystems config, so an enabled subsystem always prints (even
@@ -328,7 +337,7 @@ def run(argv=None):
         for r in sorted(done, key=lambda r: r.rid):
             print(f"  req {r.rid}: {len(r.output)} tokens, "
                   f"first {r.output[:8]}")
-        return
+        return done, s
 
     eng = Engine(cfg, params, cass=cass, ecfg=ecfg, rt_extra=rt_extra)
     t0 = time.perf_counter()
@@ -353,7 +362,9 @@ def run(argv=None):
               f"acceptance: {est:.2f}x over bf16")
     print("first request tokens:",
           [int(t) for t in tokens[0] if int(t) >= 0][:24])
+    return tokens, stats
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

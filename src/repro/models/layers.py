@@ -72,6 +72,10 @@ def packed_shape(w: dict) -> tuple[int, int]:
 
 def resolve_weight(rt: Runtime, w, path: str = "") -> jax.Array:
     """Materialise a weight leaf per the runtime view."""
+    if isinstance(w, dict) and "target" in w:   # packing.resolve_views
+        if rt.view not in ("draft", "target"):
+            raise ValueError(f"decoded weight {path} under view={rt.view!r}")
+        return w[rt.view]
     if not is_packed(w):
         return w
     if rt.cass is None:

@@ -114,6 +114,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.format import CassandraConfig
+from repro.core.packing import resolve_views
 from repro.models.layers import Runtime
 from repro.serving import kvcache as KC
 from repro.serving.blockpool import (BlockAllocator, TRASH_BLOCK,
@@ -318,7 +319,8 @@ class Scheduler:
             raise NotImplementedError(
                 "scheduler admission is token-prompt only for now")
         self.cfg, self.cass, self.ecfg = cfg, cass, ecfg
-        self.params = params
+        # packed weights decode once here, not on every serving pass
+        self.params = resolve_views(params, cass) if cass else params
         self.num_slots, self.s_max = num_slots, s_max
         self.eos_id, self.speculative = eos_id, speculative
         self.paged, self.block_size = paged, block_size
@@ -438,6 +440,8 @@ class Scheduler:
         def counted(*args):
             self.trace_counts[name] = self.trace_counts.get(name, 0) + 1
             return fn(*args)
+        # the compiled program's name: compile logs and profiles key on it
+        counted.__name__ = counted.__qualname__ = f"serve_{name}"
         return jax.jit(counted, donate_argnums=(1,))
 
     def _reset_state(self) -> None:

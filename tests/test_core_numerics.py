@@ -257,3 +257,51 @@ class TestCassandraFormat:
         summary2 = fmt.compression_summary(spec2, verif2, w.size * 2)
         assert summary2["draft_ratio"] < summary["draft_ratio"], (summary,
                                                                   summary2)
+
+
+class TestStackedFormatting:
+    @pytest.mark.parametrize("variant,calibrated", [(1, False), (1, True),
+                                                    (2, False)])
+    def test_per_layer_format_matches_vmap(self, variant, calibrated):
+        """Formatting a (R, in, out) stack one layer at a time is bit-for-bit
+        the whole-stack vmap it replaced."""
+        from repro.core import packing
+        w = rand_bf16(jax.random.PRNGKey(19), (3, 256, 64))
+        an = (jax.random.uniform(jax.random.PRNGKey(20), (3, 256)) + 0.5
+              if calibrated else None)
+        cfg = fmt.CassandraConfig(variant=variant)
+        got = packing._pack_weight(w, an, cfg, trim=False)
+        if an is None:
+            ref = jax.vmap(lambda wl: packing._format_layer(wl, None, cfg))(w)
+        else:
+            ref = jax.vmap(lambda wl, a: packing._format_layer(wl, a, cfg))(
+                w, an)
+        ref = {"spec": ref[0], "verif": ref[1]}
+        assert jax.tree.structure(got) == jax.tree.structure(ref)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_resolved_views_equal_on_the_fly_decode(self):
+        """Views decoded once at load are bit-for-bit what ``dense``
+        decodes per pass from the packed streams."""
+        from repro.core import packing
+        from repro.configs import get_config
+        from repro.models.layers import Runtime, resolve_weight
+        w = rand_bf16(jax.random.PRNGKey(21), (2, 256, 64))
+        cfg = fmt.CassandraConfig(variant=1)
+        packed = packing._pack_weight(w, None, cfg, trim=True)
+        views = packing.resolve_views({"w": packed}, cfg)["w"]
+        for view in ("draft", "target"):
+            rt = Runtime(cfg=get_config("qwen3-1.7b", smoke=True), cass=cfg,
+                         view=view)
+            for r in range(w.shape[0]):
+                layer = jax.tree.map(lambda a: a[r], packed)
+                np.testing.assert_array_equal(
+                    np.asarray(bitops.bf16_to_bits(resolve_weight(rt, layer))),
+                    np.asarray(bitops.bf16_to_bits(
+                        resolve_weight(rt, jax.tree.map(lambda a: a[r],
+                                                        views)))))
+        np.testing.assert_array_equal(
+            np.asarray(bitops.bf16_to_bits(views["target"])),
+            np.asarray(bitops.bf16_to_bits(w)))
