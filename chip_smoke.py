@@ -125,6 +125,8 @@ def run_phases(arch: str, smoke: bool, attn_impl: str,
     specs = (("a", "bf16 autoregressive", 0, "off"),
              ("b", "Cassandra-1, gather attention", 1, "off"),
              ("c", f"Cassandra-1, {attn_impl} paged kernels", 1, attn_impl))
+    dev = jax.devices()
+    device = f"{dev[0].device_kind} x{len(dev)} ({dev[0].platform})"
     phases = []
     t_run = time.perf_counter()
     for name, label, variant, attn in specs:
@@ -135,7 +137,7 @@ def run_phases(arch: str, smoke: bool, attn_impl: str,
             done, summary = serve.run(
                 serve_argv(arch, smoke, variant, attn, shape))
         total = time.perf_counter() - t0
-        stats = jax.devices()[0].memory_stats() or {}
+        stats = dev[0].memory_stats() or {}
         phases.append(Phase(
             name=name, label=label,
             outputs={r.rid: list(r.output) for r in done},
@@ -144,6 +146,8 @@ def run_phases(arch: str, smoke: bool, attn_impl: str,
             cache_hits=log.hits,
             cache_misses=log.misses,
             peak_bytes=stats.get("peak_bytes_in_use")))
+        # printed as each phase ends, so a cut run still reports it
+        print("\n".join(phase_lines(phases[-1], device)), flush=True)
         del done, summary
         gc.collect()        # drop the phase's scheduler, cache and params
     return phases
@@ -295,9 +299,6 @@ def main() -> int:
     with CompileLog() as log:
         ok, checks = judge(phases, reference_logits_fn(ARCH, smoke=False),
                            SHAPE["max_new"])
-    for p in phases:
-        for line in phase_lines(p, device):
-            print(line)
     print(f"[check] reference forward compile {sum(log.seconds.values()):.1f}s")
     for line in checks:
         print(line)
