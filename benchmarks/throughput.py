@@ -79,6 +79,7 @@ import jax
 
 from repro.configs import get_config
 from repro.core.format import CassandraConfig
+from repro.core.packing import resolve_views
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving.blockpool import blocks_needed
@@ -977,9 +978,10 @@ def main(argv=None):
         cfg = get_config(args.arch, smoke=True)
         params = init_params(cfg, jax.random.PRNGKey(args.seed))
     cass = CassandraConfig(variant=1, gamma=args.gamma)
-    packed = (common.calibrated_format(cfg, params, cass) if args.trained
-              else common.calibrated_format(cfg, params, cass,
-                                            calibrate=False))
+    packed = resolve_views(
+        common.calibrated_format(cfg, params, cass) if args.trained
+        else common.calibrated_format(cfg, params, cass, calibrate=False),
+        cass)
 
     # a serving-shaped trace: mixed prompt lengths and output budgets so
     # retirement desynchronises and admission overlaps live decode — the

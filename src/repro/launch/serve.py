@@ -85,7 +85,8 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.core.format import CassandraConfig
-from repro.core.packing import Calibrator, format_params, params_nbytes
+from repro.core.packing import (Calibrator, format_params, params_nbytes,
+                                resolve_views)
 from repro.core.speculative import speedup_model
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, forward_train
@@ -272,6 +273,9 @@ def run(argv=None):
         print(f"[format] spec={nb['spec']/1e6:.1f}MB "
               f"verif={nb['verif']/1e6:.1f}MB plain={nb['plain']/1e6:.1f}MB "
               f"(draft reads {nb['spec']/max(total,1)*100:.0f}% of resident)")
+        # decode once to dense draft/target views, not on every serving
+        # pass; rebinding frees the packed tree
+        params = resolve_views(params, cass)
 
     ecfg = EngineConfig(gamma=args.gamma, greedy=args.greedy)
     rt_extra = {"ssm_chunk": 8 if args.smoke else 64}

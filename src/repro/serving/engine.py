@@ -40,7 +40,6 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, layer_groups
 from repro.core import speculative as SP
 from repro.core.format import CassandraConfig
-from repro.core.packing import resolve_views
 from repro.models import model as M
 from repro.models.layers import Runtime
 from repro.serving import kvcache as KC
@@ -545,7 +544,7 @@ class Engine:
                  cass: CassandraConfig | None = None,
                  ecfg: EngineConfig = EngineConfig(), rt_extra: dict = {}):
         self.cfg, self.cass, self.ecfg = cfg, cass, ecfg
-        self.params = resolve_views(params, cass) if cass else params
+        self.params = params
         self.rt = Runtime(cfg=cfg, cass=cass,
                           view="target" if cass else "plain", **rt_extra)
         self._prefill = jax.jit(

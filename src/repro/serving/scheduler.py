@@ -114,7 +114,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.format import CassandraConfig
-from repro.core.packing import resolve_views
 from repro.models.layers import Runtime
 from repro.serving import kvcache as KC
 from repro.serving.blockpool import (BlockAllocator, TRASH_BLOCK,
@@ -319,8 +318,7 @@ class Scheduler:
             raise NotImplementedError(
                 "scheduler admission is token-prompt only for now")
         self.cfg, self.cass, self.ecfg = cfg, cass, ecfg
-        # packed weights decode once here, not on every serving pass
-        self.params = resolve_views(params, cass) if cass else params
+        self.params = params
         self.num_slots, self.s_max = num_slots, s_max
         self.eos_id, self.speculative = eos_id, speculative
         self.paged, self.block_size = paged, block_size
