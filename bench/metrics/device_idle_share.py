@@ -1,0 +1,8 @@
+"""Device: share of the traced window in which no operation ran on the
+chip, % (1 - union of operation intervals / window)."""
+
+
+def read(r):
+    if r.trace is None or not r.trace["devices"]:
+        return None
+    return (1.0 - r.trace["busy_s"] / r.trace["window_s"]) * 100.0
