@@ -78,6 +78,9 @@ blocks the cache may park after their requests retire:
 from __future__ import annotations
 
 import argparse
+import contextlib
+import json
+import os
 import time
 
 import jax
@@ -94,7 +97,8 @@ from repro.models.layers import Runtime
 from repro.serving.engine import Engine, EngineConfig, validate_request_slos
 from repro.serving.scheduler import Scheduler
 from repro.serving.telemetry import (Telemetry, format_stats_lines,
-                                     write_metrics, write_trace)
+                                     hlo_op_scopes, write_metrics,
+                                     write_trace)
 
 
 def run(argv=None):
@@ -219,8 +223,18 @@ def run(argv=None):
     ap.add_argument("--trace-capacity", type=int, default=65536,
                     help="lifecycle tracer ring bound (events); a full "
                     "ring drops oldest events, never grows")
+    ap.add_argument("--profile-dir", default=None,
+                    help="run the serving loop under jax.profiler.trace "
+                    "into this directory: the device timeline with the "
+                    "fused step's cass.* phase scopes and the "
+                    "scheduler's sched.* host spans (open with "
+                    "TensorBoard's profile plugin or xprof); scheduler "
+                    "mode also writes op_scopes.json there, the fused "
+                    "step's HLO op name -> phase scope map")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    profiled = (jax.profiler.trace(args.profile_dir) if args.profile_dir
+                else contextlib.nullcontext())
     # fail on malformed SLOs before paying for model init
     validate_request_slos(ttft_deadline_ms=args.ttft_deadline_ms,
                           itl_target_ms=args.itl_target_ms)
@@ -318,7 +332,8 @@ def run(argv=None):
                          priority=prio,
                          ttft_deadline_ms=args.ttft_deadline_ms,
                          itl_target_ms=args.itl_target_ms)
-        done = sched.run()
+        with profiled:
+            done = sched.run()
         dt = time.perf_counter() - t0
         s = sched.summary()
         s["wall_s"] = dt
@@ -338,6 +353,15 @@ def run(argv=None):
         if args.metrics_out:
             write_metrics(args.metrics_out, s)
             print(f"[telemetry] metrics jsonl -> {args.metrics_out}")
+        if args.profile_dir:
+            print(f"[telemetry] profiler trace -> {args.profile_dir}")
+            if sched.fused:
+                path = os.path.join(args.profile_dir, "op_scopes.json")
+                scopes = hlo_op_scopes(sched.unified_step_text())
+                with open(path, "w") as f:
+                    json.dump(scopes, f, indent=0, sort_keys=True)
+                print(f"[telemetry] {len(scopes)} scoped ops of "
+                      f"serve_unified -> {path}")
         for r in sorted(done, key=lambda r: r.rid):
             print(f"  req {r.rid}: {len(r.output)} tokens, "
                   f"first {r.output[:8]}")
@@ -345,11 +369,14 @@ def run(argv=None):
 
     eng = Engine(cfg, params, cass=cass, ecfg=ecfg, rt_extra=rt_extra)
     t0 = time.perf_counter()
-    tokens, stats = eng.generate(prompt, max_new=args.max_new,
-                                 key=jax.random.fold_in(key, 2),
-                                 speculative=args.variant != 0,
-                                 telemetry=telem)
+    with profiled:
+        tokens, stats = eng.generate(prompt, max_new=args.max_new,
+                                     key=jax.random.fold_in(key, 2),
+                                     speculative=args.variant != 0,
+                                     telemetry=telem)
     dt = time.perf_counter() - t0
+    if args.profile_dir:
+        print(f"[telemetry] profiler trace -> {args.profile_dir}")
     if args.trace_out:
         write_trace(args.trace_out, telem.tracer)
         print(f"[telemetry] perfetto trace -> {args.trace_out}")

@@ -63,8 +63,8 @@ class CostModel:
     """Per-bucket measured wall costs with a cycle↔ms exchange rate.
 
     ``observe`` folds one device-step invocation in; ``refresh`` bulk-fits
-    from a ``Scheduler.step_walls``-shaped dict (``name -> [calls,
-    total_seconds]``), replacing any prior state — the constructor-style
+    from a wall store shaped like ``Scheduler.metrics.walls`` (``name ->
+    [calls, total_seconds]``), replacing any prior state — the constructor-style
     entry point for fitting a model from a finished run's summary.
     """
 
@@ -104,10 +104,11 @@ class CostModel:
         b.total_ms += max(float(wall_ms), 0.0)
         b.tokens += int(tokens)
 
-    def refresh(self, step_walls: dict) -> None:
-        """Re-fit from a ``Scheduler.step_walls`` dict (replaces state)."""
+    def refresh(self, walls: dict) -> None:
+        """Re-fit from a ``Scheduler.metrics.walls`` dict (replaces
+        state)."""
         self.buckets = {}
-        for name, (calls, total_s) in step_walls.items():
+        for name, (calls, total_s) in walls.items():
             b = BucketCost(calls=int(calls),
                            total_ms=max(float(total_s), 0.0) * 1e3)
             self.buckets[name] = b

@@ -27,6 +27,14 @@ so admission piggybacks on decode instead of stalling it
 (``scheduler.Scheduler`` in fused mode); ``spec_decode_step`` /
 ``chunk_prefill_step`` remain the single-role reference paths the
 regression tests compare against.
+
+Both speculative steps name their phases with ``jax.named_scope`` —
+``cass.draft`` (the γ draft passes and the draft KV view),
+``cass.verify`` (the target pass and acceptance) and ``cass.commit``
+(the KV encode and append) — and ``kvcache`` adds ``cass.kv_decode`` and
+``cass.kv_gather`` wherever the packed KV is decoded or paged in. The
+scopes change HLO metadata only: a device trace can put each op down to
+a phase (``telemetry.hlo_op_scopes``), and the computation is the same.
 """
 from __future__ import annotations
 
@@ -391,13 +399,16 @@ def spec_decode_step(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
     per-row math for decode rows of a mixed-role batch — the regression
     tests in tests/test_scheduler.py hold them bit-identical."""
     rt_t = dataclasses.replace(rt, view="target" if rt.cass else "plain")
-    draft_tokens, draft_logits, key = _run_drafts(rt, params, cache,
-                                                  cur_tokens, key, ecfg)
+    with jax.named_scope("cass.draft"):
+        draft_tokens, draft_logits, key = _run_drafts(rt, params, cache,
+                                                      cur_tokens, key, ecfg)
     # batched verification over [cur ++ drafts]
     ver_tokens = jnp.concatenate([cur_tokens, draft_tokens], axis=1)
-    t_logits, t_upd = M.forward_decode(rt_t, params, ver_tokens, cache)
-    res = _accept(draft_tokens, draft_logits, t_logits, key, ecfg)
-    cache = commit(rt, cache, t_upd, res.n_accepted)
+    with jax.named_scope("cass.verify"):
+        t_logits, t_upd = M.forward_decode(rt_t, params, ver_tokens, cache)
+        res = _accept(draft_tokens, draft_logits, t_logits, key, ecfg)
+    with jax.named_scope("cass.commit"):
+        cache = commit(rt, cache, t_upd, res.n_accepted)
     return res, cache
 
 
@@ -452,21 +463,24 @@ def unified_step(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
     and the verify still reads exactly the committed prefix.
     """
     rt_t = dataclasses.replace(rt, view="target" if rt.cass else "plain")
-    draft_tokens, draft_logits, key = _run_drafts(rt, params, cache,
-                                                  cur_tokens, key, ecfg)
+    with jax.named_scope("cass.draft"):
+        draft_tokens, draft_logits, key = _run_drafts(rt, params, cache,
+                                                      cur_tokens, key, ecfg)
     is_prefill = prefill_valid > 0
     ver_tokens = jnp.concatenate([cur_tokens, draft_tokens], axis=1)
     tokens = jnp.where(is_prefill[:, None], chunk_tokens, ver_tokens)
-    t_logits, t_upd = M.forward_decode(rt_t, params, tokens, cache)
-    res = _accept(draft_tokens, draft_logits, t_logits, key, ecfg)
+    with jax.named_scope("cass.verify"):
+        t_logits, t_upd = M.forward_decode(rt_t, params, tokens, cache)
+        res = _accept(draft_tokens, draft_logits, t_logits, key, ecfg)
     # role-masked commit width: prefill rows commit their chunk's real
     # tokens, decode rows their accepted run + bonus, idle rows one
     # masked garbage token
     n = jnp.where(is_prefill,
                   jnp.maximum(prefill_valid.astype(jnp.int32), 1) - 1,
                   jnp.where(decode_mask, res.n_accepted, 0))
-    cache = commit(rt, cache, t_upd, n)
-    last = jnp.take_along_axis(t_logits, n[:, None, None], axis=1)[:, 0]
+    with jax.named_scope("cass.commit"):
+        cache = commit(rt, cache, t_upd, n)
+        last = jnp.take_along_axis(t_logits, n[:, None, None], axis=1)[:, 0]
     return res, last, cache
 
 

@@ -115,17 +115,19 @@ def read_store(cass: CassandraConfig, store, d: int, view: str,
     """Materialise dense (..., d) bf16 from a store per the runtime view."""
     if not is_packed(store):
         return store
-    if view == "draft":
-        out = fmt.draft_tensor(store["spec"], cass, d, _keep(cass, d),
-                               fmt.kv_group(cass, d), cass.kv_trunc, d,
-                               codebook=codebook, corr_bits=ONLINE_CORR_BITS)
-    else:
-        out = fmt.target_tensor(store["spec"], store["verif"], cass, d,
-                                _keep(cass, d), fmt.kv_group(cass, d),
-                                cass.kv_trunc, d, codebook=codebook,
-                                corr_bits=ONLINE_CORR_BITS)
-    # format_tensor blocks the last dim: (..., NB=1, d) -> (..., d)
-    return out.reshape(*store["spec"]["bitmap"].shape[:-2], d)
+    with jax.named_scope("cass.kv_decode"):
+        if view == "draft":
+            out = fmt.draft_tensor(store["spec"], cass, d, _keep(cass, d),
+                                   fmt.kv_group(cass, d), cass.kv_trunc, d,
+                                   codebook=codebook,
+                                   corr_bits=ONLINE_CORR_BITS)
+        else:
+            out = fmt.target_tensor(store["spec"], store["verif"], cass, d,
+                                    _keep(cass, d), fmt.kv_group(cass, d),
+                                    cass.kv_trunc, d, codebook=codebook,
+                                    corr_bits=ONLINE_CORR_BITS)
+        # format_tensor blocks the last dim: (..., NB=1, d) -> (..., d)
+        return out.reshape(*store["spec"]["bitmap"].shape[:-2], d)
 
 
 def append_store(store, new_store, at) -> dict:
@@ -179,10 +181,11 @@ def gather_block_leaf(pool: jax.Array, table: jax.Array) -> jax.Array:
     ``num_blocks``; this is the belt-and-braces for traced values.
     """
     nb = pool.shape[0]
-    table = jnp.where((table >= 0) & (table < nb), table, TRASH_BLOCK)
-    out = jnp.take(pool, table, axis=0, mode="clip")
-    return out.reshape(table.shape[0],
-                       table.shape[1] * pool.shape[1], *pool.shape[2:])
+    with jax.named_scope("cass.kv_gather"):
+        table = jnp.where((table >= 0) & (table < nb), table, TRASH_BLOCK)
+        out = jnp.take(pool, table, axis=0, mode="clip")
+        return out.reshape(table.shape[0],
+                           table.shape[1] * pool.shape[1], *pool.shape[2:])
 
 
 def gather_store(store, table: jax.Array):

@@ -33,7 +33,7 @@ into *sharing*:
   a new request only for its **unshared** blocks plus the parked blocks
   it re-pins.
 
-``SchedulerPrefixStats`` live in ``scheduler.Scheduler.stats``:
+``SchedulerPrefixStats`` live in ``scheduler.Scheduler.metrics``:
 ``prefix_queries/hits/matched_tokens``, ``prefix_blocks_aliased`` (pool
 blocks a request mapped without allocating) and ``cow_copies``.
 

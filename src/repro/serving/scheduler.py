@@ -95,6 +95,12 @@ multiplexes a request queue through one jit'd serving step per cycle.
   cycle and wall time, so ``summary()`` reports TTFT and p50/p95
   inter-token latency (the fused-vs-alternating headline in
   ``benchmarks/throughput.py``).
+* **Profiler spans** — a cycle's host phases open spans on the JAX
+  profiler's clock (``telemetry.span``): ``sched.admit``, ``sched.plan``,
+  ``sched.dispatch``, ``sched.wait`` (the sanctioned block on the step)
+  and ``sched.harvest``. A profiled run shows each device idle gap
+  beside the phase that held the host; the fused step's own ops carry
+  the ``cass.*`` named scopes (``engine.unified_step``).
 
 γ=0 / ``speculative=False`` degrades to continuous-batching autoregressive
 decode — the serving baseline for ``benchmarks/throughput.py``.
@@ -365,8 +371,7 @@ class Scheduler:
         self.cost = CostModel()
         # observability bundle (serving.telemetry): lifecycle tracer +
         # metrics registry. The registry is the ONE keyed store serving
-        # numbers live in (``stats``/``step_walls`` are read-only views
-        # over it), and its wall observations feed the cost model
+        # numbers live in, and its wall observations feed the cost model
         # through the same bucket keys — ``bucket_wall_ms`` and
         # ``cost_model`` can no longer diverge. The tracer is fed only
         # host-authoritative values (planner decisions, harvested numpy
@@ -459,8 +464,8 @@ class Scheduler:
         self.telemetry.reset()
         self.tracer = self.telemetry.tracer
         self.metrics = self.telemetry.metrics
-        # zero-init the full legacy counter set so every snapshot (and
-        # the ``stats`` view) carries every key from cycle 0
+        # zero-init the full counter set so every snapshot carries every
+        # key from cycle 0
         self.metrics.declare(
             "cycles", "prefill_cycles", "mixed_cycles", "prefill_tokens",
             "committed", "accepted", "drafted", "admitted", "finished",
@@ -546,18 +551,18 @@ class Scheduler:
         their private blocks return to the pool."""
         self._reset_state()
 
-    @property
-    def stats(self) -> dict:
-        """Legacy counter view: the registry's counters and gauges
-        merged flat, spelled exactly as the old ad-hoc dict. Read-only —
-        writers go through ``self.metrics``."""
-        return {**self.metrics.counters, **self.metrics.gauges}
-
-    @property
-    def step_walls(self) -> dict:
-        """Legacy wall view (``name -> [calls, total_seconds]``): the
-        registry's per-bucket wall store, live."""
-        return self.metrics.walls
+    def unified_step_text(self) -> str:
+        """The compiled text of the fused serving step (``serve_unified``)
+        for the live cache and weights: lowered with the operand shapes a
+        dispatch passes, so it is the program that runs (a compile-cache
+        hit once the step has run). ``telemetry.hlo_op_scopes`` maps its
+        instructions to the step's named phases."""
+        b, w = self.num_slots, self.ecfg.gamma + 1
+        return self._unified.lower(
+            self.params, self.cache, jnp.asarray(self.cur),
+            jnp.zeros((b, w), jnp.int32), jnp.zeros((b,), jnp.int32),
+            jnp.zeros((b,), bool), jax.random.split(self.key)[1]
+        ).compile().as_text()
 
     # -- queue -------------------------------------------------------------
 
@@ -1319,11 +1324,14 @@ class Scheduler:
         """One chunk of every prefilling row — the synchronous shape:
         dispatch, block, harvest in place (alternating mode and the
         ``overlap=False`` fused wide path)."""
-        p = self._dispatch_wide(prefilling)
-        # speclint: disable=sync-block(the one sanctioned per-cycle sync)
-        jax.block_until_ready(p.last)
+        with TM.span("sched.dispatch"):
+            p = self._dispatch_wide(prefilling)
+        with TM.span("sched.wait"):
+            # speclint: disable=sync-block(the one sanctioned per-cycle sync)
+            jax.block_until_ready(p.last)
         self._stamp_wall("chunk", p.t0)
-        self._harvest_wide(p)
+        with TM.span("sched.harvest"):
+            self._harvest_wide(p)
 
     def _finish_prefill(self, req: Request, last_logits: np.ndarray,
                         cycle: float | None = None) -> None:
@@ -1521,10 +1529,12 @@ class Scheduler:
     def _fused_step(self) -> bool:
         """Execute one planned mixed-role cycle via ``unified_step`` —
         the synchronous shape: dispatch, block, harvest in place."""
-        plan = self._plan_cycle()
+        with TM.span("sched.plan"):
+            plan = self._plan_cycle()
+            wide = plan is not None and self._plan_wide_cycle(plan)
         if plan is None:
             return self._fast_forward()
-        if self._plan_wide_cycle(plan):
+        if wide:
             # wide ``chunk_size``-bucket cycle: either the decode pool is
             # empty (cold start — nothing to piggyback on or stall), or
             # the cost model says riding is dearer than one stall (long
@@ -1536,14 +1546,17 @@ class Scheduler:
             self.metrics.inc("cycles")
             self.clock += 1.0
             return True
-        p = self._dispatch_unified(plan)
+        with TM.span("sched.dispatch"):
+            p = self._dispatch_unified(plan)
         # the cycle's one sanctioned sync: bound the step-wall stamp at
         # the step's completion, before the host-side harvest
-        # speclint: disable=sync-block(the one sanctioned per-cycle sync)
-        jax.block_until_ready(p.res.tokens)
+        with TM.span("sched.wait"):
+            # speclint: disable=sync-block(the one sanctioned per-cycle sync)
+            jax.block_until_ready(p.res.tokens)
         self._stamp_wall("unified", p.t0)
-        self._harvest_unified(plan, p.res, p.last, self.clock)
-        self._track_residency()
+        with TM.span("sched.harvest"):
+            self._harvest_unified(plan, p.res, p.last, self.clock)
+            self._track_residency()
         self.metrics.inc("cycles")
         self.clock += 1.0
         return True
@@ -1593,8 +1606,9 @@ class Scheduler:
             return
         out = p.res.tokens if p.kind == "unified" else p.last
         t_h = time.perf_counter()
-        # speclint: disable=sync-block(the one sanctioned per-cycle sync, deferred to harvest)
-        jax.block_until_ready(out)
+        with TM.span("sched.wait"):
+            # speclint: disable=sync-block(the one sanctioned per-cycle sync, deferred to harvest)
+            jax.block_until_ready(out)
         now = time.perf_counter()
         dispatch_dt = p.t_dispatch - p.t0
         name = p.kind                   # wall bucket: "unified" | "chunk"
@@ -1605,13 +1619,14 @@ class Scheduler:
         self._stamp_wall_at(name + ".dispatch", dispatch_dt, p.clock)
         self._stamp_wall_at(name, dispatch_dt + (now - t_h), p.clock)
         self._stamp_wall_at(name + ".overlap", t_h - p.t_dispatch, p.clock)
-        # speclint: disable=sync-truthy(kind is a host string field of the pending record)
-        if p.kind == "unified":
-            self._harvest_unified(p.plan, p.res, p.last, p.clock)
-        else:
-            self._harvest_wide(p)
-        self._finalize_inflight()
-        self._track_residency(cycle=p.clock)
+        with TM.span("sched.harvest"):
+            # speclint: disable=sync-truthy(kind is a host string field of the pending record)
+            if p.kind == "unified":
+                self._harvest_unified(p.plan, p.res, p.last, p.clock)
+            else:
+                self._harvest_wide(p)
+            self._finalize_inflight()
+            self._track_residency(cycle=p.clock)
 
     def _finalize_inflight(self) -> None:
         """Land deferred spill/restore transfers at the harvest point
@@ -1686,20 +1701,24 @@ class Scheduler:
         free_run = self._free_run_ok()
         if not free_run:
             self._harvest_pending()
-            self._admit_ready()
-        plan = self._plan_cycle()
+            with TM.span("sched.admit"):
+                self._admit_ready()
+        with TM.span("sched.plan"):
+            plan = self._plan_cycle()
+            wide = plan is not None and self._plan_wide_cycle(plan)
         if plan is None:
             # nothing to dispatch: drain whatever is still pending (a
             # trailing zombie-only cycle after the last live row retired
             # one harvest ago) before idling or fast-forwarding
             self._harvest_pending()
             return self._fast_forward()
-        if self._plan_wide_cycle(plan):
-            nxt = self._dispatch_wide(
-                [r for r in self.slots
-                 if r is not None and not r.prefill_done])
-        else:
-            nxt = self._dispatch_unified(plan, stale=free_run)
+        with TM.span("sched.dispatch"):
+            if wide:
+                nxt = self._dispatch_wide(
+                    [r for r in self.slots
+                     if r is not None and not r.prefill_done])
+            else:
+                nxt = self._dispatch_unified(plan, stale=free_run)
         self.metrics.inc("cycles")
         self.clock += 1.0
         if free_run:
@@ -1747,7 +1766,8 @@ class Scheduler:
                 self.check_invariants()
         if self.overlap:
             return self._fused_step_pipelined()
-        self._admit_ready()
+        with TM.span("sched.admit"):
+            self._admit_ready()
         if self.fused:
             return self._fused_step()
         prefilling = [r for r in self.slots

@@ -1,11 +1,8 @@
 """Host-side serving observability: one event stream, three faces.
 
 The serving stack's value claim is a wall-clock ratio driven by
-per-request acceptance dynamics, but until this module the only
-visibility was end-of-run aggregates spread over three parallel ad-hoc
-stores: ``Scheduler.stats`` (a plain dict), ``Scheduler.step_walls``
-(wall-time pairs), and per-benchmark JSON assembled by hand. This module
-replaces all three with one layered subsystem:
+per-request acceptance dynamics. This module is its one observability
+layer:
 
 * :class:`Tracer` — a **request-lifecycle tracer**. Every request emits
   SUBMIT → ADMIT (with prefix-hit depth) → PREFILL_CHUNK* → CYCLE
@@ -28,13 +25,25 @@ replaces all three with one layered subsystem:
   the ``benchmarks/throughput.py`` gate JSON all read.
 
 * **Exporters** — :func:`perfetto_trace` renders the ring as Chrome
-  ``trace_event`` JSON (one track per slot, one for device steps, one
-  for the spill subsystem, counter tracks for pool occupancy /
-  per-cycle accepted tokens; loads directly in ``chrome://tracing`` or
+  ``trace_event`` JSON (one track per slot, one for the step walls the
+  host measured, one for the spill subsystem, counter tracks for pool
+  occupancy / per-cycle accepted tokens; loads directly in
+  ``chrome://tracing`` or
   https://ui.perfetto.dev), and :func:`metrics_jsonl` renders a
   snapshot as newline-delimited JSON. Both are wired as
   ``--trace-out`` / ``--metrics-out`` on ``repro.launch.serve`` and
   ``benchmarks/throughput.py``.
+
+* **Profiler hooks** — :func:`span` opens a host span on the JAX
+  profiler's clock (``sched.admit|plan|dispatch|wait|harvest`` in the
+  scheduler's cycle), so a ``jax.profiler`` trace puts each device idle
+  gap beside the host phase that held it. The fused serving step names
+  its phases with ``jax.named_scope`` (``cass.draft``, ``cass.verify``,
+  ``cass.commit``, and ``cass.kv_decode`` / ``cass.kv_gather`` wherever
+  the packed KV is decoded or paged in); :func:`hlo_op_scopes` maps each
+  instruction of a compiled program's text to the scopes it ran under,
+  which is how the device trace's bare HLO op names are put down to a
+  phase.
 
 The zero-sync guarantee is machine-checked: ``tools/speclint`` flags any
 telemetry sink call (``emit``/``inc``/``gauge``/``observe``/…) whose
@@ -44,8 +53,11 @@ seeded corpus case proving the rule fires.
 from __future__ import annotations
 
 import json
+import re
 import time
 from collections import deque
+
+import jax
 
 # -- event taxonomy ---------------------------------------------------------
 # One request's lifecycle, in order. Every event is a plain tuple
@@ -156,8 +168,7 @@ class MetricsRegistry:
       silence).
 
     ``snapshot()`` returns a flat JSON-ready dict: counters and gauges at
-    top level (backwards-compatible with the old ``Scheduler.stats``
-    spellings), derived ratios (``tokens_per_cycle``, ``acceptance``,
+    top level, derived ratios (``tokens_per_cycle``, ``acceptance``,
     ``prefix_hit_rate``) computed here once, plus structured
     ``histograms`` / ``bucket_wall_ms`` / ``cost_model`` /
     ``subsystems`` / ``telemetry`` sections.
@@ -283,10 +294,111 @@ class Telemetry:
         self.metrics.reset()
 
 
+# -- profiler hooks ---------------------------------------------------------
+
+SCOPE_PREFIX = "cass."
+
+_HLO_COMPUTATION = re.compile(r"(?:ENTRY\s+)?%?([^\s(]+)\s*\(")
+_HLO_INSTRUCTION = re.compile(r"\s+(?:ROOT\s+)?%?([^\s=]+)\s*=\s*(.*)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_OPERAND = re.compile(r"%([^\s,(){}]+)")
+_HLO_CALLS = re.compile(r"\b(?:calls|body|condition|to_apply|"
+                        r"branch_computations|called_computations)="
+                        r"(\{[^}]*\}|[^\s,]+)")
+
+
+def span(name: str):
+    """A host span on the JAX profiler's clock (a TraceMe): it lands in
+    the same trace as the device's operations when a profiler is running,
+    and costs about a microsecond when none is."""
+    return jax.profiler.TraceAnnotation(name)
+
+
+def _common_scope(paths) -> str:
+    """The longest scope path every one of ``paths`` starts with."""
+    split = [p.split("/") if p else [] for p in paths]
+    out = []
+    for parts in zip(*split):
+        if any(q != parts[0] for q in parts):
+            break
+        out.append(parts[0])
+    return "/".join(out)
+
+
+def hlo_op_scopes(text: str) -> dict[str, str]:
+    """Instruction name -> named-scope path, from a compiled program's
+    HLO text (``jax.jit(f).lower(...).compile().as_text()``).
+
+    The path joins the ``cass.*`` components of the instruction's
+    ``op_name`` metadata, outermost first: an op of the verify pass's
+    packed-KV decode maps to ``"cass.verify/cass.kv_decode"``. An
+    instruction under no such scope (a layout copy the compiler made, an
+    operand's relayout, a select between phases) takes the scope its
+    fused computation's ops share, else the scope its users share, else
+    the scope of the instruction that calls its computation (a loop
+    body's copies run under the loop's scope), else the scope its
+    operands share (an output's relayout after the commit). Instructions
+    under none of these are left out. Names carry no leading ``%``, as a device trace's op
+    events name them once the ``%`` is stripped.
+
+    The text lists a computation after those it calls, and an
+    instruction after its operands, so one pass over both in reverse
+    order resolves every user and caller before the instruction that
+    needs it, and a last pass in text order every operand."""
+    comps: dict[str, list] = {}           # computation -> instructions
+    own, calls, users, callers, operands = {}, {}, {}, {}, {}
+    comp = None
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            m = _HLO_COMPUTATION.match(line)
+            comp = m.group(1) if m and line.rstrip().endswith("{") else None
+            if comp is not None:
+                comps[comp] = []
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if comp is None or m is None:
+            continue
+        name, rest = m.groups()
+        comps[comp].append(name)
+        op = _HLO_OP_NAME.search(rest)
+        own[name] = "/".join(c for c in (op.group(1) if op else "").split("/")
+                             if c.startswith(SCOPE_PREFIX))
+        calls[name] = [c.strip(" {}%") for g in _HLO_CALLS.findall(rest)
+                       for c in g.split(",")]
+        for c in calls[name]:
+            callers.setdefault(c, []).append(name)
+        operands[name] = _HLO_OPERAND.findall(rest.split(", metadata=")[0])
+        for operand in operands[name]:
+            users.setdefault((comp, operand), []).append(name)
+
+    def inner(comp: str) -> str:
+        paths = [own[i] for i in comps.get(comp, ()) if own[i]]
+        return _common_scope(paths) if paths else ""
+
+    scope: dict[str, str] = {}
+    for comp in reversed(comps):
+        outer = _common_scope([scope.get(i, "")
+                               for i in callers.get(comp, ())])
+        for name in reversed(comps[comp]):
+            if own[name]:
+                scope[name] = own[name]
+                continue
+            fused = [p for p in map(inner, calls[name]) if p]
+            used = [scope.get(u, "") for u in users.get((comp, name), ())]
+            scope[name] = (_common_scope(fused) if fused else
+                           _common_scope(used) if any(used) else outer)
+    for comp, names in comps.items():
+        for name in names:
+            if not scope[name]:
+                given = [scope.get(o, "") for o in operands[name]]
+                scope[name] = _common_scope(given) if any(given) else ""
+    return {n: p for n, p in scope.items() if p}
+
+
 # -- exporters --------------------------------------------------------------
 
 _PID = 1
-_TID_DEVICE = 2        # compiled device steps (one at a time)
+_TID_DEVICE = 2        # step walls back-computed from host stamps
 _TID_SPILL = 3         # preemption / spill subsystem instants
 _TID_DISPATCH = 4      # host dispatch + overlap spans (pipelined mode)
 _TID_SLOT0 = 10        # slot i -> tid 10 + i
@@ -414,7 +526,7 @@ def perfetto_trace(tracer: Tracer, process_name: str = "cassandra-serve"
 
     out = [{"name": "process_name", "ph": "M", "pid": _PID,
             "args": {"name": process_name}}]
-    names = {_TID_DEVICE: "device steps", _TID_SPILL: "spill/preempt",
+    names = {_TID_DEVICE: "step walls (host)", _TID_SPILL: "spill/preempt",
              _TID_DISPATCH: "dispatch/overlap"}
     for tid in sorted(tracks):
         label = names.get(tid, f"slot {tid - _TID_SLOT0}")

@@ -62,8 +62,8 @@ def test_negative_observations_clamped():
 
 
 def test_refresh_refits_from_step_walls():
-    """``refresh`` bulk-fits from a ``Scheduler.step_walls``-shaped dict
-    (name -> [calls, total_seconds]), replacing prior state."""
+    """``refresh`` bulk-fits from a ``Scheduler.metrics.walls``-shaped
+    dict (name -> [calls, total_seconds]), replacing prior state."""
     cm = CostModel(warmup_discard=0)
     cm.observe("unified", 100.0)
     cm.refresh({"unified": [4, 0.008], "chunk": [2, 0.002]})

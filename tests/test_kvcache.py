@@ -290,7 +290,10 @@ def _check_swap_trace(num_blocks, ops):
             charged = list(pool.blocks_of(owner))
             held = charged + list(pool._shared[owner])
             gate_before = pool.reserved_total + pool.uncharged_total
-            dropped = pool.swap_out(owner, next_key, len(held))
+            # swap keys live apart from owner ids, as the scheduler's
+            # ("swap", n) keys do: an int key could name a live owner
+            key = ("swap", next_key)
+            dropped = pool.swap_out(owner, key, len(held))
             # the swapped key holds ZERO gate capacity: the whole
             # reservation left the gate; the only additions are the
             # owner's charged blocks that sharers kept live (each now
@@ -303,10 +306,10 @@ def _check_swap_trace(num_blocks, ops):
                     - dead_uncharged)
             for blk in dropped:
                 assert pool.refcount(blk) == 0
-            assert pool.is_swapped(next_key)
+            assert pool.is_swapped(key)
             with pytest.raises(ValueError):
-                pool.swap_out(owner, next_key, 0)   # double swap / gone
-            swapped[next_key] = len(held)
+                pool.swap_out(owner, key, 0)        # double swap / gone
+            swapped[key] = len(held)
             del reserved[owner]
             next_key += 1
         elif kind == "swap_in" and swapped:

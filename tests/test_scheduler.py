@@ -340,7 +340,7 @@ def test_fused_matches_alternating_trace(model, paged):
             # interleaving win: admissions ride decode cycles instead of
             # stalling them, so the fused run never takes more cycles
             fused_cycles = sched.summary()["cycles"]
-            assert sched.stats["mixed_cycles"] > 0
+            assert sched.metrics.counters["mixed_cycles"] > 0
     assert outs[0] == outs[1]
     assert fused_cycles <= sched.summary()["cycles"]
 
@@ -359,7 +359,7 @@ def test_fused_single_compile_bucket(model, spec_sched):
         arrival=i / 2.0) for i, ln in enumerate(lens)]
     done = spec_sched.run()
     assert len(done) == len(reqs)
-    assert spec_sched.stats["mixed_cycles"] > 0      # roles really mixed
+    assert spec_sched.metrics.counters["mixed_cycles"] > 0      # roles really mixed
     assert spec_sched.trace_counts.get("unified", 0) == 1
     assert spec_sched._unified._cache_size() == 1
     # the only other bucket ever traced is the wide admission chunk for
@@ -392,8 +392,8 @@ def test_prefill_budget_caps_tokens_not_outputs(model, spec_sched):
             spec_sched.run()
         finally:
             spec_sched.max_prefill_tokens_per_step = None
-        assert spec_sched.stats["mixed_cycles"] > 0
-        peak = spec_sched.stats["peak_prefill_tokens_per_cycle"]
+        assert spec_sched.metrics.counters["mixed_cycles"] > 0
+        peak = spec_sched.metrics.gauges["peak_prefill_tokens_per_cycle"]
         if budget is None:
             baseline = [r.output for r in reqs]
             assert peak > 2          # unbudgeted mixed cycles go wider
@@ -965,7 +965,7 @@ def test_moe_fused_matches_alternating_trace():
         sched.run()
         assert all(r.done for r in reqs)
         if fused:
-            assert sched.stats["mixed_cycles"] > 0
+            assert sched.metrics.counters["mixed_cycles"] > 0
         outs.append([r.output for r in reqs])
     assert outs[0] == outs[1]
 
@@ -1091,7 +1091,7 @@ def test_wall_clock_step_immune(model, spec_sched, monkeypatch):
     prev = {}
     while not spec_sched.idle:
         spec_sched.step()
-        for name, (calls, total) in spec_sched.step_walls.items():
+        for name, (calls, total) in spec_sched.metrics.walls.items():
             assert total >= 0.0, (name, total)
             pc, pt = prev.get(name, (0, 0.0))
             assert calls >= pc and total >= pt   # monotone accumulation
@@ -1299,7 +1299,7 @@ def test_cost_model_observes_real_walls(model, spec_sched):
     """``_stamp_wall`` feeds the online cost model: after a run every
     measured step bucket is fitted, the cycle<->ms exchange rate is a
     real measurement, and the fit PERSISTS across ``reset()`` — the
-    model keeps refining across runs while ``step_walls`` starts
+    model keeps refining across runs while ``metrics.walls`` starts
     fresh."""
     cfg, _ = model
     spec_sched.reset()
@@ -1309,7 +1309,7 @@ def test_cost_model_observes_real_walls(model, spec_sched):
     spec_sched.run()
     cost = spec_sched.cost
     assert cost.warm
-    assert set(spec_sched.step_walls) <= set(cost.buckets)
+    assert set(spec_sched.metrics.walls) <= set(cost.buckets)
     assert cost.cycle_ms() > 0
     snap = spec_sched.summary()["cost_model"]
     assert snap["warm"] is True and snap["cycle_ms"] > 0
@@ -1318,7 +1318,7 @@ def test_cost_model_observes_real_walls(model, spec_sched):
     assert spec_sched.cost is cost       # same model, still warm
     assert cost.warm
     assert {n: b.calls for n, b in cost.buckets.items()} == calls
-    assert spec_sched.step_walls == {}   # raw walls start fresh
+    assert spec_sched.metrics.walls == {}   # raw walls start fresh
 
 
 def test_deadline_beats_priority_in_goodput_mode(model, spec_sched):

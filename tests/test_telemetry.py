@@ -203,6 +203,9 @@ def test_perfetto_schema_and_monotone_tracks(oversub):
     # pool-occupancy counter track all present
     assert any(e["ph"] == "X" and e.get("cat") == "request" for e in evs)
     assert any(e["ph"] == "X" and e.get("cat") == "device" for e in evs)
+    # the step track is back-computed from host walls, and says so
+    tracks = {e["args"]["name"] for e in evs if e["name"] == "thread_name"}
+    assert "step walls (host)" in tracks and "device steps" not in tracks
     assert {e["name"] for e in evs if e["ph"] == "C"} >= {
         "pool_blocks", "resident_tokens", "queue_depth",
         "accepted_tokens_per_cycle"}
