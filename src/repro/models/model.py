@@ -219,9 +219,11 @@ def _attn_entry(rt: Runtime, bp: dict, x, positions, *, causal, centry,
                 table=None):
     """Attention sub-block in any mode. Returns (out, upd).
 
-    ``ventry`` — optional pre-materialised dense view of the packed cache
-    entry (the draft view is decoded once per speculative cycle and reused
-    across the γ draft steps — §Perf iteration A4).
+    ``ventry`` — optional dense view of the packed cache entry, decoded
+    once per speculative cycle by ``materialize_cache_view``: the γ draft
+    passes read its draft view and, for Cassandra-1, the verify pass its
+    target view, so neither decodes the pool here. Without it the entry
+    is decoded in place (``read_store``).
     ``table`` — paged caches only: (B,MB) block table; the cache entry (or
     its ventry) is a block pool decoded pool-wide, and the per-request
     prefix is assembled by ``kvcache.gather_block_leaf``. ``s_max`` is
@@ -630,42 +632,78 @@ def _commit_prefill(rt: Runtime, cache, updates_groups, s, book):
     return out
 
 
-def materialize_cache_view(rt: Runtime, cache: dict) -> list | None:
-    """Decode the packed cache's draft/target view ONCE into dense stores.
+def _kernel_reads_pool(rt: Runtime, cache: dict) -> bool:
+    """The paged-attention kernel path: draft passes decode packed KV
+    inside the kernel and verify decodes per layer, so no dense pool view
+    is built for them."""
+    return rt.attn_kernel != "off" and KC.is_paged(cache)
 
-    The speculative engine reuses this across the γ draft steps — the
-    packed-stream expansion runs once per cycle instead of once per pass
-    (§Perf A4). Returns None for plain caches. On TPU this corresponds to
-    decoding the packed stream into an HBM scratch; the fused Pallas
-    kernel path instead re-reads the packed stream per pass with zero
-    expansion traffic (see DESIGN.md §9).
+
+def materialize_cache_view(rt: Runtime, cache: dict
+                           ) -> tuple[list | None, list | None]:
+    """Dense views of a packed cache for one speculative cycle:
+    ``(draft, target)``, each a list over layer groups of dense stores
+    (``forward_decode``'s ``cache_view``), or None where the passes read
+    the cache themselves.
+
+    One decode of the pool per cycle: for Cassandra-1 each layer's
+    target view is decoded once (``read_store(view="target")``) and the
+    draft view is derived from it elementwise
+    (``kvcache.draft_from_target``, bit-exact); the γ draft passes read
+    the draft view and the verify pass the target view. Cassandra-2's
+    draft is not a function of its container-exact target, so its draft
+    view is decoded apart and verify decodes the target per layer
+    itself (target None). Layers are decoded one at a time (``lax.map``
+    over each group's repeats), so a decode has the shapes and
+    temporaries of one layer's. Plain caches, and the paged-kernel path
+    (``attn_kernel != "off"``), get ``(None, None)``.
+    ``kv_pool_decodes`` counts the pool decodes each case leaves in a
+    cycle.
     """
     cfg, cass = rt.cfg, rt.cass
     book = KC.cache_codebook(cache)
-    if book is None:
-        return None
-    view = "draft" if rt.view == "draft" else "target"
-    groups = []
+    if book is None or _kernel_reads_pool(rt, cache):
+        return None, None
+    dims = ({"c": cfg.kv_lora_rank, "kr": cfg.qk_rope_dim} if cfg.mla
+            else {"k": cfg.hd, "v": cfg.hd})
+    shared = cass.variant == 1
+
+    def layer(entries):
+        draft, target = {}, {}
+        for ekey, centry in entries.items():
+            draft[ekey], target[ekey] = {}, {}
+            for nm, d in dims.items():
+                if shared:
+                    t = KC.read_store(cass, centry[nm], d, "target", book)
+                    target[ekey][nm] = t
+                    draft[ekey][nm] = KC.draft_from_target(
+                        cass, centry[nm]["spec"], t, d)
+                else:
+                    draft[ekey][nm] = KC.read_store(cass, centry[nm], d,
+                                                    "draft", book)
+        return draft, target
+
+    drafts, targets = [], []
     for gi, g in enumerate(layer_groups(cfg)):
-        gdict = {}
-        for j, entry in enumerate(g.entries):
-            if entry[0] != "a":
-                continue
-            centry = cache["dec"][gi][f"e{j}"]
-            if cfg.mla:
-                gdict[f"e{j}"] = {
-                    "c": jax.vmap(lambda s: KC.read_store(
-                        cass, s, cfg.kv_lora_rank, view, book))(centry["c"]),
-                    "kr": jax.vmap(lambda s: KC.read_store(
-                        cass, s, cfg.qk_rope_dim, view, book))(centry["kr"])}
-            else:
-                gdict[f"e{j}"] = {
-                    "k": jax.vmap(lambda s: KC.read_store(
-                        cass, s, cfg.hd, view, book))(centry["k"]),
-                    "v": jax.vmap(lambda s: KC.read_store(
-                        cass, s, cfg.hd, view, book))(centry["v"])}
-        groups.append(gdict)
-    return groups
+        attn = {f"e{j}": cache["dec"][gi][f"e{j}"]
+                for j, entry in enumerate(g.entries) if entry[0] == "a"}
+        draft, target = jax.lax.map(layer, attn) if attn else ({}, {})
+        drafts.append(draft)
+        targets.append(target)
+    return drafts, (targets if shared else None)
+
+
+def kv_pool_decodes(rt: Runtime, cache: dict, drafts: bool = True) -> int:
+    """Full-pool decodes of the packed KV in one serving step: 0 for a
+    plain cache; 1 when the step does not draft, when one target decode
+    feeds both views (Cassandra-1) or when the paged kernel decodes the
+    draft passes' KV in-kernel; 2 when the draft view is decoded apart
+    from verify's target (Cassandra-2). See ``materialize_cache_view``."""
+    if KC.cache_codebook(cache) is None:
+        return 0
+    if not drafts or rt.cass.variant == 1 or _kernel_reads_pool(rt, cache):
+        return 1
+    return 2
 
 
 def forward_decode(rt: Runtime, params: Params, tokens: jax.Array,
@@ -676,7 +714,9 @@ def forward_decode(rt: Runtime, params: Params, tokens: jax.Array,
     ``updates`` mirrors the cache groups: per attn entry the new tokens'
     K/V (B,q,…), per ssm entry {"h_all", "conv_win", "conv", "h"} for
     commit/rollback by the serving engine. ``cache_view`` optionally
-    provides pre-materialised dense stores (see materialize_cache_view).
+    provides dense stores decoded beforehand (one view of
+    ``materialize_cache_view``); attention entries then read them and
+    decode nothing.
 
     Rows are fully independent here — per-row ``length`` offsets the
     positions, the attention prefix mask is per-row, and the slot/paged

@@ -28,13 +28,18 @@ so admission piggybacks on decode instead of stalling it
 ``chunk_prefill_step`` remain the single-role reference paths the
 regression tests compare against.
 
-Both speculative steps name their phases with ``jax.named_scope`` —
-``cass.draft`` (the γ draft passes and the draft KV view),
-``cass.verify`` (the target pass and acceptance) and ``cass.commit``
-(the KV encode and append) — and ``kvcache`` adds ``cass.kv_decode`` and
-``cass.kv_gather`` wherever the packed KV is decoded or paged in. The
-scopes change HLO metadata only: a device trace can put each op down to
-a phase (``telemetry.hlo_op_scopes``), and the computation is the same.
+Both speculative steps decode a packed cache once per cycle, before the
+drafts (``model.materialize_cache_view``): for Cassandra-1 one per-layer
+target decode of the pool feeds the verify pass, and the draft view is
+derived from it bit-exactly. They name their phases with
+``jax.named_scope`` — ``cass.view`` (the cycle's KV views),
+``cass.draft`` (the γ draft passes), ``cass.verify`` (the target pass
+and acceptance) and ``cass.commit`` (the KV encode and append) — and
+``kvcache`` adds ``cass.kv_decode``, ``cass.kv_derive`` and
+``cass.kv_gather`` wherever the packed KV is decoded, derived or paged
+in. The scopes change HLO metadata only: a device trace can put each op
+down to a phase (``telemetry.hlo_op_scopes``), and the computation is
+the same.
 """
 from __future__ import annotations
 
@@ -314,12 +319,15 @@ def commit(rt: Runtime, cache: dict, updates: list, n: jax.Array) -> dict:
 # ---------------------------------------------------------------------------
 
 def _run_drafts(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
-                key: jax.Array, ecfg: EngineConfig
+                key: jax.Array, ecfg: EngineConfig, draft_view: list | None
                 ) -> tuple[jax.Array, list, jax.Array]:
     """γ draft steps with ``view="draft"``. Reads the cache, never writes
     it (scratch/cache-view only), so rows whose draft inputs are garbage
     (prefill/idle rows riding through a fused cycle) are harmless.
-    Returns (draft_tokens (B,γ), per-step draft logits, key)."""
+    ``draft_view`` is the cycle's dense draft view of a packed cache
+    (``model.materialize_cache_view``), None where the passes read the
+    cache themselves. Returns (draft_tokens (B,γ), per-step draft logits,
+    key)."""
     cfg = rt.cfg
     gamma = ecfg.gamma
     rt_d = dataclasses.replace(rt, view="draft" if rt.cass else "plain")
@@ -331,15 +339,6 @@ def _run_drafts(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
         return jax.random.categorical(
             sub, lg / ecfg.temperature).astype(jnp.int32), key
 
-    # decode the draft view of the packed cache ONCE for all γ steps —
-    # unless the paged-attention kernel is on: it decodes the packed
-    # stream *inside* the kernel per pass (zero HBM expansion traffic),
-    # so pre-materialising would both waste the decode and silently
-    # reroute the draft pass onto the dense-pool variant.
-    if rt.attn_kernel != "off" and KC.is_paged(cache):
-        draft_view = None
-    else:
-        draft_view = M.materialize_cache_view(rt_d, cache)
     draft_tokens = []
     draft_logits = []
     if ecfg.stable_draft:
@@ -399,13 +398,16 @@ def spec_decode_step(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
     per-row math for decode rows of a mixed-role batch — the regression
     tests in tests/test_scheduler.py hold them bit-identical."""
     rt_t = dataclasses.replace(rt, view="target" if rt.cass else "plain")
+    with jax.named_scope("cass.view"):
+        draft_view, target_view = M.materialize_cache_view(rt, cache)
     with jax.named_scope("cass.draft"):
-        draft_tokens, draft_logits, key = _run_drafts(rt, params, cache,
-                                                      cur_tokens, key, ecfg)
+        draft_tokens, draft_logits, key = _run_drafts(
+            rt, params, cache, cur_tokens, key, ecfg, draft_view)
     # batched verification over [cur ++ drafts]
     ver_tokens = jnp.concatenate([cur_tokens, draft_tokens], axis=1)
     with jax.named_scope("cass.verify"):
-        t_logits, t_upd = M.forward_decode(rt_t, params, ver_tokens, cache)
+        t_logits, t_upd = M.forward_decode(rt_t, params, ver_tokens, cache,
+                                           cache_view=target_view)
         res = _accept(draft_tokens, draft_logits, t_logits, key, ecfg)
     with jax.named_scope("cass.commit"):
         cache = commit(rt, cache, t_upd, res.n_accepted)
@@ -463,14 +465,17 @@ def unified_step(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
     and the verify still reads exactly the committed prefix.
     """
     rt_t = dataclasses.replace(rt, view="target" if rt.cass else "plain")
+    with jax.named_scope("cass.view"):
+        draft_view, target_view = M.materialize_cache_view(rt, cache)
     with jax.named_scope("cass.draft"):
-        draft_tokens, draft_logits, key = _run_drafts(rt, params, cache,
-                                                      cur_tokens, key, ecfg)
+        draft_tokens, draft_logits, key = _run_drafts(
+            rt, params, cache, cur_tokens, key, ecfg, draft_view)
     is_prefill = prefill_valid > 0
     ver_tokens = jnp.concatenate([cur_tokens, draft_tokens], axis=1)
     tokens = jnp.where(is_prefill[:, None], chunk_tokens, ver_tokens)
     with jax.named_scope("cass.verify"):
-        t_logits, t_upd = M.forward_decode(rt_t, params, tokens, cache)
+        t_logits, t_upd = M.forward_decode(rt_t, params, tokens, cache,
+                                           cache_view=target_view)
         res = _accept(draft_tokens, draft_logits, t_logits, key, ecfg)
     # role-masked commit width: prefill rows commit their chunk's real
     # tokens, decode rows their accepted run + bonus, idle rows one

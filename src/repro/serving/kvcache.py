@@ -130,6 +130,38 @@ def read_store(cass: CassandraConfig, store, d: int, view: str,
         return out.reshape(*store["spec"]["bitmap"].shape[:-2], d)
 
 
+def draft_from_target(cass: CassandraConfig, spec: dict, target: jax.Array,
+                      d: int) -> jax.Array:
+    """The draft view of a Cassandra-1 store, derived from its target view.
+
+    Bit-exact against ``read_store(view="draft")`` and elementwise, so a
+    cycle that has decoded the lossless view pays no second decode for
+    the draft one. ``target`` (..., d) is the store's target view and
+    ``spec`` its speculation leaves. Pruned positions (bitmap 0) read
+    +0; kept ones keep their sign and lose the low ``kv_trunc`` mantissa
+    bits. The exponent is exact in unary blocks (``exp_mode`` 0); in
+    delta blocks the draft codes ``emax - e`` clamped to
+    ``2**exp_bits - 2``, with the escape for ``e == 0``
+    (``coding.delta_encode_block``), so it reads
+    ``0 if e == 0 else max(e, emax - (2**exp_bits - 2))``.
+    """
+    if cass.variant != 1:
+        raise ValueError("draft_from_target covers Cassandra-1 only: the "
+                         "Cassandra-2 draft is not a function of its "
+                         "container-exact target")
+    with jax.named_scope("cass.kv_derive"):
+        bits = bitops.bf16_to_bits(target).astype(jnp.int32)
+        kept = bitops.unpack_bits(spec["bitmap"], d).reshape(target.shape)
+        exp = (bits >> 7) & 0xFF
+        floor = spec["exp_emax"].astype(jnp.int32) - ((1 << cass.exp_bits)
+                                                      - 2)
+        delta_exp = jnp.where(exp == 0, 0, jnp.maximum(exp, floor))
+        exp = jnp.where(spec["exp_mode"] == 0, exp, delta_exp)
+        mant = bits & (0x7F & ~((1 << cass.kv_trunc) - 1))
+        out = (bits & 0x8000) | (exp << 7) | mant
+        return bitops.bits_to_bf16(jnp.where(kept, out, 0))
+
+
 def append_store(store, new_store, at) -> dict:
     """dynamic_update_slice every leaf along the S axis (axis 1 of B,S,…)."""
     def upd(c, n):

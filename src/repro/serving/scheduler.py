@@ -120,6 +120,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.format import CassandraConfig
+from repro.models import model as M
 from repro.models.layers import Runtime
 from repro.serving import kvcache as KC
 from repro.serving.blockpool import (BlockAllocator, TRASH_BLOCK,
@@ -537,6 +538,8 @@ class Scheduler:
         self.metrics.set_config("slo_aware", self.slo_aware)
         self.metrics.set_config("slo_declared", self._slo_seen)
         self.metrics.set_config("attn_kernel", self.attn_kernel)
+        self.metrics.set_config("kv_pool_decodes", M.kv_pool_decodes(
+            self.rt, self.cache, drafts=self.speculative))
         self.metrics.set_config("fused", self.fused)
         self.metrics.set_config("speculative", self.speculative)
         self.metrics.set_config("overlap", self.overlap)

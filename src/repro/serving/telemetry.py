@@ -38,9 +38,10 @@ layer:
   profiler's clock (``sched.admit|plan|dispatch|wait|harvest`` in the
   scheduler's cycle), so a ``jax.profiler`` trace puts each device idle
   gap beside the host phase that held it. The fused serving step names
-  its phases with ``jax.named_scope`` (``cass.draft``, ``cass.verify``,
-  ``cass.commit``, and ``cass.kv_decode`` / ``cass.kv_gather`` wherever
-  the packed KV is decoded or paged in); :func:`hlo_op_scopes` maps each
+  its phases with ``jax.named_scope`` (``cass.view``, ``cass.draft``,
+  ``cass.verify``, ``cass.commit``, and ``cass.kv_decode`` /
+  ``cass.kv_derive`` / ``cass.kv_gather`` wherever the packed KV is
+  decoded, derived or paged in); :func:`hlo_op_scopes` maps each
   instruction of a compiled program's text to the scopes it ran under,
   which is how the device trace's bare HLO op names are put down to a
   phase.

@@ -13,6 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.core import bitops
 from repro.core.format import CassandraConfig
 from repro.serving import kvcache as KC
 from repro.serving.blockpool import (BlockAllocator, TRASH_BLOCK,
@@ -455,6 +456,69 @@ if HAVE_HYPOTHESIS:
     @settings(**SETTINGS)
     def test_packed_roundtrip_property(seed, offset):
         _check_packed_roundtrip(seed, offset)
+
+
+# ---------------------------------------------------------------------------
+# Draft view derived from the target view
+# ---------------------------------------------------------------------------
+
+def _kv_values(kind: str, shape, seed: int) -> jax.Array:
+    """bf16 KV vectors of one kind: ``unary`` N(0, 0.25) (every block
+    unary-coded), ``delta`` magnitudes spread over 2^-40..2^20 (every
+    block delta-coded), ``zeros`` mostly +0, -0 and subnormals, so the
+    kept set holds them too."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jax.random.normal(k1, shape, jnp.float32) * 0.5
+    if kind in ("delta", "zeros"):
+        e = jax.random.uniform(k2, shape, minval=-40.0, maxval=20.0)
+        x = jnp.sign(x) * 2.0 ** e
+    x = x.astype(jnp.bfloat16)
+    if kind == "zeros":
+        pick = jax.random.randint(k3, shape, 0, 5)
+        bits = bitops.bf16_to_bits(x)
+        sub = (bits & 0x8000) | (bits & 0x7F | 1)      # exponent 0
+        bits = jnp.select([pick == 0, pick == 1, pick == 2],
+                          [jnp.zeros_like(bits), jnp.full_like(bits, 0x8000),
+                           sub], bits)
+        x = bitops.bits_to_bf16(bits)
+    return x
+
+
+@pytest.mark.parametrize("kind, shape, prune, modes", [
+    ("unary", (NB, BS, HKV, 128), 0.4, "unary"),
+    ("delta", (NB, BS, HKV, 128), 0.4, "delta"),
+    ("zeros", (NB, BS, HKV, D), 0.4, "delta"),
+    ("unary", (NB, BS, HKV, D), 0.0, "both"),        # keep == block
+    ("delta", (2, NB, BS, HKV, D), 0.4, "delta"),    # pool (R,NB,BS,Hkv,d)
+])
+def test_draft_from_target_bit_exact(kind, shape, prune, modes):
+    """``draft_from_target`` on the target view reproduces
+    ``read_store(view="draft")`` bit for bit, in unary and delta blocks,
+    at pruned positions, for ±0 and subnormals, with nothing pruned, and
+    at the pool's stacked shape."""
+    cass = CassandraConfig(variant=1, gamma=3, kv_prune=prune)
+    d = shape[-1]
+    x = _kv_values(kind, shape, seed=d + len(shape))
+    store = KC.encode_store(cass, x, d, BOOK)
+    delta = np.asarray(store["spec"]["exp_mode"]) == 1
+    assert {"unary": not delta.any(), "delta": delta.all(),
+            "both": delta.any() and not delta.all()}[modes]
+    target = KC.read_store(cass, store, d, "target", BOOK)
+    np.testing.assert_array_equal(np.asarray(bitops.bf16_to_bits(target)),
+                                  np.asarray(bitops.bf16_to_bits(x)))
+    want = KC.read_store(cass, store, d, "draft", BOOK)
+    got = jax.jit(KC.draft_from_target, static_argnums=(0, 3))(
+        cass, store["spec"], target, d)
+    np.testing.assert_array_equal(np.asarray(bitops.bf16_to_bits(got)),
+                                  np.asarray(bitops.bf16_to_bits(want)))
+    if kind == "zeros":                      # the planted values were kept
+        kept = np.asarray(bitops.unpack_bits(store["spec"]["bitmap"], d)
+                          ).reshape(shape)
+        bits = np.asarray(bitops.bf16_to_bits(x))
+        assert (kept & (bits == 0x8000)).any()
+        assert (kept & (bits & 0x7F80 == 0) & (bits & 0x7F != 0)).any()
+    if prune == 0.0:
+        assert cass.kv_keep(d) == d
 
 
 @pytest.mark.parametrize("seed", range(3))

@@ -311,6 +311,88 @@ def test_unified_zero_decode_bit_identical_to_chunk(model):
     assert _trees_equal(cache_c, cache_u)
 
 
+def _packed_paged_ready(cfg, params, cass, b=2, bs=4, mb=6):
+    """A packed paged cache holding an 8-token prompt per row (chunked
+    prefill through disjoint block tables), and the rows' next token."""
+    from repro.models.layers import Runtime
+    from repro.serving import kvcache as KC
+    from repro.serving.engine import chunk_prefill_step
+    rt = Runtime(cfg=cfg, cass=cass, view="target", ssm_chunk=8)
+    cache = KC.init_paged_cache(cfg, cass, b, b * mb + 1, bs, mb,
+                                packed=True)
+    cache["block_table"] = 1 + jnp.arange(b * mb, dtype=jnp.int32
+                                          ).reshape(b, mb)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (b, 8), 0,
+                                cfg.vocab_size)
+    last, cache = jax.jit(
+        lambda p, c, t, v: chunk_prefill_step(rt, p, c, t, v)
+    )(params, cache, tokens, jnp.full((b,), 8, jnp.int32))
+    return rt, cache, jnp.argmax(last, -1).astype(jnp.int32)[:, None]
+
+
+@pytest.mark.parametrize("step", ["unified", "spec"])
+@pytest.mark.parametrize("variant", [1, 2])
+def test_shared_kv_view_bit_identical_to_separate_decodes(model, step,
+                                                          variant,
+                                                          monkeypatch):
+    """One speculative cycle on a packed paged cache: with the cycle's KV
+    views from ``materialize_cache_view`` (Cassandra-1: one target decode,
+    the draft view derived from it; Cassandra-2: its draft view decoded
+    apart) the accepted tokens, the logits and the committed cache are
+    bit-identical to a reference cycle that builds no views, in which
+    every draft pass decodes the draft view and the verify pass the
+    target view for itself (``read_store`` in ``_attn_entry``)."""
+    from repro.core.format import CassandraConfig
+    from repro.core.packing import format_params, resolve_views
+    from repro.models import model as M
+    from repro.serving.engine import spec_decode_step, unified_step
+    cfg, params = model
+    cass = CassandraConfig(variant=variant, gamma=GAMMA)
+    params = resolve_views(format_params(params, cass), cass)
+    rt, cache, cur = _packed_paged_ready(cfg, params, cass)
+    ecfg = EngineConfig(gamma=GAMMA)
+    b, w = cur.shape[0], GAMMA + 1
+    key = jax.random.PRNGKey(3)
+
+    def run():
+        if step == "spec":
+            res, out = jax.jit(
+                lambda p, c, t, k: spec_decode_step(rt, p, c, t, k, ecfg)
+            )(params, cache, cur, key)
+            return res, None, out
+        return jax.jit(
+            lambda p, c, t, ch, v, d, k: unified_step(rt, p, c, t, ch, v, d,
+                                                      k, ecfg)
+        )(params, cache, cur, jnp.zeros((b, w), jnp.int32),
+          jnp.zeros((b,), jnp.int32), jnp.ones((b,), bool), key)
+
+    res, last, out = run()
+    monkeypatch.setattr(M, "materialize_cache_view",
+                        lambda rt, cache: (None, None))
+    res_r, last_r, out_r = run()
+    assert _trees_equal(res._asdict(), res_r._asdict())
+    assert _trees_equal(last, last_r)
+    assert _trees_equal(out, out_r)
+    assert int(jnp.min(res.n_accepted)) < GAMMA   # the draft differs
+
+
+@pytest.mark.parametrize("cass_variant, attn_kernel, decodes", [
+    (None, "off", 0), (1, "off", 1), (2, "off", 2), (1, "jnp", 1)])
+def test_kv_pool_decodes_reported(model, cass_variant, attn_kernel, decodes):
+    """The summary says how many full-pool KV decodes a cycle holds: none
+    for a plain cache, one for Cassandra-1 (verify's target decode feeds
+    the draft view) and for the paged kernel, two for Cassandra-2."""
+    from repro.core.format import CassandraConfig
+    cfg, params = model
+    cass = (CassandraConfig(variant=cass_variant, gamma=GAMMA)
+            if cass_variant else None)
+    sched = Scheduler(cfg, params, cass=cass, ecfg=EngineConfig(gamma=GAMMA),
+                      num_slots=2, s_max=S_MAX, rt_extra={"ssm_chunk": 8},
+                      paged=True, block_size=4, num_blocks=24,
+                      attn_kernel=attn_kernel)
+    assert sched.summary()["subsystems"]["kv_pool_decodes"] == decodes
+
+
 @pytest.mark.parametrize("paged", [False, True])
 def test_fused_matches_alternating_trace(model, paged):
     """Losslessness: a staggered mixed-length trace through the fused
