@@ -1,8 +1,21 @@
-"""Plain reference of the served models, and the comparison behind ``correct``.
+"""Model type ``qwen3``: all that the benchmark knows of this model type.
+The harness finds this file by a configuration file's
+``hf_config.model_type`` (``bench/arch/<model_type>.py``). The Qwen2
+decoder is the same with ``architecture.qk_norm`` off and ``qkv_bias`` on.
 
-Imports nothing of the program. It reads the configuration file's
-published numbers (the Hugging Face ``config.json`` keys) and computes,
-in float32 with every matmul at ``Precision.HIGHEST``:
+* ``program_config(conf, base)`` — the program's ``ModelConfig``: the
+  registry entry ``base`` (of ``conf["arch"]``) with every size taken
+  from the file.
+* The plain reference, the comparison behind ``correct``:
+  ``Dims.from_config``, ``init_weights``, ``gaps(..., control=...)``, and
+  ``loader_weights``, the program loader's tree in ``init_weights``'s
+  layout, for the test that the two draw the same weights.
+* The useful work (the section at the end): ``token_flops(conf,
+  positions)`` and ``span_flops(conf, start, stop)``.
+
+Imports nothing of the program. The reference reads the configuration
+file's published numbers (the Hugging Face ``config.json`` keys) and
+computes, in float32 with every matmul at ``Precision.HIGHEST``:
 
 * ``init_weights`` — the seeded random weights that the program's loader
   draws for a dense GQA decoder (``repro.models.init_params``): the same
@@ -71,6 +84,22 @@ class Dims:
                    qkv_bias=bool(c["architecture"]["qkv_bias"]))
 
 
+def program_config(conf: dict, base):
+    """The program's ``ModelConfig``: ``base``, the registry entry of
+    ``conf["arch"]``, with every size taken from the file."""
+    hf, arch = conf["hf_config"], conf["architecture"]
+    return dataclasses.replace(
+        base, n_layers=hf["num_hidden_layers"], d_model=hf["hidden_size"],
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"],
+        d_ff=hf["intermediate_size"], vocab_size=hf["vocab_size"],
+        head_dim=hf.get("head_dim",
+                        hf["hidden_size"] // hf["num_attention_heads"]),
+        rope_theta=float(hf["rope_theta"]), norm_eps=hf["rms_norm_eps"],
+        tie_embeddings=hf["tie_word_embeddings"], qk_norm=arch["qk_norm"],
+        qkv_bias=arch["qkv_bias"])
+
+
 # ---------------------------------------------------------------------------
 # Weights
 # ---------------------------------------------------------------------------
@@ -118,6 +147,24 @@ def init_weights(m: Dims, key) -> dict:
     return {"embed": _normal(k_emb, (m.vocab, m.d), 0.02),
             "final_norm": jnp.ones((m.d,), jnp.float32),
             "layers": jax.vmap(lambda k: _layer(k, m))(lkeys)}
+
+
+def loader_weights(params: dict) -> dict:
+    """The tree of the program's loader (``repro.models.init_params``) in
+    :func:`init_weights`'s layout, leaf for leaf."""
+    layer = params["dec"][0]["e0"]
+    attn, mlp = layer["attn"], layer["ffn"]
+    w = {name: attn[name]["w"] for name in ("wq", "wk", "wv", "wo")}
+    w.update({name: mlp[name]["w"] for name in ("w_up", "w_down", "w_gate")})
+    w.update(ln1=layer["norm1"]["scale"], ln2=layer["norm2"]["scale"])
+    for n in "qkv":
+        if "b" in attn[f"w{n}"]:
+            w[f"b{n}"] = attn[f"w{n}"]["b"]
+    for n in ("q_norm", "k_norm"):
+        if n in attn:
+            w[n] = attn[n]["scale"]
+    return {"embed": params["embed"]["table"],
+            "final_norm": params["final_norm"]["scale"], "layers": w}
 
 
 def quantize_int8(w: jax.Array, axis: int) -> jax.Array:
@@ -240,3 +287,55 @@ def gaps(m: Dims, weights: dict, prompt: np.ndarray, served: np.ndarray,
                          jnp.asarray(tgt))
         out.append(np.asarray(jax.device_get(gap))[:k])
     return np.concatenate(out) if out else np.zeros(0)
+
+
+# ---------------------------------------------------------------------------
+# Useful work
+# ---------------------------------------------------------------------------
+#
+# Only the bfloat16 target model's *useful* work is counted: what a plain
+# autoregressive server of the same model must do for the same tokens. A
+# speculative server does more (γ draft passes, rejected positions), so a
+# utilization built on this count cannot pass what the device really did.
+#
+# Per token at context position ``c`` (the token attends to ``c + 1`` keys,
+# itself included), with ``d`` hidden size, ``H``/``Hkv`` query/KV heads of
+# size ``hd``, ``F`` the MLP width, ``V`` the vocabulary and ``L`` layers:
+#
+#     matmul_params = L * (d*H*hd + 2*d*Hkv*hd + H*hd*d + 3*d*F) + d*V
+#     flops(c)      = 2 * matmul_params + L * 4 * H * hd * (c + 1)
+#
+# The first term is a multiply-add per weight (Q, K, V, O projections, the
+# SwiGLU gate, up and down projections, and the LM head; the embedding is a
+# row lookup and costs none). The second is QK^T and PV: 2 FLOPs per
+# multiply-add, two products of ``H * hd`` per key. Norms, rotary
+# embedding, softmax and biases are left out (under 0.1% here).
+
+def matmul_params(conf: dict) -> int:
+    hf = conf["hf_config"]
+    d = hf["hidden_size"]
+    h, hkv = hf["num_attention_heads"], hf["num_key_value_heads"]
+    hd = hf.get("head_dim", d // h)
+    per_layer = d * h * hd + 2 * d * hkv * hd + h * hd * d \
+        + 3 * d * hf["intermediate_size"]
+    return hf["num_hidden_layers"] * per_layer + d * hf["vocab_size"]
+
+
+def attention_flops_per_key(conf: dict) -> int:
+    """FLOPs of one token against one key, over all layers."""
+    hf = conf["hf_config"]
+    h = hf["num_attention_heads"]
+    hd = hf.get("head_dim", hf["hidden_size"] // h)
+    return hf["num_hidden_layers"] * 4 * h * hd
+
+
+def token_flops(conf: dict, positions) -> float:
+    """Useful FLOPs of the tokens at the given context positions."""
+    pos = np.asarray(positions, np.float64)
+    return float(pos.size * 2 * matmul_params(conf)
+                 + attention_flops_per_key(conf) * (pos + 1).sum())
+
+
+def span_flops(conf: dict, start: int, stop: int) -> float:
+    """Useful FLOPs of the tokens at positions ``start .. stop - 1``."""
+    return token_flops(conf, np.arange(start, stop))

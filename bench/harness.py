@@ -1,11 +1,17 @@
 """One benchmark run of one cell: set-up, measured window, readings, check.
 
-Everything is found by name. The cell in ``BENCHMARK.json`` names its
-configuration (``bench/configs/<config>.json``) and traffic mix
-(``bench/traffic/<traffic>.json``); the mix's ``kind`` names the module
-that offers its requests and drives the window (``bench/traffic/<kind>.py``,
-see :func:`load_kind`); the cell's own numbers, its correctness limit
-among them, are in ``bench/cells/<cell>.json``; each metric is read by
+Everything is found by name, so a configuration of any model family, a
+traffic mix, a cell or a metric is added by new files and new entries
+alone. The cell in ``BENCHMARK.json`` names its configuration
+(``bench/configs/<config>.json``) and traffic mix
+(``bench/traffic/<traffic>.json``); the configuration's
+``hf_config.model_type`` names the module of all that is particular to
+its model type (``bench/arch/<model_type>.py``, see :func:`load_arch`):
+the program's ``ModelConfig``, the plain reference and the useful-work
+count; the mix's ``kind`` names the module that offers its requests and
+drives the window (``bench/traffic/<kind>.py``, see :func:`load_kind`);
+the cell's own numbers, its correctness limit among them, are in
+``bench/cells/<cell>.json``; each metric is read by
 ``bench/metrics/<metric>.py`` from the :class:`Readings` of the run.
 
 The system under test is the program's normal served path: weights from
@@ -76,7 +82,8 @@ class Readings:
     summary: dict                # Scheduler.summary() after the window
     events: list                 # lifecycle tracer events (traced runs)
     peak_bytes: int
-    hf: dict                     # the configuration's published numbers
+    conf: dict                   # the configuration file
+    arch: object                 # its model type's module (load_arch)
     peaks: dict                  # the device's peak rates
     trace: dict | None = None    # trace_reduce.reduce() of the traced run
     traced: tuple | None = None  # perf_counter start, stop of the trace
@@ -94,11 +101,47 @@ def load_json(path: Path) -> dict:
 
 
 def load_module(path: Path):
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_").replace("-", "_"), path)
+    """The module at ``path``, run once a process: a second run would
+    trace and compile its jitted functions again."""
+    name = "bench_" + path.stem.replace(".", "_").replace("-", "_")
+    mod = sys.modules.get(name)
+    if mod is not None and mod.__file__ == str(path):
+        return mod
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    sys.modules[name] = mod          # a dataclass looks its module up
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[name]
+        raise
     return mod
+
+
+def load_arch(bench_dir: Path, conf: dict):
+    """The module of a configuration's model type:
+    ``bench/arch/<hf_config.model_type>.py``. It gives
+
+    * ``program_config(conf, base)``: the program's ``ModelConfig``, the
+      registry entry ``base`` of ``conf["arch"]`` with every size taken
+      from the file;
+    * the plain reference, which imports nothing of the program and
+      computes in float32 at ``Precision.HIGHEST``: ``Dims.from_config
+      (conf)``, ``init_weights(dims, key)`` (the program's weights at
+      ``weights_key``, drawn again), ``gaps(dims, weights, prompt,
+      served, control=None)`` with the controls ``"int8"`` and
+      ``"fp8"``, and ``loader_weights(params)``, the program loader's
+      tree in ``init_weights``'s layout for the test that the two agree;
+    * the useful work of the bfloat16 target model: ``token_flops(conf,
+      positions)`` and ``span_flops(conf, start, stop)``.
+    """
+    model_type = conf["hf_config"].get("model_type")
+    path = Path(bench_dir) / "arch" / f"{model_type}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"no module for model type {model_type!r} (the configuration's "
+            f"hf_config.model_type) at {path}")
+    return load_module(path)
 
 
 def load_kind(bench_dir: Path, mix: dict):
@@ -119,25 +162,14 @@ def cell_metrics(bench: dict, cell: str, section: str) -> list[dict]:
             if "workloads" not in m or cell in m["workloads"]]
 
 
-def program_config(conf: dict):
-    """The program's ModelConfig for a configuration file: the registry
-    entry of ``arch`` with every size taken from the file."""
+def program_config(conf: dict, arch):
+    """The program's ModelConfig for a configuration file of any family:
+    its model type's module (``arch``) maps the file's sizes onto the
+    program's registry entry of ``conf["arch"]``. That module, a new file
+    ``bench/arch/<model_type>.py`` for a new model type, also gives the
+    plain reference and the useful-work count (:func:`load_arch`)."""
     from repro.configs import get_config
-    hf, arch = conf["hf_config"], conf["architecture"]
-    base = get_config(conf["arch"])
-    if base.family != "dense":
-        raise ValueError(f"{conf['arch']}: only dense decoders are served "
-                         "by this harness")
-    return dataclasses.replace(
-        base, n_layers=hf["num_hidden_layers"], d_model=hf["hidden_size"],
-        n_heads=hf["num_attention_heads"],
-        n_kv_heads=hf["num_key_value_heads"],
-        d_ff=hf["intermediate_size"], vocab_size=hf["vocab_size"],
-        head_dim=hf.get("head_dim",
-                        hf["hidden_size"] // hf["num_attention_heads"]),
-        rope_theta=float(hf["rope_theta"]), norm_eps=hf["rms_norm_eps"],
-        tie_embeddings=hf["tie_word_embeddings"], qk_norm=arch["qk_norm"],
-        qkv_bias=arch["qkv_bias"])
+    return arch.program_config(conf, get_config(conf["arch"]))
 
 
 def weights_key(conf: dict):
@@ -239,7 +271,7 @@ class WindowTrace:
 # Set-up
 # ---------------------------------------------------------------------------
 
-def build(conf: dict, mix: dict, kind, trace: bool):
+def build(conf: dict, arch, mix: dict, kind, trace: bool):
     """Weights -> packed format -> dense views -> paged scheduler."""
     from repro.core.format import CassandraConfig
     from repro.core.packing import format_params, resolve_views
@@ -248,7 +280,7 @@ def build(conf: dict, mix: dict, kind, trace: bool):
     from repro.serving.scheduler import Scheduler
     from repro.serving.telemetry import Telemetry
 
-    cfg = program_config(conf)
+    cfg = program_config(conf, arch)
     srv = conf["serving"]
     gamma = srv["gamma"]
     cass = CassandraConfig(variant=srv["variant"], gamma=gamma)
@@ -308,12 +340,12 @@ def submit(sched, rec: RequestRecord, spec) -> None:
 # Correctness
 # ---------------------------------------------------------------------------
 
-def compare(conf: dict, seed: int, records,
+def compare(conf: dict, arch, seed: int, records,
             control: str | None = None) -> dict:
-    """Reference gaps of every token served so far to every request, or,
-    past ``SAMPLE_REQUESTS`` of them, to a sample drawn from the seed with
-    the one with most served tokens always in it."""
-    import reference as R
+    """Reference gaps (the model type's ``arch.gaps``) of every token
+    served so far to every request, or, past ``SAMPLE_REQUESTS`` of them,
+    to a sample drawn from the seed with the one with most served tokens
+    always in it."""
     served = [r for r in records if r.req is not None and r.req.output]
     if len(served) > SAMPLE_REQUESTS:
         longest = max(served, key=lambda r: len(r.req.output))
@@ -321,11 +353,11 @@ def compare(conf: dict, seed: int, records,
         rng = np.random.default_rng([int(seed), 11])
         pick = rng.permutation(len(rest))[:SAMPLE_REQUESTS - 1]
         served = [longest] + [rest[i] for i in sorted(pick)]
-    dims = R.Dims.from_config(conf)
-    weights = R.init_weights(dims, weights_key(conf))
+    dims = arch.Dims.from_config(conf)
+    weights = arch.init_weights(dims, weights_key(conf))
     gaps, n = [], 0
     for r in served:
-        g = R.gaps(dims, weights, np.asarray(r.req.tokens),
+        g = arch.gaps(dims, weights, np.asarray(r.req.tokens),
                    np.asarray(r.req.output, np.int32), control=control)
         gaps.append(float(g.max()))
         n += len(g)
@@ -357,6 +389,7 @@ def run_cell(root: Path, cell_name: str, seed: int, seconds: float,
                       if c["name"] == cell["config"])
     conf = load_json(root / conf_entry["file"])
     bench_dir = root / "bench"
+    arch = load_arch(bench_dir, conf)
     mix = load_json(bench_dir / "traffic" / f"{cell['traffic']}.json")
     own = load_json(bench_dir / "cells" / f"{cell_name}.json")
     kind = load_kind(bench_dir, mix)
@@ -369,7 +402,7 @@ def run_cell(root: Path, cell_name: str, seed: int, seconds: float,
     vocab = conf["hf_config"]["vocab_size"]
 
     phases = []
-    sched = build(conf, mix, kind, trace)
+    sched = build(conf, arch, mix, kind, trace)
     phases.append(memory("built"))
     warm_up(sched, vocab, seed)
     phases.append(memory("warmed up"))
@@ -391,7 +424,7 @@ def run_cell(root: Path, cell_name: str, seed: int, seconds: float,
         t0=win.t0, t1=win.t1, t_end=win.t_end, setup_s=setup_s,
         records=win.records, counters0=c0, counters1=c1,
         walls0=w0, walls1=w1, summary=summary, events=events,
-        peak_bytes=peak, hf=conf["hf_config"],
+        peak_bytes=peak, conf=conf, arch=arch,
         peaks=peaks["devices"][device_kind],
         traced=(tr.t0, tr.t1) if trace else None)
     del sched, state
@@ -415,8 +448,9 @@ def run_cell(root: Path, cell_name: str, seed: int, seconds: float,
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
 
     records = win.records
-    cmp = compare(conf, seed, records)
-    ctl = {c: compare(conf, seed, records, control=c) for c in controls}
+    cmp = compare(conf, arch, seed, records)
+    ctl = {c: compare(conf, arch, seed, records, control=c)
+           for c in controls}
     checks = {
         "widest_gap": {"value": cmp["widest_gap"],
                        "limit": own["widest_gap_max"]},

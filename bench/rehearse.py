@@ -46,7 +46,9 @@ def rehearse(conf: dict, mix: dict, layers: int,
     from repro.serving.scheduler import Scheduler
 
     import harness
-    cfg = dataclasses.replace(harness.program_config(conf), n_layers=layers)
+    arch = harness.load_arch(BENCH_DIR, conf)
+    cfg = dataclasses.replace(harness.program_config(conf, arch),
+                              n_layers=layers)
     srv = conf["serving"]
     gamma, slots, bs = srv["gamma"], mix["slots"], srv["block_size"]
     cass = CassandraConfig(variant=srv["variant"], gamma=gamma)

@@ -6,12 +6,12 @@ for a chip (``harness.run_cell`` is called directly).
 import json
 import time
 
+import jax
 import numpy as np
 import pytest
 
 import harness
-import reference as R
-from conftest import DATA, TINY_CELL
+from conftest import BENCH, DATA, TINY_CELL
 
 SEED = 2**33 + 5
 
@@ -21,29 +21,39 @@ def run(root, cell, **kw):
                             time.perf_counter(), "TPU v5 lite", **kw)
 
 
-@pytest.mark.parametrize("arch", [{"qk_norm": True, "qkv_bias": False},
-                                  {"qk_norm": False, "qkv_bias": True}])
-def test_reference_weights_equal_the_loaders(arch):
+def weight_cases():
+    """Each module under ``bench/arch`` with each test configuration of
+    its model type (``data/*.json`` by ``hf_config.model_type``); a
+    module with none is a case of its own that fails."""
+    confs = {}
+    for path in sorted(DATA.glob("*.json")):
+        model_type = json.loads(path.read_text()).get(
+            "hf_config", {}).get("model_type")
+        confs.setdefault(model_type, []).append(path.name)
+    cases = []
+    for module in sorted((BENCH / "arch").glob("*.py")):
+        names = confs.get(module.stem, [None])
+        cases += [pytest.param(module.stem, n, id=f"{module.stem}-{n}")
+                  for n in names]
+    return cases
+
+
+@pytest.mark.parametrize("model_type,conf_name", weight_cases())
+def test_reference_weights_equal_the_loaders(model_type, conf_name):
     """The reference draws its own weights; they must be the program's,
     bit for bit, or it would judge another model."""
     from repro.models import init_params
-    conf = json.loads((DATA / "tiny.json").read_text())
-    conf["architecture"].update(arch)
+    assert conf_name, f"no test configuration of {model_type} in {DATA}"
+    conf = json.loads((DATA / conf_name).read_text())
+    arch = harness.load_arch(BENCH, conf)
     key = harness.weights_key(conf)
-    prog = init_params(harness.program_config(conf), key)
-    ref = R.init_weights(R.Dims.from_config(conf), key)
-    np.testing.assert_array_equal(prog["embed"]["table"], ref["embed"])
-    layer = prog["dec"][0]["e0"]
-    pairs = {"wq": layer["attn"]["wq"], "wk": layer["attn"]["wk"],
-             "wv": layer["attn"]["wv"], "wo": layer["attn"]["wo"],
-             "w_up": layer["ffn"]["w_up"], "w_down": layer["ffn"]["w_down"],
-             "w_gate": layer["ffn"]["w_gate"]}
-    for name, p in pairs.items():
-        np.testing.assert_array_equal(p["w"], ref["layers"][name], name)
-    if arch["qkv_bias"]:
-        for n in "qkv":
-            np.testing.assert_array_equal(layer["attn"][f"w{n}"]["b"],
-                                          ref["layers"][f"b{n}"])
+    prog = arch.loader_weights(
+        init_params(harness.program_config(conf, arch), key))
+    ref = arch.init_weights(arch.Dims.from_config(conf), key)
+    assert jax.tree.structure(prog) == jax.tree.structure(ref)
+    jax.tree_util.tree_map_with_path(
+        lambda path, p, r: np.testing.assert_array_equal(
+            p, r, jax.tree_util.keystr(path)), prog, ref)
 
 
 def test_sound_run_is_correct_and_control_is_not(tiny_root):

@@ -1,9 +1,16 @@
-"""A configuration, a traffic mix, a traffic kind, a cell and a per-layer
-metric are added by new files and new entries alone: the harness finds
-them by name, and no file that is already there is edited."""
+"""A configuration of any model family, a traffic mix, a traffic kind, a
+cell and a per-layer metric are added by new files and new entries alone:
+the harness finds them by name, and no file that is already there is
+edited. A configuration's model type (``hf_config.model_type``) names its
+module, ``bench/arch/<model_type>.py``, which gives the program's
+``ModelConfig`` (``program_config``), the plain reference (``Dims``,
+``init_weights``, ``gaps``, ``loader_weights``) and the useful-work count
+(``token_flops``, ``span_flops``); ``harness.load_arch`` says more."""
 import json
 import shutil
 import time
+
+import pytest
 
 import harness
 from conftest import DATA, TINY_MIX
@@ -66,3 +73,71 @@ def test_new_files_are_found(tiny_root):
                            time.perf_counter(), "TPU v5 lite")
     assert res["correct"], res["checks"]
     assert res["metrics"]["output_tokens_per_s"]["value"] > 0
+
+
+def test_new_model_type_is_found(tiny_root):
+    """A configuration of a model type that has no module yet, added with
+    its module (here the qwen3 module copied under the name ``qwen2``),
+    its mix and its cell, reaches ``correct``."""
+    bench_dir = tiny_root / "bench"
+    before = _snapshot(tiny_root)
+    shutil.copy(bench_dir / "arch" / "qwen3.py",
+                bench_dir / "arch" / "qwen2.py")
+    conf = json.loads((DATA / "tiny-bias.json").read_text())
+    conf["hf_config"]["model_type"] = "qwen2"
+    (bench_dir / "configs" / "tiny-2.json").write_text(json.dumps(conf))
+    shutil.copy(DATA / "limits.json",
+                bench_dir / "cells" / "tiny-2.reason.json")
+    bench = json.loads((tiny_root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "tiny-2", "source": "test",
+                             "file": "bench/configs/tiny-2.json",
+                             "reduced": [], "why": "CPU test"})
+    bench["workloads"].append({"name": "tiny-2.reason", "config": "tiny-2",
+                               "traffic": TINY_MIX, "chips": 1,
+                               "why": "CPU test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append("tiny-2.reason")
+    (tiny_root / "BENCHMARK.json").write_text(json.dumps(bench))
+    after = _snapshot(tiny_root)
+    assert [p for p in before if p.name != "BENCHMARK.json"
+            and after[p] != before[p]] == []
+
+    res = harness.run_cell(tiny_root, "tiny-2.reason", 5, 2.0, False,
+                           time.perf_counter(), "TPU v5 lite")
+    assert res["correct"], res["checks"]
+    assert res["compare"]["tokens_compared"] >= 8
+
+
+def test_model_type_without_module_fails_at_once(tiny_root):
+    conf_path = tiny_root / "bench" / "configs" / "tiny.json"
+    conf = json.loads(conf_path.read_text())
+    conf["hf_config"]["model_type"] = "no_such_type"
+    conf_path.write_text(json.dumps(conf))
+    t0 = time.perf_counter()
+    with pytest.raises(FileNotFoundError, match="no_such_type"):
+        harness.run_cell(tiny_root, "tiny.reason", 5, 2.0, False, t0,
+                         "TPU v5 lite")
+    assert time.perf_counter() - t0 < 5.0
+
+
+def test_non_dense_model_is_served(tiny_root):
+    """A module whose ``program_config`` gives a mixture-of-experts
+    ``ModelConfig``: set-up, warm-up and the traffic's window serve it as
+    they serve a dense one."""
+    bench_dir = tiny_root / "bench"
+    shutil.copy(DATA / "tiny_moe.py", bench_dir / "arch" / "tiny_moe.py")
+    conf = json.loads((DATA / "tiny-moe.json").read_text())
+    arch = harness.load_arch(bench_dir, conf)
+    assert harness.program_config(conf, arch).family == "moe"
+    mix = json.loads((DATA / f"{TINY_MIX}.json").read_text())
+    kind = harness.load_kind(bench_dir, mix)
+    vocab = conf["hf_config"]["vocab_size"]
+    sched = harness.build(conf, arch, mix, kind, False)
+    harness.warm_up(sched, vocab, 5)
+    state = kind.start(sched, mix, {}, 5, vocab)
+    win = kind.window(sched, state, 1.0, harness.WindowTrace(None))
+    served = [t for r in win.records if r.req is not None
+              for t in r.req.output]
+    assert len(served) > len(win.records)
+    assert all(0 <= t < vocab for t in served)
