@@ -10,6 +10,7 @@ import importlib
 
 from repro.configs.base import (  # noqa: F401
     ModelConfig,
+    YarnScaling,
     LayerGroup,
     layer_groups,
     input_specs,
@@ -32,6 +33,8 @@ _MODULES = {
     # the paper's own evaluation models
     "llama3-8b": "llama3_8b",
     "qwen3-4b": "qwen3_4b",
+    # the edge-sized MLA + expert model the benchmark serves
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ASSIGNED = tuple(list(_MODULES)[:10])

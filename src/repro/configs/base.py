@@ -14,9 +14,40 @@ executed with ``lax.scan`` so HLO size stays O(pattern), not O(depth).
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax.numpy as jnp
 from jax import ShapeDtypeStruct
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (``rope_scaling.type == "yarn"``), as the
+    DeepSeek-V2 reference ``modeling_deepseek.py`` applies it."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def get_mscale(scale: float, mscale: float) -> float:
+        return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+    @property
+    def attn_factor(self) -> float:
+        """Factor on the softmax scale: mscale(factor, mscale_all_dim)²."""
+        if not self.mscale_all_dim:
+            return 1.0
+        return self.get_mscale(self.factor, self.mscale_all_dim) ** 2
+
+    @property
+    def cos_sin_factor(self) -> float:
+        """Factor on cos and sin: mscale(factor, mscale) over
+        mscale(factor, mscale_all_dim)."""
+        return (self.get_mscale(self.factor, self.mscale)
+                / self.get_mscale(self.factor, self.mscale_all_dim))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +65,7 @@ class ModelConfig:
     qkv_bias: bool = False
     ffn_act: str = "swiglu"          # swiglu | relu2 | gelu
     rope_theta: float = 1_000_000.0
+    yarn: YarnScaling | None = None  # None: plain rotary frequencies
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     # --- MoE ---
@@ -42,9 +74,12 @@ class ModelConfig:
     n_shared_experts: int = 0        # deepseek: always-on shared expert(s)
     first_dense_layers: int = 0      # deepseek: first k layers use dense FFN
     moe_d_ff: int = 0                # expert hidden dim (0 -> d_ff)
+    norm_topk_prob: bool = True      # renormalise the top-k probabilities
+    expert_first: int = 0            # first expert id held on this chip
+    experts_held: int = 0            # experts held here (0 -> n_experts)
     # --- MLA (deepseek) ---
     mla: bool = False
-    q_lora_rank: int = 1536
+    q_lora_rank: int = 1536          # 0: a direct q projection
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
@@ -80,6 +115,12 @@ class ModelConfig:
     @property
     def expert_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this chip holds: ids
+        ``expert_first .. expert_first + n_held - 1`` of ``n_experts``."""
+        return self.experts_held or self.n_experts
 
     @property
     def is_encdec(self) -> bool:

@@ -58,7 +58,12 @@ def build_codebook(exps: jax.Array) -> tuple[jax.Array, jax.Array]:
     frequent exponent. Ranks beyond the observed alphabet map past MAX_RANK
     so the encoder falls back to delta mode for blocks containing them.
     """
-    counts = bitops.histogram256(exps)
+    return codebook_from_counts(bitops.histogram256(exps))
+
+
+def codebook_from_counts(counts: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """:func:`build_codebook` from the exponents' (256,) histogram, so a
+    tensor's book can be summed block by block."""
     order = jnp.argsort(-counts, stable=True)  # descending frequency
     exp_of_rank = order.astype(jnp.uint8)
     rank_of_exp = jnp.zeros(256, dtype=jnp.int32).at[order].set(jnp.arange(256))

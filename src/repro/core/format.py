@@ -155,7 +155,9 @@ def format_tensor(x: jax.Array, scores: jax.Array, cfg: CassandraConfig,
                   block: int, keep: int, group: int, trunc: int,
                   codebook: tuple[jax.Array, jax.Array] | None = None,
                   corr_bits: int = coding.CORR_BITS,
-                  pruned_raw: bool = False) -> tuple[dict, dict]:
+                  pruned_raw: bool = False,
+                  pruned_codebook: tuple[jax.Array, jax.Array] | None = None,
+                  ) -> tuple[dict, dict]:
     """Partition (..., N) bf16 into (speculation, verification) pytrees.
 
     Layout of the result (all static shapes):
@@ -166,7 +168,9 @@ def format_tensor(x: jax.Array, scores: jax.Array, cfg: CassandraConfig,
 
     ``codebook`` — optional external (exp_of_rank, rank_of_exp) pair, used
     for online KV encoding where the codebook is cache-global and stationary
-    (per-tensor books are built when None). ``corr_bits=8`` guarantees exact
+    (per-tensor books are built when None); it codes the pruned exponents
+    too unless ``pruned_codebook`` is given. External books are not stored
+    in the result. ``corr_bits=8`` guarantees exact
     reconstruction for arbitrary per-block exponent range. ``pruned_raw``
     stores pruned values as raw u16 even for Cassandra-1 (online KV path —
     skips entropy-coding the verification side).
@@ -203,7 +207,8 @@ def format_tensor(x: jax.Array, scores: jax.Array, cfg: CassandraConfig,
                 p_of_rank, p_rank = coding.build_codebook(pexp)
                 verif["pruned_codebook"] = coding.trim_codebook(p_of_rank)
             else:
-                p_rank = codebook[1]
+                p_rank = (codebook if pruned_codebook is None
+                          else pruned_codebook)[1]
             pregion = coding.encode_exponents(pexp, p_rank, cfg.exp_bits,
                                               corr_bits)
             verif["pruned_exp_words"] = pregion["words"]

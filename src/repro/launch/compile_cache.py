@@ -8,7 +8,7 @@ never a temp, pid or time-stamped one.
 
 Entry points call :func:`enable_compile_cache` first thing under their
 ``__main__`` guard. Importing this module changes nothing, and the test
-suite never calls it.
+suite calls it only in processes of its own.
 """
 from __future__ import annotations
 
@@ -23,11 +23,18 @@ DEFAULT_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 def enable_compile_cache() -> str:
     """Turn the persistent compilation cache on; return its directory.
 
-    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
-    nothing is set here; otherwise the cache goes to :data:`DEFAULT_DIR`.
+    Where ``JAX_COMPILATION_CACHE_DIR`` names another directory, JAX
+    already reads it and nothing is set here; otherwise the cache goes to
+    :data:`DEFAULT_DIR`, with no size cap. A cap meant for a shared
+    directory (``JAX_COMPILATION_CACHE_MAX_SIZE``) would evict, least
+    recently used first, a run's oldest programs once they outgrow it:
+    the next process asks for them in the order they were written, so
+    each miss's write evicts the entry it asks for next, and it loads
+    none.
     """
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env:
+    if env and Path(env).resolve() != DEFAULT_DIR:
         return env
     jax.config.update("jax_compilation_cache_dir", str(DEFAULT_DIR))
+    jax.config.update("jax_compilation_cache_max_size", -1)
     return str(DEFAULT_DIR)

@@ -295,7 +295,7 @@ def gqa_attention(rt: Runtime, p: dict, x: jax.Array, positions: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# MLA block (deepseek-v3)
+# MLA block (deepseek-v2/v3)
 # ---------------------------------------------------------------------------
 
 def mla_latent(rt: Runtime, p: dict, x: jax.Array, positions: jax.Array
@@ -306,21 +306,33 @@ def mla_latent(rt: Runtime, p: dict, x: jax.Array, positions: jax.Array
     c = L.rmsnorm(p["kv_a_norm"], kv_full[..., :cfg.kv_lora_rank], cfg.norm_eps)
     k_rope = kv_full[..., cfg.kv_lora_rank:]
     k_rope = L.apply_rope(k_rope[:, :, None, :], positions,
-                          cfg.rope_theta)[:, :, 0]
+                          cfg.rope_theta, cfg.yarn)[:, :, 0]
     return c, k_rope
 
 
 def _mla_q(rt: Runtime, p: dict, x: jax.Array, positions: jax.Array
            ) -> tuple[jax.Array, jax.Array]:
+    """Per-head (q_nope, q_rope): through the q-LoRA (``q_a``, its norm,
+    ``q_b``), or one direct projection ``wq`` when ``q_lora_rank`` is 0."""
     cfg = rt.cfg
     b, s, _ = x.shape
-    ql = L.rmsnorm(p["q_a_norm"], L.dense(rt, p["q_a"], x, "mla.q_a"),
-                   cfg.norm_eps)
-    q = L.dense(rt, p["q_b"], ql, "mla.q_b").reshape(
-        b, s, cfg.n_heads, cfg.qk_nope_dim + cfg.qk_rope_dim)
+    if cfg.q_lora_rank:
+        ql = L.rmsnorm(p["q_a_norm"], L.dense(rt, p["q_a"], x, "mla.q_a"),
+                       cfg.norm_eps)
+        q = L.dense(rt, p["q_b"], ql, "mla.q_b")
+    else:
+        q = L.dense(rt, p["wq"], x, "mla.wq")
+    q = q.reshape(b, s, cfg.n_heads, cfg.qk_nope_dim + cfg.qk_rope_dim)
     q_nope = q[..., :cfg.qk_nope_dim]
-    q_rope = L.apply_rope(q[..., cfg.qk_nope_dim:], positions, cfg.rope_theta)
+    q_rope = L.apply_rope(q[..., cfg.qk_nope_dim:], positions,
+                          cfg.rope_theta, cfg.yarn)
     return q_nope, q_rope
+
+
+def mla_scale(cfg) -> float:
+    """Softmax scale: (nope + rope)^-1/2, times mscale² under YaRN."""
+    scale = 1.0 / ((cfg.qk_nope_dim + cfg.qk_rope_dim) ** 0.5)
+    return scale * cfg.yarn.attn_factor if cfg.yarn else scale
 
 
 def _kv_b_split(rt: Runtime, p: dict) -> tuple[jax.Array, jax.Array]:
@@ -354,7 +366,7 @@ def mla_attention(rt: Runtime, p: dict, x: jax.Array, positions: jax.Array,
     """
     cfg = rt.cfg
     b, sq, _ = x.shape
-    scale = 1.0 / ((cfg.qk_nope_dim + cfg.qk_rope_dim) ** 0.5)
+    scale = mla_scale(cfg)
     q_nope, q_rope = _mla_q(rt, p, x, positions)
     new_c, new_kr = mla_latent(rt, p, x, positions)
 
@@ -490,12 +502,12 @@ def mla_attention_paged(rt: Runtime, p: dict, x: jax.Array,
     The kernel consumes the (c, k_rope) latent pools directly with
     absorbed-order math — the same latent flash that serves >2048
     prefill, walking the block table instead of a contiguous sequence.
-    (MLA pools are always plain: the rope dim is too narrow for the
-    32-lane Cassandra bit-pack, so packed MLA caches don't exist.)
+    A packed latent pool reaches it decoded (``ventry`` or
+    ``read_store``): the kernel reads dense latents only.
     """
     cfg = rt.cfg
     b, sq, _ = x.shape
-    scale = 1.0 / ((cfg.qk_nope_dim + cfg.qk_rope_dim) ** 0.5)
+    scale = mla_scale(cfg)
     q_nope, q_rope = _mla_q(rt, p, x, positions)
     new_c, new_kr = mla_latent(rt, p, x, positions)
     w_uk, w_uv = _kv_b_split(rt, p)

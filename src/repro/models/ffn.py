@@ -1,12 +1,17 @@
-"""Feed-forward blocks: dense MLP (SwiGLU / squared-ReLU / GELU) and
-capacity-bounded top-k MoE.
+"""Feed-forward blocks: dense MLP (SwiGLU / squared-ReLU / GELU) and a
+dropless top-k mixture of experts that computes the experts this chip
+holds.
 
-MoE dispatch is sort-based (no one-hot dispatch tensor): token→expert
-assignments are argsorted by expert id, each assignment gets a within-expert
-rank, and tokens scatter into a static (E, C, d) buffer (overflow dropped,
-counts returned for logging). Expert weights are sharded over the ``model``
-axis (EP); the dispatch scatter and combine gather partition under pjit
-without an all-to-all on the critical path — DESIGN.md §6.
+The expert layer routes every token over all ``n_experts`` and computes
+the part of the result that its held experts (``cfg.expert_first ..``,
+``cfg.n_held`` of them) give: the chip's share under expert parallelism,
+whose absent experts' part is left out. No token is ever dropped: every
+held expert runs over every token of the pass, and a token's combine
+weight for an expert it did not pick is 0. At serving widths (a few
+tokens a row a pass) reading each held expert's weights once is the cost
+whatever the routing, so this costs what a capacity of one slot per
+token would, with no sort, gather or scatter; and since each token's
+output depends on that token alone, rows of one batch stay independent.
 """
 from __future__ import annotations
 
@@ -34,107 +39,75 @@ def mlp(rt: Runtime, p: dict, x: jax.Array, path: str = "ffn") -> jax.Array:
 # Mixture of Experts
 # ---------------------------------------------------------------------------
 
-def moe_capacity(n_tokens: int, n_experts: int, topk: int,
-                 factor: float = 1.25) -> int:
-    """Static per-expert slot count, padded to a multiple of 8."""
-    c = int(n_tokens * topk / n_experts * factor) + 1
-    return max(8, -(-c // 8) * 8)
-
-
-def _batched_expert_mlp(rt: Runtime, p: dict, xs: jax.Array) -> jax.Array:
-    """xs (E, C, d) through per-expert FFN weights (E, d, ff)/(E, ff, d)."""
-    def one(pw, x):
-        return mlp(rt, pw, x, "moe.expert")
-    return jax.vmap(one)(p, xs)
+def route(rt: Runtime, p: dict, xt: jax.Array
+          ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Router over all ``n_experts``, in float32 (router weights stay full
+    precision): (probs (T,E), top_p (T,k), top_e (T,k)). ``top_p`` holds
+    the top-k probabilities, renormalised over the k when
+    ``norm_topk_prob``."""
+    cfg = rt.cfg
+    rlogits = jnp.dot(xt.astype(jnp.float32),
+                      p["router"]["w"].astype(jnp.float32))      # (T,E)
+    rprobs = jax.nn.softmax(rlogits, axis=-1)
+    top_p, top_e = jax.lax.top_k(rprobs, cfg.n_experts_per_tok)  # (T,k)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.maximum(jnp.sum(top_p, -1, keepdims=True), 1e-9)
+    return rprobs, top_p, top_e
 
 
 def moe(rt: Runtime, p: dict, x: jax.Array) -> tuple[jax.Array, dict]:
-    """Top-k MoE over x (B,S,d). Returns (out, aux) — aux has router stats.
+    """Dropless top-k MoE over x (B,S,d), the held experts' part (module
+    doc). Returns (out, aux).
 
-    p = {"router": {"w"}, "experts": {w_gate/w_up/w_down stacked (E,…)},
-         optional "shared": dense-FFN params (deepseek shared expert)}
+    p = {"router": {"w"} (d, n_experts), "experts": {w_gate/w_up/w_down
+    stacked (n_held,…)}, optional "shared": dense-FFN params (deepseek's
+    shared experts, which every chip computes)}. ``aux``: ``balance_loss``
+    (switch-style, over all experts), ``expert_tokens`` (top-k assignments
+    that land on a held expert) and ``dropped`` (assignments to a held
+    expert left uncomputed: none, by construction).
     """
     cfg = rt.cfg
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.n_experts_per_tok
     t = b * s
-    c = moe_capacity(t, e, k, rt.moe_capacity_factor)
     xt = x.reshape(t, d)
-
-    # --- routing (router weights stay full precision) ---
-    rlogits = jnp.dot(xt.astype(jnp.float32),
-                      p["router"]["w"].astype(jnp.float32))      # (T,E)
-    rprobs = jax.nn.softmax(rlogits, axis=-1)
-    top_p, top_e = jax.lax.top_k(rprobs, k)                       # (T,k)
-    top_p = top_p / jnp.maximum(jnp.sum(top_p, -1, keepdims=True), 1e-9)
-
-    # --- sort-based, scatter-free dispatch (gathers partition cleanly
-    # under SPMD; scatters into an E-sharded buffer force the partitioner
-    # to replicate updates — measured in §Perf iteration B2/C1) ---
-    flat_e = top_e.reshape(-1)                                    # (T*k,)
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    # rank within expert: position - start offset of that expert id
-    starts = jnp.searchsorted(sorted_e, jnp.arange(e), side="left")
-    rank = jnp.arange(t * k) - starts[sorted_e]
-    ok = rank < c
-    src_tok = order // k                                          # (T*k,)
-
-    # slot (e_i, r) is fed by sorted assignment j = starts[e_i] + r when
-    # r < count[e_i] — a pure gather from the sorted order
-    counts = jnp.diff(jnp.append(starts, t * k))
-    slot_r = jnp.tile(jnp.arange(c), e)                           # (E*C,)
-    slot_e = jnp.repeat(jnp.arange(e), c)
-    j_for_slot = starts[slot_e] + slot_r
-    slot_valid = slot_r < counts[slot_e]
-    src_for_slot = jnp.where(slot_valid,
-                             src_tok[jnp.clip(j_for_slot, 0, t * k - 1)], t)
-    xt_pad = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)])
-    expert_in = xt_pad[src_for_slot].reshape(e, c, d)
-    expert_in = rt.shard_act(expert_in, ("experts", None, None))
-    expert_out = _batched_expert_mlp(rt, p["experts"], expert_in)
-    expert_out = rt.shard_act(expert_out, ("experts", None, None))
-
-    # --- combine: invert the sort, gather each token's k slots ---
-    inv_order = jnp.argsort(order)                 # assignment -> sorted pos
-    slot_by_assign = jnp.where(ok, sorted_e * c + rank, e * c)[inv_order]
-    flat_out = jnp.concatenate(
-        [expert_out.reshape(e * c, d), jnp.zeros((1, d), expert_out.dtype)])
-    gathered = flat_out[slot_by_assign].reshape(t, k, d)
-    w = (top_p * ok[inv_order].reshape(t, k)).astype(jnp.float32)
-    out = jnp.sum(gathered.astype(jnp.float32) * w[..., None], axis=1)
-    out = out.astype(x.dtype)
-
-    if "shared" in p:
-        out = out + mlp(rt, p["shared"], xt, "moe.shared")
-
-    aux = {
-        "dropped": jnp.sum(~ok),
-        "load": jnp.bincount(flat_e, length=e),
-        # switch-style load-balance loss term
-        "balance_loss": jnp.sum(
-            jnp.mean(rprobs, axis=0)
-            * jnp.bincount(flat_e, length=e) / jnp.maximum(t * k, 1)) * e,
-    }
+    with jax.named_scope("cass.moe"):
+        rprobs, top_p, top_e = route(rt, p, xt)
+        # each held expert's combine weight per token, 0 where not picked
+        held = cfg.expert_first + jnp.arange(cfg.n_held)
+        hit = top_e[:, :, None] == held[None, None, :]            # (T,k,H)
+        w = jnp.sum(jnp.where(hit, top_p[..., None], 0.0), axis=1)
+        ys = jax.vmap(lambda pw: mlp(rt, pw, xt, "moe.expert"))(
+            p["experts"])                                         # (H,T,d)
+        ys = rt.shard_act(ys, ("experts", None, None))
+        out = jnp.sum(ys.astype(jnp.float32) * w.T[..., None], axis=0)
+        out = out.astype(x.dtype)
+        if "shared" in p:
+            out = out + mlp(rt, p["shared"], xt, "moe.shared")
+        load = jnp.bincount(top_e.reshape(-1), length=e)
+        aux = {
+            "dropped": jnp.int32(0),
+            "expert_tokens": jnp.sum(hit, dtype=jnp.int32),
+            "balance_loss": jnp.sum(jnp.mean(rprobs, axis=0) * load
+                                    / jnp.maximum(t * k, 1)) * e,
+        }
     return out.reshape(b, s, d), aux
 
 
 def moe_reference(rt: Runtime, p: dict, x: jax.Array) -> jax.Array:
-    """Oracle: loop over experts densely (tests only — E× compute)."""
+    """Oracle: a loop over the held experts, each weighted by the
+    probability of the tokens that picked it (tests only)."""
     cfg = rt.cfg
     b, s, d = x.shape
     t = b * s
     xt = x.reshape(t, d)
-    rlogits = jnp.dot(xt.astype(jnp.float32),
-                      p["router"]["w"].astype(jnp.float32))
-    rprobs = jax.nn.softmax(rlogits, axis=-1)
-    top_p, top_e = jax.lax.top_k(rprobs, cfg.n_experts_per_tok)
-    top_p = top_p / jnp.maximum(jnp.sum(top_p, -1, keepdims=True), 1e-9)
+    _, top_p, top_e = route(rt, p, xt)
     out = jnp.zeros((t, d), jnp.float32)
-    for ei in range(cfg.n_experts):
-        pw = jax.tree.map(lambda a: a[ei], p["experts"])
+    for i in range(cfg.n_held):
+        pw = jax.tree.map(lambda a: a[i], p["experts"])
         y = mlp(rt, pw, xt).astype(jnp.float32)
-        wgt = jnp.sum(jnp.where(top_e == ei, top_p, 0.0), axis=-1)
+        wgt = jnp.sum(jnp.where(top_e == cfg.expert_first + i, top_p, 0.0),
+                      axis=-1)
         out = out + y * wgt[:, None]
     out = out.astype(x.dtype)
     if "shared" in p:

@@ -17,6 +17,7 @@ backend the 512-device dry-run lowers.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable
 
 import jax
@@ -51,7 +52,6 @@ class Runtime:
     remat: bool = False                 # checkpoint each scanned layer block
     remat_policy: str = "full"          # full | dots (save matmul outputs)
     unroll: bool = False                # python-loop layer groups (roofline)
-    moe_capacity_factor: float = 1.25   # per-expert slots vs perfect balance
 
     def shard_act(self, x: jax.Array, spec: tuple) -> jax.Array:
         if self.shard is None:
@@ -139,19 +139,46 @@ def norm(rt: Runtime, p: dict, x: jax.Array) -> jax.Array:
 # Rotary position embeddings
 # ---------------------------------------------------------------------------
 
-def rope_freqs(dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def rope_freqs(dim: int, theta: float, yarn=None) -> jax.Array:
+    """Inverse frequencies (D/2,). With ``yarn`` (``configs.YarnScaling``)
+    they are YaRN's, as ``modeling_deepseek.py`` computes them: the base
+    frequency f_e = theta^(-2i/D) where a dimension turns often within the
+    original context, f_e / factor where it turns rarely, and a linear
+    ramp between the correction dimensions low and high of beta_fast and
+    beta_slow."""
+    power = theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if yarn is None:
+        return 1.0 / power
+
+    def correction_dim(rotations):
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = 1.0 - ramp                # 1: keep f_e, 0: f_e / factor
+    return (1.0 / (yarn.factor * power) * (1.0 - extra)
+            + 1.0 / power * extra)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x (B,S,H,D) with positions (B,S) or (S,). Half-split convention."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
+    """x (B,S,H,D) with positions (B,S) or (S,). Half-split convention.
+    ``yarn`` scales the frequencies and cos/sin (``rope_freqs``)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                         # (D/2,)
+    freqs = rope_freqs(d, theta, yarn)                   # (D/2,)
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs   # (B,S,D/2)
     cos = jnp.cos(ang)[..., None, :]                     # (B,S,1,D/2)
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.cos_sin_factor, sin * yarn.cos_sin_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -72,12 +72,20 @@ def _init_gqa(key, cfg: ModelConfig, dtype):
 
 
 def _init_mla(key, cfg: ModelConfig, dtype):
+    """MLA weights. Without q-LoRA (``q_lora_rank`` 0) the query is one
+    projection ``wq`` drawn from the key ``q_a`` would have had."""
     ks = jax.random.split(key, 5)
     qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+    if cfg.q_lora_rank:
+        q = {"q_a": _dense_init(ks[0], cfg.d_model, cfg.q_lora_rank, dtype),
+             "q_a_norm": {"scale": jnp.ones((cfg.q_lora_rank,),
+                                            jnp.float32)},
+             "q_b": _dense_init(ks[1], cfg.q_lora_rank, cfg.n_heads * qk,
+                                dtype)}
+    else:
+        q = {"wq": _dense_init(ks[0], cfg.d_model, cfg.n_heads * qk, dtype)}
     return {
-        "q_a": _dense_init(ks[0], cfg.d_model, cfg.q_lora_rank, dtype),
-        "q_a_norm": {"scale": jnp.ones((cfg.q_lora_rank,), jnp.float32)},
-        "q_b": _dense_init(ks[1], cfg.q_lora_rank, cfg.n_heads * qk, dtype),
+        **q,
         "kv_a": _dense_init(ks[2], cfg.d_model,
                             cfg.kv_lora_rank + cfg.qk_rope_dim, dtype),
         "kv_a_norm": {"scale": jnp.ones((cfg.kv_lora_rank,), jnp.float32)},
@@ -123,16 +131,20 @@ def _init_mlp(key, cfg: ModelConfig, d_ff, dtype):
 
 
 def _init_moe(key, cfg: ModelConfig, dtype):
-    ks = jax.random.split(key, 2 + cfg.n_experts)
-    experts = [_init_mlp(ks[2 + e], cfg, cfg.expert_ff, dtype)
-               for e in range(cfg.n_experts)]
+    """Router over all ``n_experts``; weights of the held experts only
+    (``expert_first ..``), expert ``e`` drawn from ``fold_in(k, e)`` so it
+    is the same tensor whichever share holds it."""
+    k_router, k_shared, k_experts = jax.random.split(key, 3)
+    ids = cfg.expert_first + jnp.arange(cfg.n_held)
     p = {
         "router": {"w": (jax.random.normal(
-            ks[0], (cfg.d_model, cfg.n_experts), jnp.float32) * 0.02)},
-        "experts": jax.tree.map(lambda *xs: jnp.stack(xs), *experts),
+            k_router, (cfg.d_model, cfg.n_experts), jnp.float32) * 0.02)},
+        "experts": jax.vmap(lambda e: _init_mlp(
+            jax.random.fold_in(k_experts, e), cfg, cfg.expert_ff, dtype))(
+                ids),
     }
     if cfg.n_shared_experts:
-        p["shared"] = _init_mlp(ks[1], cfg, cfg.expert_ff
+        p["shared"] = _init_mlp(k_shared, cfg, cfg.expert_ff
                                 * cfg.n_shared_experts, dtype)
     return p
 
@@ -307,9 +319,8 @@ def _attn_entry_paged(rt: Runtime, bp: dict, x, positions, *, centry,
     gathered. Packed GQA caches feed the draft pass their *spec leaves*
     directly — the Cassandra decode runs inside the kernel, so draft KV
     never exists densely in HBM. The verify pass (target view) and all
-    MLA paths read a dense pool (``ventry``/``read_store``) through the
-    plain kernel variant — MLA caches can't pack (the rope dim is
-    narrower than the 32-lane bit-pack).
+    MLA paths, packed latent pools included, read a dense pool
+    (``ventry``/``read_store``) through the plain kernel variant.
     """
     cfg = rt.cfg
     cass = rt.cass
@@ -385,20 +396,24 @@ def _block(rt: Runtime, bp: dict, entry: str, x, positions, *, mode,
                                 cross_kv=(ck, cv))
         x = x + xo
 
+    aux = _aux0()
     if entry[1] == "m":
         h = L.norm(rt, bp["norm2"], x)
         x = x + F.mlp(rt, bp["ffn"], h)
-        aux = {"balance_loss": jnp.float32(0.0), "dropped": jnp.int32(0)}
     elif entry[1] == "M":
         h = L.norm(rt, bp["norm2"], x)
-        out, aux = F.moe(rt, bp["moe"], h)
+        out, maux = F.moe(rt, bp["moe"], h)
         x = x + out
-        aux = {"balance_loss": aux["balance_loss"].astype(jnp.float32),
-               "dropped": aux["dropped"].astype(jnp.int32)}
-    else:
-        aux = {"balance_loss": jnp.float32(0.0), "dropped": jnp.int32(0)}
+        aux = {k: maux[k].astype(v.dtype) for k, v in aux.items()}
     x = rt.shard_act(x, ("batch", None, None))
     return x, upd, aux
+
+
+def _aux0() -> dict:
+    """A pass's expert-layer statistics, summed over its layers
+    (``ffn.moe``'s ``aux``); zero where a layer has no experts."""
+    return {"balance_loss": jnp.float32(0.0), "dropped": jnp.int32(0),
+            "expert_tokens": jnp.int32(0)}
 
 
 # ---------------------------------------------------------------------------
@@ -411,7 +426,7 @@ def _scan_groups(rt: Runtime, groups_params, entries_per_group, x, positions,
                  s_max=0, enc_out=None, valid_len=None, view_groups=None,
                  table=None):
     """Run all layer groups; scan over repeats within each group."""
-    aux0 = {"balance_loss": jnp.float32(0.0), "dropped": jnp.int32(0)}
+    aux0 = _aux0()
     updates_groups = []
     for gi, entries in enumerate(entries_per_group):
         gp = groups_params[gi]
@@ -454,9 +469,7 @@ def _scan_groups(rt: Runtime, groups_params, entries_per_group, x, positions,
                     ventry=ven, table=table)
                 if upd:
                     g_upd[ekey] = upd
-                aux = {"balance_loss": aux["balance_loss"]
-                       + baux["balance_loss"],
-                       "dropped": aux["dropped"] + baux["dropped"]}
+                aux = jax.tree.map(jnp.add, aux, baux)
             return (xx, aux), g_upd
 
         if rt.remat:
@@ -709,11 +722,12 @@ def kv_pool_decodes(rt: Runtime, cache: dict, drafts: bool = True) -> int:
 def forward_decode(rt: Runtime, params: Params, tokens: jax.Array,
                    cache: dict, scratch: dict | None = None,
                    scratch_len=None, cache_view: list | None = None):
-    """q new tokens against the cache. Returns (logits, updates).
+    """q new tokens against the cache. Returns (logits, updates, aux).
 
     ``updates`` mirrors the cache groups: per attn entry the new tokens'
     K/V (B,q,…), per ssm entry {"h_all", "conv_win", "conv", "h"} for
-    commit/rollback by the serving engine. ``cache_view`` optionally
+    commit/rollback by the serving engine. ``aux`` sums the expert
+    layers' statistics over the pass (``ffn.moe``). ``cache_view`` optionally
     provides dense stores decoded beforehand (one view of
     ``materialize_cache_view``); attention entries then read them and
     decode nothing.
@@ -725,8 +739,8 @@ def forward_decode(rt: Runtime, params: Params, tokens: jax.Array,
     beside a γ+1 verify run at L_b beside an idle row): the fused
     mixed-role serving step (``engine.unified_step``) is just this pass
     with per-row token selection, and a row's outputs are bit-identical
-    whatever the other rows carry (MoE capacity overflow, which couples
-    rows by design, excepted).
+    whatever the other rows carry (the expert layer is dropless, so it
+    couples no rows either).
     """
     cfg = rt.cfg
     length = cache["length"]
@@ -752,7 +766,7 @@ def forward_decode(rt: Runtime, params: Params, tokens: jax.Array,
         book=book, s_max=s_max, view_groups=cache_view,
         table=cache.get("block_table"))
     x = L.norm(rt, params["final_norm"], x)
-    return L.unembed(rt, params, x), upd
+    return L.unembed(rt, params, x), upd, aux
 
 
 def _cache_s_max(cfg: ModelConfig, cache: dict) -> int:

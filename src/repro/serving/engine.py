@@ -37,7 +37,7 @@ derived from it bit-exactly. They name their phases with
 and acceptance) and ``cass.commit`` (the KV encode and append) — and
 ``kvcache`` adds ``cass.kv_decode``, ``cass.kv_derive`` and
 ``cass.kv_gather`` wherever the packed KV is decoded, derived or paged
-in. The scopes change HLO metadata only: a device trace can put each op
+in, and ``ffn.moe`` ``cass.moe`` around each expert layer. The scopes change HLO metadata only: a device trace can put each op
 down to a phase (``telemetry.hlo_op_scopes``), and the computation is
 the same.
 """
@@ -350,8 +350,8 @@ def _run_drafts(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
             [cur_tokens, jnp.zeros((cur_tokens.shape[0], gamma),
                                    cur_tokens.dtype)], axis=1)
         for i in range(gamma):
-            logits, _ = M.forward_decode(rt_d, params, toks, cache,
-                                         cache_view=draft_view)
+            logits, _, _ = M.forward_decode(rt_d, params, toks, cache,
+                                            cache_view=draft_view)
             lg = logits[:, i]
             nxt, key = sample(lg, key)
             draft_tokens.append(nxt)
@@ -361,10 +361,10 @@ def _run_drafts(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
         scratch = make_scratch(cfg, cache, gamma)
         tok = cur_tokens
         for i in range(gamma):
-            logits, upd = M.forward_decode(rt_d, params, tok, cache,
-                                           scratch=scratch,
-                                           scratch_len=jnp.int32(i),
-                                           cache_view=draft_view)
+            logits, upd, _ = M.forward_decode(rt_d, params, tok, cache,
+                                              scratch=scratch,
+                                              scratch_len=jnp.int32(i),
+                                              cache_view=draft_view)
             scratch = _scratch_write(scratch, upd, i)
             lg = logits[:, -1]
             nxt, key = sample(lg, key)
@@ -406,8 +406,8 @@ def spec_decode_step(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
     # batched verification over [cur ++ drafts]
     ver_tokens = jnp.concatenate([cur_tokens, draft_tokens], axis=1)
     with jax.named_scope("cass.verify"):
-        t_logits, t_upd = M.forward_decode(rt_t, params, ver_tokens, cache,
-                                           cache_view=target_view)
+        t_logits, t_upd, _ = M.forward_decode(rt_t, params, ver_tokens,
+                                              cache, cache_view=target_view)
         res = _accept(draft_tokens, draft_logits, t_logits, key, ecfg)
     with jax.named_scope("cass.commit"):
         cache = commit(rt, cache, t_upd, res.n_accepted)
@@ -417,7 +417,7 @@ def spec_decode_step(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
 def unified_step(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
                  chunk_tokens: jax.Array, prefill_valid: jax.Array,
                  decode_mask: jax.Array, key: jax.Array, ecfg: EngineConfig
-                 ) -> tuple[SP.AcceptResult, jax.Array, dict]:
+                 ) -> tuple[SP.AcceptResult, jax.Array, dict, dict]:
     """One fused serving cycle over a mixed-role batch.
 
     Per-row roles, all traced operands (any role mix hits ONE compile):
@@ -442,14 +442,11 @@ def unified_step(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
     (drafts write scratch only). One target pass then serves as verify
     for decode rows and as the chunk-prefill forward for prefill rows.
 
-    The per-row bitwise guarantee holds for row-independent architectures
-    (every dense op here is per-row). MoE capacity overflow is the one
-    batch-coupled op: all rows' tokens compete for shared expert slots,
-    so on MoE models what rides alongside a row can flip its dropped
-    tokens — true of ANY masked batched step (the alternating
-    scheduler's frozen riders included, since PR 1), not specific to
-    mixed roles. Keep ``moe_capacity_factor`` high enough that overflow
-    never fires if bitwise serving parity on MoE archs matters.
+    Every op here is per-row, the expert layer included (it is
+    dropless), so a row's outputs are bit-identical whatever rides
+    beside it. The fourth output is the target pass's expert-layer
+    statistics (``model.forward_decode``'s ``aux``: ``expert_tokens``,
+    ``dropped``), device scalars the scheduler reads at harvest.
 
     **Deferred-harvest contract** (the scheduler's dispatch/harvest
     pipeline leans on it): of this step's jitted outputs only the cache
@@ -474,8 +471,8 @@ def unified_step(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
     ver_tokens = jnp.concatenate([cur_tokens, draft_tokens], axis=1)
     tokens = jnp.where(is_prefill[:, None], chunk_tokens, ver_tokens)
     with jax.named_scope("cass.verify"):
-        t_logits, t_upd = M.forward_decode(rt_t, params, tokens, cache,
-                                           cache_view=target_view)
+        t_logits, t_upd, aux = M.forward_decode(rt_t, params, tokens, cache,
+                                                cache_view=target_view)
         res = _accept(draft_tokens, draft_logits, t_logits, key, ecfg)
     # role-masked commit width: prefill rows commit their chunk's real
     # tokens, decode rows their accepted run + bonus, idle rows one
@@ -486,12 +483,17 @@ def unified_step(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
     with jax.named_scope("cass.commit"):
         cache = commit(rt, cache, t_upd, n)
         last = jnp.take_along_axis(t_logits, n[:, None, None], axis=1)[:, 0]
-    return res, last, cache
+    return res, last, cache, _pass_stats(aux)
+
+
+def _pass_stats(aux: dict) -> dict:
+    """What the scheduler counts of a target pass's expert layers."""
+    return {"expert_tokens": aux["expert_tokens"], "dropped": aux["dropped"]}
 
 
 def chunk_prefill_step(rt: Runtime, params, cache: dict,
                        tokens: jax.Array, valid: jax.Array
-                       ) -> tuple[jax.Array, dict]:
+                       ) -> tuple[jax.Array, dict, dict]:
     """One batched prefill chunk: q=C prompt tokens per row, appended at
     each row's current ``length``.
 
@@ -503,14 +505,15 @@ def chunk_prefill_step(rt: Runtime, params, cache: dict,
     returns the logits at each row's last real token (the first generated
     token once its prompt is exhausted). Rows with valid=0 commit one
     garbage token into their masked stale region (paged: the trash block)
-    — the caller freezes their length and recurrent state.
+    — the caller freezes their length and recurrent state. The third
+    output is the pass's expert-layer statistics, as ``unified_step``'s.
     """
     rt_t = dataclasses.replace(rt, view="target" if rt.cass else "plain")
-    logits, upd = M.forward_decode(rt_t, params, tokens, cache)
+    logits, upd, aux = M.forward_decode(rt_t, params, tokens, cache)
     n = jnp.maximum(valid.astype(jnp.int32), 1) - 1
     cache = commit(rt, cache, upd, n)
     last = jnp.take_along_axis(logits, n[:, None, None], axis=1)[:, 0]
-    return last, cache
+    return last, cache, _pass_stats(aux)
 
 
 def autoregressive_step(rt: Runtime, params, cache: dict,
@@ -519,7 +522,7 @@ def autoregressive_step(rt: Runtime, params, cache: dict,
                         ) -> tuple[jax.Array, dict]:
     """bf16-baseline decode: one token per full-model read."""
     rt_t = dataclasses.replace(rt, view="target" if rt.cass else "plain")
-    logits, upd = M.forward_decode(rt_t, params, cur_tokens, cache)
+    logits, upd, _ = M.forward_decode(rt_t, params, cur_tokens, cache)
     lg = logits[:, -1]
     if greedy:
         nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)

@@ -118,7 +118,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, layer_groups
 from repro.core.format import CassandraConfig
 from repro.models import model as M
 from repro.models.layers import Runtime
@@ -240,6 +240,7 @@ class PendingCycle:
     clock: float                # scheduler clock at dispatch
     t0: float                   # perf_counter at dispatch start
     t_dispatch: float           # perf_counter when dispatch returned
+    stats: dict                 # target pass's expert-layer device scalars
 
 
 def _freeze_rows(cache0: dict, cache: dict, active: jax.Array) -> dict:
@@ -285,19 +286,20 @@ def _masked_auto(rt: Runtime, params, cache: dict, cur: jax.Array,
 
 def _masked_chunk(rt: Runtime, params, cache: dict, tokens: jax.Array,
                   valid: jax.Array):
-    last, new_cache = chunk_prefill_step(rt, params, cache, tokens, valid)
-    return last, _freeze_rows(cache, new_cache, valid > 0)
+    last, new_cache, stats = chunk_prefill_step(rt, params, cache, tokens,
+                                                valid)
+    return last, _freeze_rows(cache, new_cache, valid > 0), stats
 
 
 def _masked_unified(rt: Runtime, params, cache: dict, cur: jax.Array,
                     chunk_tokens: jax.Array, prefill_valid: jax.Array,
                     decode_mask: jax.Array, key: jax.Array,
                     ecfg: EngineConfig):
-    res, last, new_cache = unified_step(rt, params, cache, cur,
-                                        chunk_tokens, prefill_valid,
-                                        decode_mask, key, ecfg)
+    res, last, new_cache, stats = unified_step(rt, params, cache, cur,
+                                               chunk_tokens, prefill_valid,
+                                               decode_mask, key, ecfg)
     active = decode_mask | (prefill_valid > 0)
-    return res, last, _freeze_rows(cache, new_cache, active)
+    return res, last, _freeze_rows(cache, new_cache, active), stats
 
 
 class Scheduler:
@@ -391,6 +393,10 @@ class Scheduler:
         self.rt = Runtime(cfg=cfg, cass=cass,
                           view="target" if cass else "plain",
                           attn_kernel=attn_kernel, **rt_extra)
+        # expert layers in a pass: their statistics are fetched at harvest
+        self.expert_layers = sum(g.repeats * sum(e[1] == "M"
+                                                 for e in g.entries)
+                                 for g in layer_groups(cfg))
         packed = cass is not None
         if paged:
             self.cache = KC.init_paged_cache(
@@ -473,7 +479,8 @@ class Scheduler:
             "prefix_queries", "prefix_hits", "prefix_matched_tokens",
             "prefix_blocks_aliased", "cow_copies", "preemptions",
             "swap_resumes", "swap_out_blocks", "swap_in_blocks",
-            "swap_matched_blocks")
+            "swap_matched_blocks", "moe_expert_tokens", "moe_expert_slots",
+            "moe_dropped")
         for peak in ("peak_prefill_tokens_per_cycle",
                      "peak_resident_tokens", "peak_reserved_tokens",
                      "peak_swapped_tokens"):
@@ -1299,18 +1306,23 @@ class Scheduler:
                 self._grow_blocks(r, r.pos + v)
         self._push_host_state()
         t0 = time.perf_counter()
-        last, self.cache = self._chunk(self.params, self.cache,
-                                       jnp.asarray(tokens),
-                                       jnp.asarray(valid))
+        last, self.cache, stats = self._chunk(self.params, self.cache,
+                                              jnp.asarray(tokens),
+                                              jnp.asarray(valid))
         return PendingCycle(kind="chunk", plan=None,
                             prefilling=list(prefilling), valid=valid,
                             res=None, last=last, clock=self.clock,
-                            t0=t0, t_dispatch=time.perf_counter())
+                            t0=t0, t_dispatch=time.perf_counter(),
+                            stats=stats)
 
     def _harvest_wide(self, p: PendingCycle) -> None:
         """Fold one wide admission cycle's materialized logits into host
         state (row advance, prefix indexing, prefill completion)."""
-        last = jax.device_get(p.last)
+        if self.expert_layers:
+            last, stats = jax.device_get((p.last, p.stats))
+            self._count_experts(stats)
+        else:
+            last = jax.device_get(p.last)
         for r in p.prefilling:
             v = int(p.valid[r.slot])
             r.pos += v
@@ -1474,28 +1486,30 @@ class Scheduler:
         self.key, sub = jax.random.split(self.key)
         chunk_dev, valid_dev = self._take_prefetch(plan)
         t0 = time.perf_counter()
-        res, last, self.cache = self._unified(
+        res, last, self.cache, stats = self._unified(
             self.params, self.cache, cur, chunk_dev, valid_dev,
             jnp.asarray(plan.decode_mask), sub)
         pending = PendingCycle(kind="unified", plan=plan, prefilling=[],
                                valid=None, res=res, last=last,
                                clock=self.clock, t0=t0,
-                               t_dispatch=time.perf_counter())
+                               t_dispatch=time.perf_counter(), stats=stats)
         self._prefetch_next_chunk(plan)
         return pending
 
-    def _harvest_unified(self, plan: CyclePlan, res, last,
-                         cycle: float) -> None:
+    def _harvest_unified(self, p: PendingCycle, cycle: float) -> None:
         """Fold one fused cycle's materialized results into host state.
         ``cycle`` is the harvested cycle's dispatch-time clock (== the
         live clock on the synchronous path). A row that retired between
         the cycle's dispatch and its harvest (pipelined free-run: the
         retire decision arrived one cycle late) is a *zombie* — its
         extra cycle's results are discarded here, never delivered, and
-        it contributes nothing to acceptance accounting."""
+        it contributes nothing to acceptance accounting. The pass's
+        expert-layer statistics ride in the cycle's one batched fetch."""
+        plan, res = p.plan, p.res
+        stats = p.stats if self.expert_layers else None
         # harvest prefill rows
         if plan.prefilling:
-            last = jax.device_get(last)
+            last, stats = jax.device_get((p.last, stats))
             for r in plan.prefilling:
                 v = int(plan.prefill_valid[r.slot])
                 r.pos += v
@@ -1519,8 +1533,9 @@ class Scheduler:
             # are discarded, the rollback the late-retire test pins
             self.metrics.inc("zombie_rows", len(plan.decoding) - len(live))
         if live:
-            tokens, valid, n, nxt = jax.device_get(
-                (res.tokens, res.valid, res.n_accepted, res.next_token))
+            tokens, valid, n, nxt, stats = jax.device_get(
+                (res.tokens, res.valid, res.n_accepted, res.next_token,
+                 stats))
             for r in live:
                 self._harvest_decode_row(r, tokens, valid, n, nxt,
                                          cycle=cycle)
@@ -1528,6 +1543,20 @@ class Scheduler:
             lmask[[r.slot for r in live]] = True
             self.metrics.inc("accepted", int(n[lmask].sum()))
             self.metrics.inc("drafted", self.ecfg.gamma * len(live))
+        if self.expert_layers and (plan.prefilling or live):
+            self._count_experts(stats)
+
+    def _count_experts(self, stats: dict) -> None:
+        """Fold one target pass's fetched expert-layer statistics into
+        the counters: ``moe_expert_tokens`` (top-k assignments that land
+        on a held expert, every token of the pass counted),
+        ``moe_expert_slots`` (held experts × expert layers: one read of
+        each held expert's weights) and ``moe_dropped`` (always 0: the
+        layer is dropless)."""
+        self.metrics.inc("moe_expert_tokens", int(stats["expert_tokens"]))
+        self.metrics.inc("moe_expert_slots",
+                         self.cfg.n_held * self.expert_layers)
+        self.metrics.inc("moe_dropped", int(stats["dropped"]))
 
     def _fused_step(self) -> bool:
         """Execute one planned mixed-role cycle via ``unified_step`` —
@@ -1558,7 +1587,7 @@ class Scheduler:
             jax.block_until_ready(p.res.tokens)
         self._stamp_wall("unified", p.t0)
         with TM.span("sched.harvest"):
-            self._harvest_unified(plan, p.res, p.last, self.clock)
+            self._harvest_unified(p, self.clock)
             self._track_residency()
         self.metrics.inc("cycles")
         self.clock += 1.0
@@ -1625,7 +1654,7 @@ class Scheduler:
         with TM.span("sched.harvest"):
             # speclint: disable=sync-truthy(kind is a host string field of the pending record)
             if p.kind == "unified":
-                self._harvest_unified(p.plan, p.res, p.last, p.clock)
+                self._harvest_unified(p, p.clock)
             else:
                 self._harvest_wide(p)
             self._finalize_inflight()

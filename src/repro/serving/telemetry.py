@@ -41,10 +41,10 @@ layer:
   its phases with ``jax.named_scope`` (``cass.view``, ``cass.draft``,
   ``cass.verify``, ``cass.commit``, and ``cass.kv_decode`` /
   ``cass.kv_derive`` / ``cass.kv_gather`` wherever the packed KV is
-  decoded, derived or paged in); :func:`hlo_op_scopes` maps each
-  instruction of a compiled program's text to the scopes it ran under,
-  which is how the device trace's bare HLO op names are put down to a
-  phase.
+  decoded, derived or paged in, ``cass.moe`` around each expert layer);
+  :func:`hlo_op_scopes` maps each instruction of a compiled program's
+  text to the scopes it ran under, which is how the device trace's bare
+  HLO op names are put down to a phase.
 
 The zero-sync guarantee is machine-checked: ``tools/speclint`` flags any
 telemetry sink call (``emit``/``inc``/``gauge``/``observe``/…) whose
