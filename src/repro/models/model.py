@@ -231,11 +231,11 @@ def _attn_entry(rt: Runtime, bp: dict, x, positions, *, causal, centry,
                 table=None):
     """Attention sub-block in any mode. Returns (out, upd).
 
-    ``ventry`` — optional dense view of the packed cache entry, decoded
-    once per speculative cycle by ``materialize_cache_view``: the γ draft
-    passes read its draft view and, for Cassandra-1, the verify pass its
-    target view, so neither decodes the pool here. Without it the entry
-    is decoded in place (``read_store``).
+    ``ventry`` — optional dense view of the packed cache entry, kept in
+    the cache (``materialize_cache_view``): the γ draft passes read its
+    draft view and the verify and prefill passes its target view, so none
+    decodes the pool here. Without it the entry is decoded in place
+    (``read_store``).
     ``table`` — paged caches only: (B,MB) block table; the cache entry (or
     its ventry) is a block pool decoded pool-wide, and the per-request
     prefix is assembled by ``kvcache.gather_block_leaf``. ``s_max`` is
@@ -601,36 +601,46 @@ def _commit_prefill(rt: Runtime, cache, updates_groups, s, book):
             "paged caches are filled by chunked prefill "
             "(engine.chunk_prefill_step), not forward_prefill")
     packed = book is not None
+    views = cache.get("view")
     new_dec = []
+    new_view = ({nm: [dict(g) for g in groups]
+                 for nm, groups in views.items()} if views else None)
     new_cross = [] if "cross" in cache else None
     for gi, g_upd in enumerate(updates_groups):
         gcache = cache["dec"][gi]
         gout = dict(gcache)
         gx = dict(cache["cross"][gi]) if new_cross is not None else None
 
-        def commit_entry(centry, upd):
-            out = dict(centry)
-            if "k" in upd:      # gqa
-                for name in ("k", "v"):
-                    enc = (KC.encode_store(cass, upd[name], cfg.hd, book)
+        def commit_entry(centry, upd, ventry):
+            out, vout = dict(centry), None
+            if "k" in upd or "c" in upd:        # gqa | mla
+                if ventry is not None:
+                    vout = {"target": {}, "draft": {}}
+                for name, d in KC.store_dims(cfg).items():
+                    enc = (KC.encode_store(cass, upd[name], d, book)
                            if packed else upd[name])
                     out[name] = KC.append_store(centry[name], enc, 0)
-            elif "c" in upd:    # mla
-                enc_c = (KC.encode_store(cass, upd["c"], cfg.kv_lora_rank,
-                                         book) if packed else upd["c"])
-                enc_r = (KC.encode_store(cass, upd["kr"], cfg.qk_rope_dim,
-                                         book) if packed else upd["kr"])
-                out["c"] = KC.append_store(centry["c"], enc_c, 0)
-                out["kr"] = KC.append_store(centry["kr"], enc_r, 0)
+                    if ventry is not None:
+                        t, dr = KC.store_views(cass, enc, d, book, upd[name])
+                        vout["target"][name] = KC.append_store(
+                            ventry["target"][name], t, 0)
+                        vout["draft"][name] = KC.append_store(
+                            ventry["draft"][name], dr, 0)
             elif "conv" in upd:  # ssm
                 out["conv"] = upd["conv"].astype(centry["conv"].dtype)
                 out["h"] = upd["h"]
-            return out
+            return out, vout
 
         for ekey, upd in g_upd.items():
             core = {k: v for k, v in upd.items() if k not in ("ck", "cv")}
             if core:
-                gout[ekey] = jax.vmap(commit_entry)(gcache[ekey], core)
+                ventry = ({nm: views[nm][gi][ekey] for nm in views}
+                          if views and ekey in views["target"][gi] else None)
+                gout[ekey], vout = jax.vmap(commit_entry)(gcache[ekey], core,
+                                                          ventry)
+                if vout is not None:
+                    for nm in new_view:
+                        new_view[nm][gi][ekey] = vout[nm]
             if "ck" in upd and gx is not None:
                 gx[ekey] = {"ck": upd["ck"].astype(jnp.bfloat16),
                             "cv": upd["cv"].astype(jnp.bfloat16)}
@@ -639,84 +649,42 @@ def _commit_prefill(rt: Runtime, cache, updates_groups, s, book):
             new_cross.append(gx)
     out = dict(cache)
     out["dec"] = new_dec
+    if new_view is not None:
+        out["view"] = new_view
     if new_cross is not None:
         out["cross"] = new_cross
     out["length"] = jnp.full_like(cache["length"], s)
     return out
 
 
-def _kernel_reads_pool(rt: Runtime, cache: dict) -> bool:
-    """The paged-attention kernel path: draft passes decode packed KV
-    inside the kernel and verify decodes per layer, so no dense pool view
-    is built for them."""
-    return rt.attn_kernel != "off" and KC.is_paged(cache)
+def materialize_cache_view(cache: dict) -> tuple[list | None, list | None]:
+    """The dense views of a packed cache: ``(draft, target)``, each a list
+    over layer groups of dense stores (``forward_decode``'s
+    ``cache_view``), or ``(None, None)`` where the cache keeps none and
+    the passes read it themselves.
 
-
-def materialize_cache_view(rt: Runtime, cache: dict
-                           ) -> tuple[list | None, list | None]:
-    """Dense views of a packed cache for one speculative cycle:
-    ``(draft, target)``, each a list over layer groups of dense stores
-    (``forward_decode``'s ``cache_view``), or None where the passes read
-    the cache themselves.
-
-    One decode of the pool per cycle: for Cassandra-1 each layer's
-    target view is decoded once (``read_store(view="target")``) and the
-    draft view is derived from it elementwise
-    (``kvcache.draft_from_target``, bit-exact); the γ draft passes read
-    the draft view and the verify pass the target view. Cassandra-2's
-    draft is not a function of its container-exact target, so its draft
-    view is decoded apart and verify decodes the target per layer
-    itself (target None). Layers are decoded one at a time (``lax.map``
-    over each group's repeats), so a decode has the shapes and
-    temporaries of one layer's. Plain caches, and the paged-kernel path
-    (``attn_kernel != "off"``), get ``(None, None)``.
-    ``kv_pool_decodes`` counts the pool decodes each case leaves in a
-    cycle.
+    A lookup: the views live in ``cache["view"]`` and are kept current
+    where the packed stores are written (``engine.commit``,
+    ``kvcache.copy_pool_blocks``, ``kvcache.restore_pool_blocks``), so
+    no serving step decodes the pool. The γ draft passes read the draft
+    view, the verify and prefill passes the target view. Plain caches and
+    the paged-kernel path's keep no views.
     """
-    cfg, cass = rt.cfg, rt.cass
-    book = KC.cache_codebook(cache)
-    if book is None or _kernel_reads_pool(rt, cache):
+    view = cache.get("view")
+    if view is None:
         return None, None
-    dims = ({"c": cfg.kv_lora_rank, "kr": cfg.qk_rope_dim} if cfg.mla
-            else {"k": cfg.hd, "v": cfg.hd})
-    shared = cass.variant == 1
-
-    def layer(entries):
-        draft, target = {}, {}
-        for ekey, centry in entries.items():
-            draft[ekey], target[ekey] = {}, {}
-            for nm, d in dims.items():
-                if shared:
-                    t = KC.read_store(cass, centry[nm], d, "target", book)
-                    target[ekey][nm] = t
-                    draft[ekey][nm] = KC.draft_from_target(
-                        cass, centry[nm]["spec"], t, d)
-                else:
-                    draft[ekey][nm] = KC.read_store(cass, centry[nm], d,
-                                                    "draft", book)
-        return draft, target
-
-    drafts, targets = [], []
-    for gi, g in enumerate(layer_groups(cfg)):
-        attn = {f"e{j}": cache["dec"][gi][f"e{j}"]
-                for j, entry in enumerate(g.entries) if entry[0] == "a"}
-        draft, target = jax.lax.map(layer, attn) if attn else ({}, {})
-        drafts.append(draft)
-        targets.append(target)
-    return drafts, (targets if shared else None)
+    return view["draft"], view["target"]
 
 
-def kv_pool_decodes(rt: Runtime, cache: dict, drafts: bool = True) -> int:
+def kv_pool_decodes(cache: dict) -> int:
     """Full-pool decodes of the packed KV in one serving step: 0 for a
-    plain cache; 1 when the step does not draft, when one target decode
-    feeds both views (Cassandra-1) or when the paged kernel decodes the
-    draft passes' KV in-kernel; 2 when the draft view is decoded apart
-    from verify's target (Cassandra-2). See ``materialize_cache_view``."""
-    if KC.cache_codebook(cache) is None:
+    plain cache and for a packed cache that keeps dense views (every pass
+    reads them, see ``materialize_cache_view``); 1 for the paged-kernel
+    path's packed pool, which keeps none: its draft passes decode the KV
+    in-kernel and its verify pass decodes it per layer."""
+    if KC.cache_codebook(cache) is None or KC.has_views(cache):
         return 0
-    if not drafts or rt.cass.variant == 1 or _kernel_reads_pool(rt, cache):
-        return 1
-    return 2
+    return 1
 
 
 def forward_decode(rt: Runtime, params: Params, tokens: jax.Array,
@@ -728,9 +696,8 @@ def forward_decode(rt: Runtime, params: Params, tokens: jax.Array,
     K/V (B,q,…), per ssm entry {"h_all", "conv_win", "conv", "h"} for
     commit/rollback by the serving engine. ``aux`` sums the expert
     layers' statistics over the pass (``ffn.moe``). ``cache_view`` optionally
-    provides dense stores decoded beforehand (one view of
-    ``materialize_cache_view``); attention entries then read them and
-    decode nothing.
+    provides dense stores (one view of ``materialize_cache_view``);
+    attention entries then read them and decode nothing.
 
     Rows are fully independent here — per-row ``length`` offsets the
     positions, the attention prefix mask is per-row, and the slot/paged

@@ -28,18 +28,26 @@ so admission piggybacks on decode instead of stalling it
 ``chunk_prefill_step`` remain the single-role reference paths the
 regression tests compare against.
 
-Both speculative steps decode a packed cache once per cycle, before the
-drafts (``model.materialize_cache_view``): for Cassandra-1 one per-layer
-target decode of the pool feeds the verify pass, and the draft view is
-derived from it bit-exactly. They name their phases with
-``jax.named_scope`` — ``cass.view`` (the cycle's KV views),
+No serving step decodes a packed pool. The cache keeps dense target and
+draft views of it (``kvcache``'s ``cache["view"]``): the draft passes
+read the draft view and the verify and prefill passes the target view
+(``model.materialize_cache_view``, a lookup), and ``commit`` writes the
+new tokens into both beside their packed encoding: for Cassandra-1 the
+target view is the exact bf16 verify computed and the draft view is
+derived from it elementwise, bit-exactly; Cassandra-2's are decoded from
+the new tokens' packed bytes (``kvcache.store_views``). The packed pool
+is read only where blocks leave or enter the device (spill, restore) and
+by the paged-kernel path, which keeps no views.
+
+The speculative steps name their phases with ``jax.named_scope`` —
 ``cass.draft`` (the γ draft passes), ``cass.verify`` (the target pass
-and acceptance) and ``cass.commit`` (the KV encode and append) — and
-``kvcache`` adds ``cass.kv_decode``, ``cass.kv_derive`` and
-``cass.kv_gather`` wherever the packed KV is decoded, derived or paged
-in, and ``ffn.moe`` ``cass.moe`` around each expert layer. The scopes change HLO metadata only: a device trace can put each op
-down to a phase (``telemetry.hlo_op_scopes``), and the computation is
-the same.
+and acceptance) and ``cass.commit`` (the KV encode and append, with
+``cass.kv_view_write`` around the view writes) — and ``kvcache`` adds
+``cass.kv_decode``, ``cass.kv_derive`` and ``cass.kv_gather`` wherever
+the packed KV is decoded, a draft view derived or a pool paged in, and
+``ffn.moe`` ``cass.moe`` around each expert layer. The scopes change HLO
+metadata only: a device trace can put each op down to a phase
+(``telemetry.hlo_op_scopes``), and the computation is the same.
 """
 from __future__ import annotations
 
@@ -267,30 +275,47 @@ def commit(rt: Runtime, cache: dict, updates: list, n: jax.Array) -> dict:
 
     Slot caches append at per-row offsets inside each row's (S_max,)
     region; paged caches scatter the same token runs into the block pool
-    through the (traced) block table."""
+    through the (traced) block table. A cache with dense views gets the
+    same runs' target and draft views through the same append (same
+    table, offsets and trash routing), so they stay what the packed
+    stores decode to."""
     cfg, cass = rt.cfg, rt.cass
     book = KC.cache_codebook(cache)
     packed = book is not None
     length = cache["length"]                          # (B,)
     table = cache.get("block_table")
+    views = cache.get("view")
+    new_view = ({nm: [dict(g) for g in groups]
+                 for nm, groups in views.items()} if views else None)
+
+    def append(store, new):
+        return jax.vmap(lambda c, nw: KC.append_batched(c, nw, length,
+                                                        table))(store, new)
     new_dec = []
     for gi, gupd in enumerate(updates):
         gcache = dict(cache["dec"][gi])
         for ekey, upd in gupd.items():
             centry = dict(gcache[ekey])
             if "k" in upd or "c" in upd:
-                items = (("k", cfg.hd), ("v", cfg.hd)) if "k" in upd else \
-                    (("c", cfg.kv_lora_rank), ("kr", cfg.qk_rope_dim))
-                for nm, d in items:
+                if views:
+                    vt = dict(views["target"][gi][ekey])
+                    vd = dict(views["draft"][gi][ekey])
+                for nm, d in KC.store_dims(cfg).items():
                     new = upd[nm]                     # (R,B,q,…)
+                    enc = new
                     if packed:
-                        new = jax.vmap(
+                        enc = jax.vmap(
                             lambda x, d=d: KC.encode_store(cass, x, d, book)
                         )(new)
-                    centry[nm] = jax.vmap(
-                        lambda c, nw: KC.append_batched(c, nw, length,
-                                                        table)
-                    )(centry[nm], new)
+                    centry[nm] = append(centry[nm], enc)
+                    if views:
+                        with jax.named_scope("cass.kv_view_write"):
+                            t, dr = KC.store_views(cass, enc, d, book, new)
+                            vt[nm] = append(vt[nm], t)
+                            vd[nm] = append(vd[nm], dr)
+                if views:
+                    new_view["target"][gi][ekey] = vt
+                    new_view["draft"][gi][ekey] = vd
             elif "h_all" in upd:
                 # SSM rollback: state after accepting n+1 tokens
                 h_all = upd["h_all"]                  # (R,B,q,di,ns)
@@ -310,6 +335,8 @@ def commit(rt: Runtime, cache: dict, updates: list, n: jax.Array) -> dict:
         new_dec.append(gcache)
     out = dict(cache)
     out["dec"] = new_dec
+    if new_view is not None:
+        out["view"] = new_view
     out["length"] = length + n.astype(length.dtype) + 1
     return out
 
@@ -324,7 +351,7 @@ def _run_drafts(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
     """γ draft steps with ``view="draft"``. Reads the cache, never writes
     it (scratch/cache-view only), so rows whose draft inputs are garbage
     (prefill/idle rows riding through a fused cycle) are harmless.
-    ``draft_view`` is the cycle's dense draft view of a packed cache
+    ``draft_view`` is the dense draft view a packed cache keeps
     (``model.materialize_cache_view``), None where the passes read the
     cache themselves. Returns (draft_tokens (B,γ), per-step draft logits,
     key)."""
@@ -398,8 +425,7 @@ def spec_decode_step(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
     per-row math for decode rows of a mixed-role batch — the regression
     tests in tests/test_scheduler.py hold them bit-identical."""
     rt_t = dataclasses.replace(rt, view="target" if rt.cass else "plain")
-    with jax.named_scope("cass.view"):
-        draft_view, target_view = M.materialize_cache_view(rt, cache)
+    draft_view, target_view = M.materialize_cache_view(cache)
     with jax.named_scope("cass.draft"):
         draft_tokens, draft_logits, key = _run_drafts(
             rt, params, cache, cur_tokens, key, ecfg, draft_view)
@@ -462,8 +488,7 @@ def unified_step(rt: Runtime, params, cache: dict, cur_tokens: jax.Array,
     and the verify still reads exactly the committed prefix.
     """
     rt_t = dataclasses.replace(rt, view="target" if rt.cass else "plain")
-    with jax.named_scope("cass.view"):
-        draft_view, target_view = M.materialize_cache_view(rt, cache)
+    draft_view, target_view = M.materialize_cache_view(cache)
     with jax.named_scope("cass.draft"):
         draft_tokens, draft_logits, key = _run_drafts(
             rt, params, cache, cur_tokens, key, ecfg, draft_view)
@@ -509,7 +534,9 @@ def chunk_prefill_step(rt: Runtime, params, cache: dict,
     output is the pass's expert-layer statistics, as ``unified_step``'s.
     """
     rt_t = dataclasses.replace(rt, view="target" if rt.cass else "plain")
-    logits, upd, aux = M.forward_decode(rt_t, params, tokens, cache)
+    _, target_view = M.materialize_cache_view(cache)
+    logits, upd, aux = M.forward_decode(rt_t, params, tokens, cache,
+                                        cache_view=target_view)
     n = jnp.maximum(valid.astype(jnp.int32), 1) - 1
     cache = commit(rt, cache, upd, n)
     last = jnp.take_along_axis(logits, n[:, None, None], axis=1)[:, 0]
@@ -522,7 +549,9 @@ def autoregressive_step(rt: Runtime, params, cache: dict,
                         ) -> tuple[jax.Array, dict]:
     """bf16-baseline decode: one token per full-model read."""
     rt_t = dataclasses.replace(rt, view="target" if rt.cass else "plain")
-    logits, upd, _ = M.forward_decode(rt_t, params, cur_tokens, cache)
+    _, target_view = M.materialize_cache_view(cache)
+    logits, upd, _ = M.forward_decode(rt_t, params, cur_tokens, cache,
+                                      cache_view=target_view)
     lg = logits[:, -1]
     if greedy:
         nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)

@@ -22,6 +22,17 @@ Cache layout (pytree; R = scan repeats of the layer group):
 
 plain store = bf16 array; packed store = {"spec": {...}, "verif": {...}}.
 
+A packed cache whose passes read dense KV also keeps its two dense views,
+``cache["view"] = {"target": groups, "draft": groups}``: per attention
+entry bf16 stores shaped like the packed ones (``{"k", "v"}`` or
+``{"c", "kr"}``), so no serving step decodes the pool. Every write to the
+packed stores writes the views too: ``engine.commit`` appends the new
+tokens' views through the same ``append_batched``, ``copy_pool_blocks``
+copies their blocks, and ``restore_pool_blocks`` decodes only the
+restored blocks into them (``store_views``). The packed stores then serve
+capacity and swap; the per-cycle passes read the views. The paged-kernel
+path, which decodes packed KV in-kernel, keeps no views.
+
 Two cache layouts share the store codecs:
 
 * **slot** (``init_cache``) — every request owns a contiguous ``(S_max,)``
@@ -134,9 +145,9 @@ def draft_from_target(cass: CassandraConfig, spec: dict, target: jax.Array,
                       d: int) -> jax.Array:
     """The draft view of a Cassandra-1 store, derived from its target view.
 
-    Bit-exact against ``read_store(view="draft")`` and elementwise, so a
-    cycle that has decoded the lossless view pays no second decode for
-    the draft one. ``target`` (..., d) is the store's target view and
+    Bit-exact against ``read_store(view="draft")`` and elementwise, so
+    the draft view of tokens whose target view is known costs no decode
+    (``store_views``). ``target`` (..., d) is the store's target view and
     ``spec`` its speculation leaves. Pruned positions (bitmap 0) read
     +0; kept ones keep their sign and lose the low ``kv_trunc`` mantissa
     bits. The exponent is exact in unary blocks (``exp_mode`` 0); in
@@ -160,6 +171,38 @@ def draft_from_target(cass: CassandraConfig, spec: dict, target: jax.Array,
         mant = bits & (0x7F & ~((1 << cass.kv_trunc) - 1))
         out = (bits & 0x8000) | (exp << 7) | mant
         return bitops.bits_to_bf16(jnp.where(kept, out, 0))
+
+
+def store_views(cass: CassandraConfig, store: dict, d: int,
+                book: tuple[jax.Array, jax.Array],
+                target: jax.Array | None = None
+                ) -> tuple[jax.Array, jax.Array]:
+    """The dense ``(target, draft)`` views of a packed store, bit for bit
+    what ``read_store`` gives for each view.
+
+    ``target`` is the bf16 the store was just encoded from. Cassandra-1's
+    target view is exactly that, and its draft view is derived from it
+    elementwise (``draft_from_target``), so nothing is decoded. The
+    Cassandra-2 target is exact only to its containers, so both views are
+    decoded from the store, as they are without ``target``."""
+    if target is None or cass.variant != 1:
+        target = read_store(cass, store, d, "target", book)
+    target = target.astype(jnp.bfloat16)
+    if cass.variant == 1:
+        return target, draft_from_target(cass, store["spec"], target, d)
+    return target, read_store(cass, store, d, "draft", book)
+
+
+def store_dims(cfg: ModelConfig) -> dict[str, int]:
+    """An attention entry's stores and their vector widths."""
+    if cfg.mla:
+        return {"c": cfg.kv_lora_rank, "kr": cfg.qk_rope_dim}
+    return {"k": cfg.hd, "v": cfg.hd}
+
+
+def has_views(cache: dict) -> bool:
+    """Whether the cache keeps dense views of its packed stores."""
+    return "view" in cache
 
 
 def append_store(store, new_store, at) -> dict:
@@ -261,7 +304,8 @@ def copy_pool_blocks(cache: dict, src: jax.Array, dst: jax.Array) -> dict:
     (K,) int32 operands padded with ``TRASH_BLOCK`` -> ``TRASH_BLOCK``
     no-op pairs, so any number of copies per cycle hits one compile.
     SSM entries are per-row recurrent state with no token axis — nothing
-    to copy (prefix caching is validated off for SSM archs)."""
+    to copy (prefix caching is validated off for SSM archs). Dense views
+    copy the same blocks."""
     def cp(leaf):                                  # (R, NB, BS, …)
         return leaf.at[:, dst].set(leaf[:, src])
     new_dec = []
@@ -272,6 +316,8 @@ def copy_pool_blocks(cache: dict, src: jax.Array, dst: jax.Array) -> dict:
         new_dec.append(gd)
     out = dict(cache)
     out["dec"] = new_dec
+    if has_views(cache):
+        out["view"] = jax.tree.map(cp, cache["view"])
     return out
 
 
@@ -300,7 +346,8 @@ def spill_pool_blocks(cache: dict, blocks: jax.Array) -> list:
     return out
 
 
-def restore_pool_blocks(cache: dict, blocks: jax.Array, data: list) -> dict:
+def restore_pool_blocks(cache: dict, blocks: jax.Array, data: list,
+                        cass: CassandraConfig | None = None) -> dict:
     """Scatter spilled block contents back: ``pool[blocks[i]] = data[i]``
     in every attention store leaf — the host→device half of KV swap-in.
 
@@ -310,7 +357,11 @@ def restore_pool_blocks(cache: dict, blocks: jax.Array, data: list) -> dict:
     one compiled step. Padded entries scatter into the trash block,
     which holds garbage by contract. Restored bytes are bit-identical to
     the spilled ones, so a resumed row's attention sees exactly the
-    cache it had when preempted."""
+    cache it had when preempted. A cache with dense views (``cass`` then
+    names its format) gets the K restored blocks decoded into both, one
+    layer at a time; no other block is decoded."""
+    def put(leaf, d):
+        return leaf.at[:, blocks].set(d.astype(leaf.dtype))
     new_dec = []
     for g, gd in zip(cache["dec"], data):
         gout = {}
@@ -318,17 +369,38 @@ def restore_pool_blocks(cache: dict, blocks: jax.Array, data: list) -> dict:
             if "conv" in e:
                 gout[ekey] = e
             else:
-                gout[ekey] = jax.tree.map(
-                    lambda leaf, d: leaf.at[:, blocks].set(
-                        d.astype(leaf.dtype)), e, gd[ekey])
+                gout[ekey] = jax.tree.map(put, e, gd[ekey])
         new_dec.append(gout)
     out = dict(cache)
     out["dec"] = new_dec
+    if has_views(cache):
+        if cass is None:
+            raise ValueError("restoring into a cache with dense views "
+                             "needs its CassandraConfig")
+        book = cache_codebook(cache)
+        view = {nm: [dict(g) for g in groups]
+                for nm, groups in cache["view"].items()}
+        with jax.named_scope("cass.kv_view_write"):
+            for gi, gd in enumerate(data):
+                for ekey, vt in cache["view"]["target"][gi].items():
+                    vt_new, vd_new = {}, {}
+                    for nm, leaf in vt.items():
+                        t, dr = jax.lax.map(
+                            lambda s, d=leaf.shape[-1]: store_views(
+                                cass, s, d, book),
+                            jax.tree.map(jnp.asarray, gd[ekey][nm]))
+                        vt_new[nm] = put(leaf, t)
+                        vd_new[nm] = put(
+                            cache["view"]["draft"][gi][ekey][nm], dr)
+                    view["target"][gi][ekey] = vt_new
+                    view["draft"][gi][ekey] = vd_new
+        out["view"] = view
     return out
 
 
-def restore_pool_blocks_marked(cache: dict, blocks: jax.Array,
-                               data: list) -> tuple[dict, jax.Array]:
+def restore_pool_blocks_marked(cache: dict, blocks: jax.Array, data: list,
+                               cass: CassandraConfig | None = None
+                               ) -> tuple[dict, jax.Array]:
     """``restore_pool_blocks`` plus a scalar completion *marker*.
 
     The marker (count of real, non-trash restore entries) is a tiny
@@ -340,7 +412,7 @@ def restore_pool_blocks_marked(cache: dict, blocks: jax.Array,
     to stamp a truthful restore wall; the pipelined scheduler defers
     that block to its next harvest, by which time the restore has
     overlapped the following fused step and the wait is ~zero."""
-    out = restore_pool_blocks(cache, blocks, data)
+    out = restore_pool_blocks(cache, blocks, data, cass)
     return out, jnp.sum(blocks != TRASH_BLOCK)
 
 
@@ -409,9 +481,24 @@ def _entry_struct(cfg: ModelConfig, cass: CassandraConfig | None,
     raise ValueError(kind)
 
 
+def _view_specs(cfg: ModelConfig, b: int, s: int) -> dict:
+    """ShapeDtypeStructs of a packed cache's dense views: per layer group
+    the attention entries' bf16 stores, once for each view."""
+    groups = []
+    for g in layer_groups(cfg):
+        gdict = {f"e{j}": _entry_struct(cfg, None, _entry_kind(cfg, entry),
+                                        b, s, False, None)
+                 for j, entry in enumerate(g.entries) if entry[0] == "a"}
+        groups.append(jax.tree.map(
+            lambda x, r=g.repeats: jax.ShapeDtypeStruct((r, *x.shape),
+                                                        x.dtype), gdict))
+    return {"target": groups, "draft": groups}
+
+
 def cache_specs(cfg: ModelConfig, cass: CassandraConfig | None,
                 b: int, s_max: int, packed: bool) -> dict:
-    """ShapeDtypeStruct pytree of the full cache (dry-run input specs)."""
+    """ShapeDtypeStruct pytree of the full cache (dry-run input specs).
+    A packed cache keeps its dense views."""
     book = (jax.ShapeDtypeStruct((256,), jnp.uint8),
             jax.ShapeDtypeStruct((256,), jnp.uint8))
 
@@ -444,6 +531,7 @@ def cache_specs(cfg: ModelConfig, cass: CassandraConfig | None,
     if packed:
         cache["book_exp_of_rank"] = book[0]
         cache["book_rank_of_exp"] = book[1]
+        cache["view"] = _view_specs(cfg, b, s_max)
     return cache
 
 
@@ -469,13 +557,15 @@ def init_cache(cfg: ModelConfig, cass: CassandraConfig | None,
 
 def paged_cache_specs(cfg: ModelConfig, cass: CassandraConfig | None,
                       b: int, num_blocks: int, block_size: int,
-                      max_blocks: int, packed: bool) -> dict:
+                      max_blocks: int, packed: bool,
+                      views: bool = True) -> dict:
     """ShapeDtypeStruct pytree of a paged cache.
 
     Attention stores become block pools (R,NB,BS,…) shared by all rows;
     SSM state stays per-row (token-recurrent state has no token axis to
     page). ``block_table`` (B,MB) maps each row's logical blocks to pool
-    blocks; ``length`` stays (B,).
+    blocks; ``length`` stays (B,). A packed pool keeps dense views
+    (R,NB,BS,…) unless ``views`` is False, as on the paged-kernel path.
     """
     if cfg.cross_attention:
         raise NotImplementedError(
@@ -507,18 +597,20 @@ def paged_cache_specs(cfg: ModelConfig, cass: CassandraConfig | None,
     if packed:
         cache["book_exp_of_rank"] = book[0]
         cache["book_rank_of_exp"] = book[1]
+        if views:
+            cache["view"] = _view_specs(cfg, num_blocks, block_size)
     return cache
 
 
 def init_paged_cache(cfg: ModelConfig, cass: CassandraConfig | None,
                      b: int, num_blocks: int, block_size: int,
                      max_blocks: int, packed: bool,
-                     codebook: tuple[jax.Array, jax.Array] | None = None
-                     ) -> dict:
+                     codebook: tuple[jax.Array, jax.Array] | None = None,
+                     views: bool = True) -> dict:
     """Allocate a zeroed paged cache; all table entries start at the trash
     block (0)."""
     specs = paged_cache_specs(cfg, cass, b, num_blocks, block_size,
-                              max_blocks, packed)
+                              max_blocks, packed, views)
     cache = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), specs)
     if packed:
         cache = _install_book(cache, codebook)

@@ -57,7 +57,9 @@ multiplexes a request queue through one jit'd serving step per cycle.
   and the head admits immediately. The victim requeues and resumes as an
   ordinary admission: a prefix match re-aliases whatever the cache still
   holds, and a batched ``restore_pool_blocks`` swap-in brings back the
-  private tail bit-exactly. The victim policy reuses the planner's
+  private tail bit-exactly (a cache with dense KV views gets the restored
+  blocks decoded into them, counted by ``kv_view_decoded_blocks``; the
+  serving cycle itself decodes none). The victim policy reuses the planner's
   token-cost model: preempt the lowest-priority resident row whose
   remaining-work cycles beat the head's time-to-first-token (plus the
   swap round-trip margin); among equal priorities only rows with MORE
@@ -399,9 +401,11 @@ class Scheduler:
                                  for g in layer_groups(cfg))
         packed = cass is not None
         if paged:
+            # the paged kernel reads the packed pool itself: no views
             self.cache = KC.init_paged_cache(
                 cfg, cass, num_slots, self.num_blocks, block_size,
-                self.max_blocks, packed=packed)
+                self.max_blocks, packed=packed,
+                views=attn_kernel == "off")
             self.capacity = self.max_blocks * block_size
         else:
             self.cache = KC.init_cache(cfg, cass, num_slots, s_max,
@@ -437,7 +441,8 @@ class Scheduler:
             # -> (cache, marker): the marker is a scalar output of the
             # SAME executable as the scatter, so blocking on it proves
             # the restore landed without syncing any cache leaf
-            return KC.restore_pool_blocks_marked(cache, blocks, data)
+            return KC.restore_pool_blocks_marked(cache, blocks, data,
+                                                 self.cass)
         # preemption's device<->host transfer halves: ``blocks`` is a
         # traced (max_blocks,) vector padded with trash entries, so every
         # spill/restore of any real size shares ONE compile bucket each
@@ -480,7 +485,7 @@ class Scheduler:
             "prefix_blocks_aliased", "cow_copies", "preemptions",
             "swap_resumes", "swap_out_blocks", "swap_in_blocks",
             "swap_matched_blocks", "moe_expert_tokens", "moe_expert_slots",
-            "moe_dropped")
+            "moe_dropped", "kv_view_decoded_blocks")
         for peak in ("peak_prefill_tokens_per_cycle",
                      "peak_resident_tokens", "peak_reserved_tokens",
                      "peak_swapped_tokens"):
@@ -545,8 +550,8 @@ class Scheduler:
         self.metrics.set_config("slo_aware", self.slo_aware)
         self.metrics.set_config("slo_declared", self._slo_seen)
         self.metrics.set_config("attn_kernel", self.attn_kernel)
-        self.metrics.set_config("kv_pool_decodes", M.kv_pool_decodes(
-            self.rt, self.cache, drafts=self.speculative))
+        self.metrics.set_config("kv_pool_decodes",
+                                M.kv_pool_decodes(self.cache))
         self.metrics.set_config("fused", self.fused)
         self.metrics.set_config("speculative", self.speculative)
         self.metrics.set_config("overlap", self.overlap)
@@ -746,6 +751,10 @@ class Scheduler:
         req.swap_key = None
         self.metrics.inc("swap_resumes")
         self.metrics.inc("swap_in_blocks", restore_n)
+        if KC.has_views(self.cache):
+            # the one decode of packed bytes into the views: the
+            # restored blocks (the steady serving cycle decodes none)
+            self.metrics.inc("kv_view_decoded_blocks", restore_n)
         self.metrics.inc("swap_matched_blocks", matched)
         self.tracer.emit(TM.RESUME, rid=req.rid, slot=slot,
                          cycle=self.clock, args=(matched, restore_n))

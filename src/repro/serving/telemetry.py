@@ -38,10 +38,11 @@ layer:
   profiler's clock (``sched.admit|plan|dispatch|wait|harvest`` in the
   scheduler's cycle), so a ``jax.profiler`` trace puts each device idle
   gap beside the host phase that held it. The fused serving step names
-  its phases with ``jax.named_scope`` (``cass.view``, ``cass.draft``,
-  ``cass.verify``, ``cass.commit``, and ``cass.kv_decode`` /
-  ``cass.kv_derive`` / ``cass.kv_gather`` wherever the packed KV is
-  decoded, derived or paged in, ``cass.moe`` around each expert layer);
+  its phases with ``jax.named_scope`` (``cass.draft``, ``cass.verify``,
+  ``cass.commit`` with ``cass.kv_view_write`` around the KV view writes,
+  and ``cass.kv_decode`` / ``cass.kv_derive`` / ``cass.kv_gather``
+  wherever the packed KV is decoded, a draft view derived or a pool
+  paged in, ``cass.moe`` around each expert layer);
   :func:`hlo_op_scopes` maps each instruction of a compiled program's
   text to the scopes it ran under, which is how the device trace's bare
   HLO op names are put down to a phase.

@@ -30,7 +30,7 @@ jax.config.update("jax_platform_name", "cpu")
 
 GAMMA = 2
 MAX_NEW = 6
-PHASE_SCOPES = ("cass.view", "cass.draft", "cass.verify", "cass.commit")
+PHASE_SCOPES = ("cass.draft", "cass.verify", "cass.commit")
 DATA = Path(__file__).resolve().parent / "data"
 
 
@@ -75,15 +75,15 @@ def test_profiled_serving_is_bitwise_lossless(served):
 def test_step_text_names_every_phase(served):
     scopes = hlo_op_scopes(served["text"])
     parts = {c for path in scopes.values() for c in path.split("/")}
-    assert set(PHASE_SCOPES) | {"cass.kv_decode", "cass.kv_derive",
+    assert set(PHASE_SCOPES) | {"cass.kv_view_write", "cass.kv_derive",
                                 "cass.kv_gather"} <= parts
-    # the packed KV is decoded once, before the drafts, and the draft
-    # view derived from that decode; neither pass decodes it again
+    # the passes read the views the cache keeps: nothing decodes the
+    # packed KV, and commit writes the new tokens' views, the draft view
+    # derived from the target
     paths = set(scopes.values())
-    assert {"cass.view/cass.kv_decode", "cass.view/cass.kv_derive"} <= paths
-    assert not {"cass.draft/cass.kv_decode",
-                "cass.verify/cass.kv_decode"} & paths
-    assert served["sched"].summary()["subsystems"]["kv_pool_decodes"] == 1
+    assert "cass.commit/cass.kv_view_write/cass.kv_derive" in paths
+    assert "cass.kv_decode" not in parts
+    assert served["sched"].summary()["subsystems"]["kv_pool_decodes"] == 0
 
 
 def test_no_op_falls_under_two_phases(served):
@@ -206,5 +206,5 @@ def test_serve_profile_dir_writes_trace_and_scope_map(tmp_path):
     assert len(done) == 2
     assert glob.glob(str(tmp_path / "**/*.xplane.pb"), recursive=True)
     scopes = json.loads((tmp_path / "op_scopes.json").read_text())
-    assert {"cass.view/cass.kv_decode", "cass.view/cass.kv_derive",
+    assert {"cass.commit/cass.kv_view_write/cass.kv_derive",
             "cass.commit"} <= set(scopes.values())
